@@ -137,11 +137,12 @@ def make_parser() -> argparse.ArgumentParser:
     p.add_argument("--check-every", type=int, default=1, metavar="K",
                    help="test convergence every K iterations inside the "
                         "device loop (amortizes the stopping test) [1]")
-    p.add_argument("--residual-replacement", type=int, default=0,
+    p.add_argument("--residual-replacement", type=int, default=None,
                    metavar="R",
                    help="pipelined CG: recompute r/w/s/z from their "
                         "definitions every R iterations, correcting "
-                        "recurrence drift at tight tolerances (0 = off)")
+                        "recurrence drift at tight tolerances (0 = off) "
+                        "[50 for float32, 0 for float64]")
     # resilience options (acg_tpu/robust/)
     p.add_argument("--resilient", action="store_true",
                    help="run the solve under the self-healing supervisor "
@@ -586,7 +587,7 @@ def _serve_main(args, tracer, A, b, options, fault_specs) -> int:
         halo=HaloMethod(args.halo),
         partition_method=args.partition_method, seed=args.seed,
         options=options, tracer=tracer,
-        prep_cache=_cli_prep_cache(args))
+        prep_cache=_cli_prep_cache(args), nrhs=args.serve_max_batch)
     if args.replicas > 1:
         # the replica fleet (acg_tpu/serve/fleet.py): R sessions behind
         # one admission front — the REPL commands below read a Fleet
@@ -796,6 +797,8 @@ def main(argv=None) -> int:
 
 def _main(argv=None) -> int:
     args = make_parser().parse_args(argv)
+    from acg_tpu.utils.backend import enable_compile_cache
+    enable_compile_cache()
     # phase-span tracer: the pipeline's host timeline (read / partition /
     # operator-build / warmup / solve), logged at -v and exported into
     # --output-stats-json; spans also emit jax.profiler.TraceAnnotation
@@ -838,6 +841,15 @@ def _main(argv=None) -> int:
     # honor 64-bit value requests on device (see config.ensure_x64_for)
     from acg_tpu.config import ensure_x64_for
     ensure_x64_for(np.dtype(args.dtype))
+    import jax
+    if args.dtype == "float64" and jax.devices()[0].platform == "tpu":
+        from acg_tpu.utils.backend import f64_report
+        f64 = f64_report()
+        if not f64["f64_ok"]:
+            print("WARNING: --dtype float64 on this TPU does not keep "
+                  f"float64 precision ({f64}); results carry less than "
+                  "double precision.  Use --dtype float32 on the chip.",
+                  file=sys.stderr)
 
     # 1. read A (ref cuda/acg-cuda.c:1296-1331)
     _log(args, f"reading matrix {args.A!r}")

@@ -2,9 +2,11 @@
 
 The reference's host data layer is C (acg/sort.c, acg/prefixsum.c,
 acg/mtxfile.c parsing, acg/graph.c traversals); acg_tpu mirrors that split
-with a small C++ library for the host hot paths and exposes it here.  Every
-entry point has a NumPy fallback, so the package works without the build
-step; ``python -m acg_tpu.native --build`` (or native/build.sh) compiles it.
+with a small C++ library for the host hot paths and exposes it here.  The
+library is not committed: :func:`load` builds it from native/acg_host.cpp
+(native/build.sh) the first time it is missing or older than its source.
+Every entry point has a NumPy fallback; when the build or the load fails,
+the reason goes to stderr once and the fallbacks run.
 
 Accelerated paths (used automatically when the library is present):
 - :func:`parse_mtx_body` — single-pass text parse of coordinate entries
@@ -69,21 +71,32 @@ def build(verbose: bool = True) -> bool:
         return False
     global _lib
     _lib = None
-    return load() is not None
+    return True
+
+
+def _stale() -> bool:
+    src = os.path.join(os.path.dirname(_LIB_PATH), "acg_host.cpp")
+    return (not os.path.exists(_LIB_PATH)
+            or os.path.getmtime(_LIB_PATH) < os.path.getmtime(src))
 
 
 def load():
-    """Load (and memoize) the shared library; None if unavailable."""
+    """Load (and memoize) the shared library, building it first when it
+    is missing or stale; None (reported on stderr) if that fails."""
     global _lib
     if _lib is not None:
         return _lib if _lib is not False else None
-    if not os.path.exists(_LIB_PATH):
-        _lib = False
+    built = not _stale() or build()
+    _lib = False                  # memoize a failure: report it once
+    if not built:
+        print("acg_tpu: native library unavailable; using the NumPy "
+              "fallbacks", file=sys.stderr)
         return None
     try:
         lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
-        _lib = False
+    except OSError as e:
+        print(f"acg_tpu: native library failed to load ({e}); using the "
+              "NumPy fallbacks", file=sys.stderr)
         return None
     i64p = ctypes.POINTER(ctypes.c_int64)
     f64p = ctypes.POINTER(ctypes.c_double)
@@ -99,44 +112,35 @@ def load():
     lib.acg_bfs_order.restype = ctypes.c_int64
     lib.acg_bfs_order.argtypes = [i64p, i64p, ctypes.c_int64, u8p,
                                   ctypes.c_int64, ctypes.c_int, i64p]
-    if hasattr(lib, "acg_rcm_order"):   # older prebuilt .so may lack it
-        lib.acg_rcm_order.restype = ctypes.c_int64
-        lib.acg_rcm_order.argtypes = [i64p, i64p, ctypes.c_int64, i64p]
+    lib.acg_rcm_order.restype = ctypes.c_int64
+    lib.acg_rcm_order.argtypes = [i64p, i64p, ctypes.c_int64, i64p]
     u32p = ctypes.POINTER(ctypes.c_uint32)
     u64p = ctypes.POINTER(ctypes.c_uint64)
-    if hasattr(lib, "acg_hem_round"):   # older prebuilt .so may lack it
-        lib.acg_hem_round.restype = ctypes.c_int64
-        lib.acg_hem_round.argtypes = [i64p, i64p, f64p, u32p,
-                                      ctypes.c_int64, ctypes.c_int64, i64p]
-    if hasattr(lib, "acg_hem_compact_live"):
-        lib.acg_hem_compact_live.restype = ctypes.c_int64
-        lib.acg_hem_compact_live.argtypes = [i64p, i64p, f64p,
-                                             ctypes.c_int64, i64p]
-    if hasattr(lib, "acg_contract_edges"):
-        lib.acg_contract_edges.restype = ctypes.c_int64
-        lib.acg_contract_edges.argtypes = [i64p, i64p, f64p,
-                                           ctypes.c_int64, i64p,
-                                           ctypes.c_int64, i64p, i64p, f64p]
-    if hasattr(lib, "acg_refine_weighted_sweep"):
-        lib.acg_refine_weighted_sweep.restype = ctypes.c_int64
-        lib.acg_refine_weighted_sweep.argtypes = [
-            i64p, i64p, f64p, i64p, ctypes.c_int64, i64p, ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_int32), ctypes.c_int64, i64p,
-            ctypes.c_int64, ctypes.c_int]
-    if hasattr(lib, "acg_radix_argsort_u64"):  # same stale-.so tolerance
-        lib.acg_radix_argsort_u64.restype = ctypes.c_int
-        lib.acg_radix_argsort_u64.argtypes = [u64p, ctypes.c_int64, i64p]
-    if hasattr(lib, "acg_sgell_fill_slots"):
-        lib.acg_sgell_fill_slots.restype = ctypes.c_int64
-        lib.acg_sgell_fill_slots.argtypes = [i64p, i64p, ctypes.c_int64,
-                                             ctypes.c_int64]
-    if hasattr(lib, "acg_csr_permute_sym"):
-        lib.acg_csr_permute_sym.restype = ctypes.c_int
-        lib.acg_csr_permute_sym.argtypes = [i64p, i64p, ctypes.c_int64,
-                                            i64p, i64p, i64p, i64p]
-    if hasattr(lib, "acg_native_threads"):
-        lib.acg_native_threads.restype = ctypes.c_int
-        lib.acg_native_threads.argtypes = []
+    lib.acg_hem_round.restype = ctypes.c_int64
+    lib.acg_hem_round.argtypes = [i64p, i64p, f64p, u32p,
+                                  ctypes.c_int64, ctypes.c_int64, i64p]
+    lib.acg_hem_compact_live.restype = ctypes.c_int64
+    lib.acg_hem_compact_live.argtypes = [i64p, i64p, f64p,
+                                         ctypes.c_int64, i64p]
+    lib.acg_contract_edges.restype = ctypes.c_int64
+    lib.acg_contract_edges.argtypes = [i64p, i64p, f64p,
+                                       ctypes.c_int64, i64p,
+                                       ctypes.c_int64, i64p, i64p, f64p]
+    lib.acg_refine_weighted_sweep.restype = ctypes.c_int64
+    lib.acg_refine_weighted_sweep.argtypes = [
+        i64p, i64p, f64p, i64p, ctypes.c_int64, i64p, ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_int32), ctypes.c_int64, i64p,
+        ctypes.c_int64, ctypes.c_int]
+    lib.acg_radix_argsort_u64.restype = ctypes.c_int
+    lib.acg_radix_argsort_u64.argtypes = [u64p, ctypes.c_int64, i64p]
+    lib.acg_sgell_fill_slots.restype = ctypes.c_int64
+    lib.acg_sgell_fill_slots.argtypes = [i64p, i64p, ctypes.c_int64,
+                                         ctypes.c_int64]
+    lib.acg_csr_permute_sym.restype = ctypes.c_int
+    lib.acg_csr_permute_sym.argtypes = [i64p, i64p, ctypes.c_int64,
+                                        i64p, i64p, i64p, i64p]
+    lib.acg_native_threads.restype = ctypes.c_int
+    lib.acg_native_threads.argtypes = []
     _lib = lib
     return lib
 
@@ -148,10 +152,10 @@ def available() -> bool:
 def native_threads() -> int:
     """The thread count the native stages will use: the
     ``ACG_NATIVE_THREADS`` resolution (default: hardware concurrency).
-    1 when the library is absent or predates the thread pool — the
-    NumPy fallbacks are single-threaded either way."""
+    1 when the library is unavailable — the NumPy fallbacks are
+    single-threaded."""
     lib = load()
-    if lib is None or not hasattr(lib, "acg_native_threads"):
+    if lib is None:
         return 1
     return int(lib.acg_native_threads())
 
@@ -212,7 +216,7 @@ def rcm_order_native(rowptr, colidx, nrows: int):
     Mirrors acg_tpu/sparse/rcm.py's rules (min-degree component starts,
     two-sweep pseudo-peripheral refinement, degree-sorted BFS, reversal)."""
     lib = load()
-    if lib is None or not hasattr(lib, "acg_rcm_order"):
+    if lib is None:
         return None
     rowptr = np.ascontiguousarray(rowptr, dtype=np.int64)
     colidx = np.ascontiguousarray(colidx, dtype=np.int64)
@@ -251,7 +255,7 @@ def hem_round_native(rows, cols, w, jit, n: int, match) -> int | None:
     Returns newly matched node count, or None if unavailable (caller runs
     the bit-compatible NumPy round)."""
     lib = load()
-    if lib is None or not hasattr(lib, "acg_hem_round"):
+    if lib is None:
         return None
     rows = np.ascontiguousarray(rows, dtype=np.int64)
     cols = np.ascontiguousarray(cols, dtype=np.int64)
@@ -274,7 +278,7 @@ def hem_compact_live_native(rows, cols, w, match) -> int | None:
     None if unavailable.  ``rows``/``cols`` int64 and ``w`` float64 must
     be C-contiguous and writable."""
     lib = load()
-    if lib is None or not hasattr(lib, "acg_hem_compact_live"):
+    if lib is None:
         return None
     for a, dt in ((rows, np.int64), (cols, np.int64), (w, np.float64)):
         if a.dtype != dt or not a.flags.c_contiguous or not a.flags.writeable:
@@ -299,7 +303,7 @@ def contract_edges_native(rows, cols, w, cmap, nc: int,
     allocated — the finest level's 63M-edge contraction at 9M rows
     was the partitioner's peak-RSS moment."""
     lib = load()
-    if lib is None or not hasattr(lib, "acg_contract_edges"):
+    if lib is None:
         return None
     if reuse_buffers:
         for a, dt in ((rows, np.int64), (cols, np.int64),
@@ -337,7 +341,7 @@ def refine_weighted_sweep_native(ptr, adj_c, adj_w, nw, boundary, part,
     in place.  mode 0 = gain sweep, 1 = balance repair.  Returns moves
     made, or None if unavailable."""
     lib = load()
-    if lib is None or not hasattr(lib, "acg_refine_weighted_sweep"):
+    if lib is None:
         return None
     ptr = np.ascontiguousarray(ptr, dtype=np.int64)
     adj_c = np.ascontiguousarray(adj_c, dtype=np.int64)
@@ -365,7 +369,7 @@ def sgell_fill_slots_native(rowptr, colidx, nrows: int,
     ascending (the CsrMatrix contract).  None if unavailable or on
     malformed input (caller falls back to the full layout)."""
     lib = load()
-    if lib is None or not hasattr(lib, "acg_sgell_fill_slots"):
+    if lib is None:
         return None
     rowptr = np.ascontiguousarray(rowptr, dtype=np.int64)
     colidx = np.ascontiguousarray(colidx, dtype=np.int64)
@@ -380,7 +384,7 @@ def csr_permute_sym_native(rowptr, colidx, nrows: int, perm):
     ``order`` the per-entry source index, so the caller gathers values
     at their native dtype; None if unavailable."""
     lib = load()
-    if lib is None or not hasattr(lib, "acg_csr_permute_sym"):
+    if lib is None:
         return None
     rowptr = np.ascontiguousarray(rowptr, dtype=np.int64)
     colidx = np.ascontiguousarray(colidx, dtype=np.int64)
@@ -402,7 +406,7 @@ def radix_argsort_native(keys) -> np.ndarray | None:
     acgradixsortpair, acg/sort.c) — identical permutation to
     ``np.argsort(keys, kind="stable")``; None if unavailable."""
     lib = load()
-    if lib is None or not hasattr(lib, "acg_radix_argsort_u64"):
+    if lib is None:
         return None
     keys = np.ascontiguousarray(keys, dtype=np.uint64)
     perm = np.empty(len(keys), dtype=np.int64)

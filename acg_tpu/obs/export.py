@@ -242,7 +242,7 @@ def result_to_dict(res) -> dict:
     note = getattr(res, "kernel_note", "")
     if note:
         # why the kernel tier differs from the unconstrained auto choice
-        # (e.g. "pipe2d disengaged: replace_every=50"); omitted when the
+        # (e.g. "pipe2d disengaged: fault injection"); omitted when the
         # tier is the auto pick, so /1../4 documents stay byte-stable
         d["kernel_note"] = str(note)
     if nrhs > 1:
@@ -1017,10 +1017,14 @@ def _validate_introspection(p: list, intro, v5: bool = False,
     if roof is not None and not isinstance(roof, dict):
         p.append("introspection.roofline is neither null nor an object")
     elif isinstance(roof, dict):
-        for f in ("operator_bytes", "vector_bytes", "bytes_per_iter",
-                  "hbm_gbps", "predicted_iters_per_sec"):
+        for f in ("operator_bytes", "vector_bytes", "bytes_per_iter"):
             _check(p, _is_num(roof.get(f, "missing")),
                    f"roofline.{f} missing or not numeric")
+        # null off the chip: no bandwidth to model against
+        for f in ("hbm_gbps", "predicted_iters_per_sec"):
+            v = roof.get(f, "missing")
+            _check(p, v is None or _is_num(v),
+                   f"roofline.{f} missing or not numeric/null")
         _check(p, isinstance(roof.get("nrhs", "missing"), int),
                "roofline.nrhs missing or not int")
         for f in ("measured_iters_per_sec", "roofline_frac"):

@@ -438,7 +438,7 @@ def observe_solve_result(res, solver: str) -> None:
     note = getattr(res, "kernel_note", "") or ""
     if note:
         # bounded label cardinality: count each clause by its HEAD
-        # ("pipe2d disengaged: replace_every=50" -> "pipe2d
+        # ("pipe2d disengaged: fault injection" -> "pipe2d
         # disengaged"), not the full parameterized message
         fam = _REGISTRY.counter(
             "acg_solver_kernel_disengaged_total",

@@ -172,8 +172,9 @@ class DeviceDia:
         """Tier order under mat_dtype="auto": lossless bf16 FIRST, then
         exact two-value int8, then full width.  bf16 wins when both apply:
         measured end-to-end on v5e at 128³ Poisson, bf16 3836 it/s vs the
-        int8 tier's 3771 (BENCH_r02/PERF.md — the int8→f32 upcast + scales
-        broadcast costs more than the smaller band stream saves).  int8
+        int8 tier's 3771 (pre-bring-up record, deleted at PR 21 — the
+        int8→f32 upcast + scales broadcast costs more than the smaller
+        band stream saves).  int8
         remains the exact tier for two-valued bands that are NOT
         bf16-representable (e.g. {0, 1/3} coefficients).
 

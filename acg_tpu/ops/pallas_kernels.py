@@ -27,9 +27,10 @@ loop, measured at parity with a dedicated Pallas kernel (PERF.md
 All kernels are correctness-tested in interpret mode on CPU.  On real
 hardware the DIA kernels activate automatically via
 :func:`pallas_spmv_available` — a once-per-process probe that compiles
-every storage tier and verifies it against the XLA path, falling back
-silently when Mosaic is unavailable (``ACG_TPU_PALLAS=0`` skips the
-probe entirely).
+every storage tier and verifies it against the XLA path.  A group whose
+probe fails is left out of routing; the failure is reported on stderr
+and kept for :func:`probe_errors` (``ACG_TPU_PALLAS=0`` skips the probes
+entirely).
 """
 
 from __future__ import annotations
@@ -44,6 +45,23 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 TILE_ROWS = 8          # float32 min sublane tile
+
+
+def pallas_call(kernel, **kw):
+    """``pl.pallas_call`` traced with 64-bit types off.  Under
+    ``jax_enable_x64`` the kernels' index arithmetic traces as int64,
+    and Mosaic's lowering of the int64 -> int32 conversion recurses
+    without end (jax 0.9); every kernel in this package goes through
+    here so an f64 solve elsewhere in the process cannot break them."""
+    call = pl.pallas_call(kernel, **kw)
+    if kw.get("interpret"):
+        return call               # the interpreter runs any dtype
+
+    def run(*args):
+        with jax.enable_x64(False):
+            return call(*args)
+
+    return run
 
 
 # The original 1-D resident kernel (``dia_matvec_pallas``: (1, tile)
@@ -113,7 +131,7 @@ def dia_matvec_pallas_2d(bands, offsets: tuple, x, rows_tile: int = 512,
     scaled = scales is not None
     sc = (scales.astype(x.dtype) if scaled
           else jnp.zeros((D,), dtype=x.dtype))
-    y = pl.pallas_call(
+    y = pallas_call(
         functools.partial(_dia2d_kernel, offsets, rows_tile, scaled),
         out_shape=jax.ShapeDtypeStruct((R, LANES), x.dtype),
         grid=(R // rows_tile,),
@@ -213,7 +231,7 @@ def dia_matvec_pallas_2d_padded(bands_pad, offsets: tuple, x_pad,
         out_shape.append(jax.ShapeDtypeStruct((1, 1), x_pad.dtype))
         out_specs.append(pl.BlockSpec((1, 1), lambda i: (0, 0),
                                       memory_space=pltpu.SMEM))
-    outs = pl.pallas_call(
+    outs = pallas_call(
         functools.partial(_dia2d_padded_kernel, offsets, rows_tile, scaled,
                           with_dot),
         out_shape=tuple(out_shape),
@@ -309,7 +327,7 @@ def cg_pipelined_iter_pallas(bands_pad, offsets: tuple, w_pad, z_pad,
     tile_spec = pl.BlockSpec((rows_tile, LANES), lambda i: (i, 0),
                              memory_space=pltpu.VMEM)
     vec = jax.ShapeDtypeStruct((Rp, LANES), dt)
-    outs = pl.pallas_call(
+    outs = pallas_call(
         functools.partial(_pipe2d_kernel, offsets, rows_tile, scaled),
         out_shape=(vec,) * 6 + (jax.ShapeDtypeStruct((1, 2), dt),),
         grid=(ntiles,),
@@ -404,7 +422,7 @@ def dia_matvec_pallas_2d_padded_batched(bands_pad, offsets: tuple, x_pad,
         out_shape.append(jax.ShapeDtypeStruct((1, B), x_pad.dtype))
         out_specs.append(pl.BlockSpec((1, B), lambda i, s: (0, 0),
                                       memory_space=pltpu.SMEM))
-    outs = pl.pallas_call(
+    outs = pallas_call(
         functools.partial(_dia2d_padded_batched_kernel, offsets, rows_tile,
                           scaled, with_dot),
         out_shape=tuple(out_shape),
@@ -624,7 +642,7 @@ def dia_matvec_pallas_hbm2d(bands_pad, offsets: tuple, x_pad,
     scratch = ([pltpu.VMEM((nbuf, rows_tile + ext, LANES), x_pad.dtype)
                 for _, ext, _ in windows]
                + [pltpu.SemaphoreType.DMA((nbuf,)) for _ in windows])
-    outs = pl.pallas_call(
+    outs = pallas_call(
         functools.partial(_dia_hbm2d_kernel, windows, rows_tile, scaled,
                           with_dot, Rp, nbuf),
         out_shape=tuple(out_shape),
@@ -790,7 +808,7 @@ def dia_matvec_pallas_hbm2d_ring(bands_pad, offsets: tuple, x_pad,
                                       memory_space=pltpu.SMEM))
     scratch = [pltpu.VMEM(((T_ring + 1) * rows_tile, LANES), x_pad.dtype),
                pltpu.SemaphoreType.DMA((T_ring + 1,))]
-    outs = pl.pallas_call(
+    outs = pallas_call(
         functools.partial(_dia_hbm2d_ring_kernel, offsets, rows_tile,
                           T_ring, qmin_t, qmax_t, scaled, with_dot,
                           ntiles),
@@ -886,15 +904,15 @@ def pipe2d_plan(npad: int, offsets: tuple, vec_dtype, band_dtype,
 
 
 def pipe2d_rt_for(nrows_padded: int, offsets: tuple, vec_dtype,
-                  band_dtype, plan, replace_every: int) -> int | None:
+                  band_dtype, plan) -> int | None:
     """THE pipe2d gate, shared by the single-chip and distributed
     pipelined solvers (their selection must never diverge): rows_tile for
     the single-kernel iteration, or None.  ``plan`` is the fused-plan
-    result; the kernel applies only on the resident tier with
-    replace_every == 0, after its probe passes, and within its own VMEM
+    result; the kernel applies only on the resident tier, after its
+    probe passes, and within its own VMEM
     plan.  Call OUTSIDE jit (probes must not run inside a trace; the
     result must be part of the jit cache key)."""
-    if plan is None or plan[0] != "resident" or replace_every != 0:
+    if plan is None or plan[0] != "resident":
         return None
     if not pallas_spmv_available("pipe2d"):
         return None
@@ -940,9 +958,10 @@ def fused_plan_for(n: int, offsets: tuple, vec_dtype,
     this (n, offsets, dtypes), else None.  The fused LOOP takes every
     storage width including f32: its win is structural (padded carries +
     in-kernel p'Ap), and the A/B measured it directly — p3d-var-96 f32
-    full-width 25,578 it/s fused vs 19,448 XLA, 2026-07-31
-    (measurements/var96-*), even though the bare chained-marginal f32
-    SpMV loses to XLA (dia_matvec_best keeps plain f32 matvecs on XLA).
+    full-width 25,578 it/s fused vs 19,448 XLA, 2026-07-31 (a
+    pre-bring-up record, deleted at PR 21), even though the bare
+    chained-marginal f32 SpMV loses to XLA (dia_matvec_best keeps plain
+    f32 matvecs on XLA).
     ACG_TPU_FUSED_F32=0 restores the narrow-tiers-only gate for
     re-measurement.  HBM: any width past the resident VMEM bound."""
     import os
@@ -982,7 +1001,8 @@ def _pick_rows_tile(n: int) -> int | None:
 _VMEM_BUDGET = 12 * 2**20   # leave headroom below the ~16 MB/core VMEM
 
 
-_SPMV_PROBE: dict = {}  # "resident2d"|"fused2d"|"hbm2d"|"ell" -> bool
+_SPMV_PROBE: dict = {}   # probe group -> passed
+_PROBE_ERRORS: dict = {}  # probe group -> failure text, None if passed
 
 
 def _probe_dia_group(kernels, n: int = 2048,
@@ -1017,8 +1037,7 @@ def _probe_dia_group(kernels, n: int = 2048,
 def _probe_ell_group() -> bool:
     """Compile-and-match the ELL gather kernel (acg_tpu/ops/pallas_spmv.py)
     for f32 and bf16 value storage against the XLA gather formulation, at
-    EVERY tile size _pick_ell_tile can select — a probe pass must
-    guarantee the production block shape compiles."""
+    EVERY tile size _pick_ell_tile can select."""
     from acg_tpu.ops.pallas_spmv import _ELL_TILES, ell_matvec_pallas
     from acg_tpu.ops.spmv import ell_matvec
 
@@ -1178,7 +1197,7 @@ def _probe_pipe2d_group(interpret: bool = False) -> bool:
 
 
 _PROBE_GROUPS = {
-    # probe at PRODUCTION block shapes (cf. _probe_ell_group's discipline):
+    # probe at PRODUCTION block shapes:
     # both rows_tile extremes the selector can pick, with a flagship-scale
     # offset (±16384 = 128³'s z-band ⇒ a 129-row halo slab) plus the
     # lane-rotation path — Mosaic accepting a tiny block but rejecting the
@@ -1213,6 +1232,7 @@ _PROBE_GROUPS = {
     # the multi-RHS resident kernel (batch grid dimension; band tiles
     # fetched once per row tile across all B systems)
     "batched2d": _probe_batched_group,
+    # the ELL gather kernel (acg_tpu/ops/pallas_spmv.py): OUT_OF_AUTO
     "ell": _probe_ell_group,
     # matrix-free stencil kernels (acg_tpu/ops/stencil.py): bands
     # synthesized in-register, zero operator HBM stream
@@ -1235,36 +1255,63 @@ _PROBE_GROUPS = {
 }
 
 
+# Probe groups no automatic routing selects: their kernels run only when a
+# caller forces them, and a forced call raises ERR_NOT_SUPPORTED when the
+# probe failed.  "ell": Mosaic on TPU v5e refuses its in-kernel 1-D
+# vector gather ("Only 2D gather is supported", PR 21 chip run); the
+# unstructured tier on the chip is sgell.
+OUT_OF_AUTO = frozenset({"ell"})
+
+
 def pallas_spmv_available(kind: str = "resident2d") -> bool:
-    """Probe once per KERNEL GROUP whether the Pallas SpMV compiles AND
-    matches the XLA path on this backend.  False (with silent XLA fallback)
-    on CPU, on chips whose Mosaic compile path is unavailable, or on any
-    numeric mismatch — so enabling a kernel can never change results.
-    Groups probe independently: a Mosaic regression in one group (e.g. the
-    HBM kernels' async-copy plumbing, or the ELL kernel's vector gather)
-    must not disable a proven group."""
+    """Probe once per KERNEL GROUP whether the Pallas kernels compile AND
+    match the XLA path on this backend.  False off TPU, with
+    ``ACG_TPU_PALLAS=0``, or when the probe raises or mismatches — a
+    group that is False is left out of routing, so enabling a kernel can
+    never change results.  A failure on a TPU is reported on stderr once
+    and kept for :func:`probe_errors`.  Groups probe independently: a
+    Mosaic regression in one group must not disable a proven group."""
     if kind in _SPMV_PROBE:
         return _SPMV_PROBE[kind]
     import os
+    import sys
 
-    env = os.environ.get("ACG_TPU_PALLAS", "").strip()
-    if env == "0":              # kill switch: skip the probe entirely
-        _SPMV_PROBE[kind] = False
-        return False
-    try:
-        if jax.devices()[0].platform != "tpu":
-            _SPMV_PROBE[kind] = False
-            return False
-        _SPMV_PROBE[kind] = bool(_PROBE_GROUPS[kind]())
-    except Exception:
-        _SPMV_PROBE[kind] = False
-    return _SPMV_PROBE[kind]
+    platform = jax.devices()[0].platform
+    if os.environ.get("ACG_TPU_PALLAS", "").strip() == "0":
+        ok, err = False, "disabled by ACG_TPU_PALLAS=0"
+    elif platform != "tpu":
+        ok, err = False, f"no TPU (platform {platform})"
+    else:
+        try:
+            ok = bool(_PROBE_GROUPS[kind]())
+            err = None if ok else "result differs from the XLA reference"
+        except Exception as e:  # noqa: BLE001 - kept and reported below
+            ok, err = False, f"{type(e).__name__}: {str(e)[:2000]}"
+        if not ok:
+            print(f"acg_tpu: Pallas probe group {kind!r} failed; its "
+                  f"kernels are not used: {err}", file=sys.stderr)
+    _SPMV_PROBE[kind] = ok
+    _PROBE_ERRORS[kind] = err
+    return ok
+
+
+def probe_errors() -> dict:
+    """group -> why its probe failed (None when it passed), for every
+    group probed so far in this process."""
+    return dict(_PROBE_ERRORS)
+
+
+def probe_all() -> dict:
+    """Run every probe group; group -> None (passed) or the reason."""
+    for kind in _PROBE_GROUPS:
+        pallas_spmv_available(kind)
+    return {k: _PROBE_ERRORS[k] for k in _PROBE_GROUPS}
 
 
 # pipelined_update_pallas (the 6-vector fused pipelined-CG update as one
 # Pallas kernel, the analog of reference acg/cg-kernels-cuda.cu:187-269)
 # was DELETED after measurement: on v5e at 128^3 the XLA-fused update is
-# marginally faster (2826 us vs 2882 us, speedup 0.981 — measurements/
-# kernels-20260730), i.e. XLA already emits the single fused pass over the
-# 7 streams inside the jitted solver loop, so the hand-written kernel
+# marginally faster (2826 us vs 2882 us, speedup 0.981 — a pre-bring-up
+# record, deleted at PR 21), i.e. XLA already emits the single fused pass
+# over the 7 streams inside the jitted solver loop, so the hand-written kernel
 # bought nothing.  See PERF.md "wire-or-delete decisions".

@@ -13,10 +13,11 @@ in-register.
 Whether the in-kernel gather beats XLA's fused gather formulation
 (acg_tpu/ops/spmv.py ``ell_matvec``) is an empirical, chip-generation
 question: Mosaic's VMEM gather support is the limiting factor.  The kernel
-is therefore probe-gated like every Pallas kernel here (compile-and-match
-once per process, group "ell" — acg_tpu/ops/pallas_kernels.py) and
-selected only when the probe passes; the XLA path is the contract and the
-oracle.  Measured numbers live in PERF.md.
+is probe-gated (compile-and-match once per process, group "ell" —
+acg_tpu/ops/pallas_kernels.py) and OUT of automatic routing: on TPU v5e
+Mosaic refuses its 1-D vector gather ("Only 2D gather is supported", PR
+21), so ``DeviceEll.matvec`` runs the XLA path and the kernel runs only
+through :func:`ell_matvec_forced`, which raises where the probe failed.
 """
 
 from __future__ import annotations
@@ -29,7 +30,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from acg_tpu.ops.pallas_kernels import _VMEM_BUDGET
+from acg_tpu.ops.pallas_kernels import _VMEM_BUDGET, pallas_call
 
 
 def _ell_kernel(x_ref, vals_ref, cols_ref, y_ref):
@@ -59,7 +60,7 @@ def ell_matvec_pallas(vals, colidx, x, tile: int = 512,
     n, W = vals.shape
     assert n % tile == 0, "n_pad must be a multiple of the tile size"
     xp = x.reshape(1, n)
-    y = pl.pallas_call(
+    y = pallas_call(
         _ell_kernel,
         out_shape=jax.ShapeDtypeStruct((n, 1), x.dtype),
         grid=(n // tile,),
@@ -103,31 +104,29 @@ def _pick_ell_tile(n: int) -> int | None:
     return None
 
 
-def pallas_ell_available() -> bool:
-    """ELL kernel probe — group "ell" of the shared once-per-process probe
-    registry (acg_tpu/ops/pallas_kernels.py): a failed probe silently keeps
-    the XLA path, so enabling the kernel can never change results."""
-    from acg_tpu.ops.pallas_kernels import pallas_spmv_available
+def ell_matvec_forced(vals, colidx, x, interpret: bool = False):
+    """y = ELL(vals, colidx) @ x through the Pallas kernel, forced.
 
-    return pallas_spmv_available("ell")
-
-
-def ell_matvec_best(vals, colidx, x):
-    """ELL SpMV through the best available path (kernel when the probe
-    passes and shapes fit, else the XLA gather formulation).
-
-    The kernel path additionally requires len(x) == nrows_padded; the XLA
-    path honors ell_matvec's wider 'len(x) >= nrows_padded' contract."""
-    from acg_tpu.ops.spmv import ell_matvec
+    The kernel is out of automatic routing (pallas_kernels.OUT_OF_AUTO:
+    Mosaic on TPU v5e refuses its 1-D vector gather; ``DeviceEll.matvec``
+    always runs the XLA gather formulation).  A forced call raises
+    ERR_NOT_SUPPORTED where the "ell" probe failed or the shape does not
+    fit, rather than quietly running another path.  ``interpret`` skips
+    the probe (CPU tests run the interpret kernel)."""
+    from acg_tpu.errors import AcgError, Status
+    from acg_tpu.ops.pallas_kernels import (pallas_spmv_available,
+                                            probe_errors)
 
     n, W = vals.shape
-    if x.ndim != 1:
-        # batched (B, n): the XLA gather broadcasts over the leading axis;
-        # the lane-gather kernel is 1-D only
-        return ell_matvec(vals, colidx, x)
     tile = _pick_ell_tile(n)
-    if (tile is not None and x.shape[0] == n
-            and pallas_ell_fits(n, W, x.dtype, vals.dtype, tile)
-            and pallas_ell_available()):
-        return ell_matvec_pallas(vals, colidx, x, tile=tile)
-    return ell_matvec(vals, colidx, x)
+    if (tile is None or x.ndim != 1 or x.shape[0] != n
+            or not pallas_ell_fits(n, W, x.dtype, vals.dtype, tile)):
+        raise AcgError(Status.ERR_NOT_SUPPORTED,
+                       f"the Pallas ELL kernel does not fit this shape "
+                       f"(n={n}, W={W}, x {x.shape} {x.dtype})")
+    if not interpret and not pallas_spmv_available("ell"):
+        raise AcgError(Status.ERR_NOT_SUPPORTED,
+                       "the Pallas ELL kernel was forced but its probe "
+                       f"failed: {probe_errors().get('ell')}")
+    return ell_matvec_pallas(vals, colidx, x, tile=tile,
+                             interpret=interpret)

@@ -46,8 +46,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from acg_tpu.ops.pallas_kernels import pallas_call
+
 LANES = 128
 SUBL = 8
+# The kernel scalar-prefetches seg/tile/first (40 B per slot) into SMEM,
+# which holds 1 MiB on v5e: 26,000 slots fail to compile there (AOT
+# compile for a described v5e, PR 21).  Larger packs do not take the tier.
+MAX_SLOTS = 24 * 1024
 TILE = SUBL * LANES          # 1024 output rows per tile
 
 # sgell wins over the XLA gather formulation down to ~0.002 fill on the
@@ -198,7 +204,7 @@ def pack_sgell(rows: np.ndarray, cols: np.ndarray, vals: np.ndarray,
     tile_slot0 = np.concatenate(([0], np.cumsum(nslots_t)))
     S = int(tile_slot0[-1])
     fill = nnz / (S * TILE)
-    if fill < min_fill:
+    if fill < min_fill or S > MAX_SLOTS:
         return dict(vals=None, idx=None, seg=None, tile=None, first=None,
                     S=S, ntiles=ntiles, n_pad=n_pad, fill=fill)
     pv = np.zeros((S, SUBL, LANES), dtype=vals.dtype)
@@ -292,7 +298,7 @@ def sgell_matvec_pallas(vals, idx, seg, tile, first, x_pad,
     x_specs = [
         pl.BlockSpec((1, 1, LANES),
                      (lambda s_cap: lambda k, seg_r, tile_r, first_r:
-                      (seg_r[k, s_cap], 0, 0))(s),
+                      (seg_r[k * SUBL + s_cap], 0, 0))(s),
                      memory_space=pltpu.VMEM)
         for s in range(SUBL)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -311,11 +317,11 @@ def sgell_matvec_pallas(vals, idx, seg, tile, first, x_pad,
                                (tile_r[k], 0),
                                memory_space=pltpu.VMEM),
     )
-    y = pl.pallas_call(
+    y = pallas_call(
         _sgell_kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((ntiles * SUBL, LANES), x_pad.dtype),
         interpret=interpret,
-    )(seg, tile, first, *([x3d] * SUBL), vals, idx)
+    )(seg.reshape(-1), tile, first, *([x3d] * SUBL), vals, idx)
     return y.reshape(-1)
 
 

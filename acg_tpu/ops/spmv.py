@@ -8,10 +8,10 @@ load-balanced CSR kernel (reference acg/cg-kernels-cuda.cu:340-441
 ``csrgemv_merge``) — the load balancing already happened on the host when
 rows were padded to a rectangle (see acg_tpu/sparse/ell.py).
 
-A Pallas kernel for the same contract lives in acg_tpu/ops/pallas_spmv.py
-(probe-gated; ``DeviceEll.matvec`` selects it when it compiles and matches
-on the running chip); this module is the portable path (CPU interpret/TPU)
-and the correctness oracle for it.
+A Pallas kernel for the same contract lives in acg_tpu/ops/pallas_spmv.py,
+out of automatic routing since PR 21 (Mosaic on TPU v5e refuses its 1-D
+vector gather; it runs only when forced, ``ell_matvec_forced``); the
+unstructured tier on the chip is sgell (acg_tpu/ops/sgell.py).
 """
 
 from __future__ import annotations
@@ -75,9 +75,7 @@ class DeviceEll:
         return self.vals.shape[1]
 
     def matvec(self, x: jax.Array) -> jax.Array:
-        from acg_tpu.ops.pallas_spmv import ell_matvec_best
-
-        return ell_matvec_best(self.vals, self.colidx, x)
+        return ell_matvec(self.vals, self.colidx, x)
 
 
 def ell_matvec(vals: jax.Array, colidx: jax.Array, x: jax.Array) -> jax.Array:

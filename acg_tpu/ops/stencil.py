@@ -56,7 +56,8 @@ from jax.experimental.pallas import tpu as pltpu
 # the stencil kernels share the padded-layout geometry owners with the
 # DIA kernels (ONE halo/tail arithmetic for both tiers)
 from acg_tpu.ops.pallas_kernels import (LANES, _VMEM_BUDGET, _window_2d,
-                                        pad_dia_vectors, padded_halo_rows)
+                                        pad_dia_vectors, padded_halo_rows,
+                                        pallas_call)
 
 # recognition is bounded: a "stencil" with more arms than the densest
 # supported family (27-pt box) is not one
@@ -376,7 +377,7 @@ def stencil_matvec_pallas_padded(grid: tuple, offsets: tuple,
         out_shape.append(jax.ShapeDtypeStruct((1, 1), x_pad.dtype))
         out_specs.append(pl.BlockSpec((1, 1), lambda i: (0, 0),
                                       memory_space=pltpu.SMEM))
-    outs = pl.pallas_call(
+    outs = pallas_call(
         functools.partial(_stencil2d_padded_kernel, grid, offsets, digits,
                           coeffs, rows_tile, n, hrows, with_dot),
         out_shape=tuple(out_shape),
@@ -441,7 +442,7 @@ def stencil_matvec_pallas_padded_batched(grid: tuple, offsets: tuple,
         out_shape.append(jax.ShapeDtypeStruct((1, B), x_pad.dtype))
         out_specs.append(pl.BlockSpec((1, B), lambda i, s: (0, 0),
                                       memory_space=pltpu.SMEM))
-    outs = pl.pallas_call(
+    outs = pallas_call(
         functools.partial(_stencil2d_batched_kernel, grid, offsets,
                           digits, coeffs, rows_tile, n, hrows, with_dot),
         out_shape=tuple(out_shape),
@@ -520,7 +521,7 @@ def cg_pipelined_iter_stencil(grid: tuple, offsets: tuple, digits: tuple,
     tile_spec = pl.BlockSpec((rows_tile, LANES), lambda i: (i, 0),
                              memory_space=pltpu.VMEM)
     vec = jax.ShapeDtypeStruct((Rp, LANES), dt)
-    outs = pl.pallas_call(
+    outs = pallas_call(
         functools.partial(_stpipe2d_kernel, grid, offsets, digits, coeffs,
                           rows_tile, n, hrows),
         out_shape=(vec,) * 6 + (jax.ShapeDtypeStruct((1, 2), dt),),
@@ -600,6 +601,37 @@ def stencil_pipe_plan(npad: int, offsets: tuple, vec_dtype) -> int | None:
         if w_bytes + 2 * vec_tiles <= _VMEM_BUDGET:
             return rt
     return None
+
+
+def prefer_stencil(npad: int, offsets: tuple, coeffs, vec_dtype,
+                   mat_dtype="auto", nrhs: int = 1) -> bool:
+    """THE fmt="auto" choice between a recognized stencil and its stored
+    DIA twin, for every builder (single-chip, per-shard, and the batched
+    serve operator through ``nrhs``): the matrix-free tier, unless its
+    kernel does not plan at this length and batch while the DIA tier's
+    Pallas kernel does — past the VMEM bound (256³ f32) the HBM-ring DIA
+    kernel, and for a batch of 4 at 128³ the batched resident DIA
+    kernel, run instead of the XLA grid-shift form.  ``coeffs`` (the
+    stencil's arm values, host side) resolve the DIA twin's band dtype
+    exactly as its storage builders do (``resolve_mat_dtype``; "int8"
+    is DIA's exact mask tier), so every caller prices the same band
+    stream."""
+    from acg_tpu.ops.dia import resolve_mat_dtype
+    from acg_tpu.ops.pallas_kernels import (fused_plan_for,
+                                            pallas_2d_batched_plan,
+                                            pallas_spmv_available)
+
+    vdt = np.dtype(vec_dtype)
+    mdt = np.dtype(np.int8 if mat_dtype == "int8" else resolve_mat_dtype(
+        np.asarray(coeffs, vdt), mat_dtype, vdt))
+    if nrhs > 1:
+        if stencil_batched_plan(nrhs, npad, offsets, vdt) is not None:
+            return True
+        return not (0 in offsets and pallas_spmv_available("batched2d")
+                    and pallas_2d_batched_plan(nrhs, npad, offsets, vdt,
+                                               mdt) is not None)
+    return (stencil_plan(npad, offsets, vdt) is not None
+            or fused_plan_for(npad, offsets, vdt, mdt) is None)
 
 
 def stencil_available(kind: str = "stencil2d") -> bool:

@@ -105,9 +105,8 @@ def build_halo_tables(ps: PartitionedSystem, nghost_max: int | None = None,
     G = nghost_max if nghost_max is not None else max(
         max((p.nghost for p in ps.parts), default=1), 1)
 
-    # recv pad = G (one past the ghost region): JAX .at[] *wraps* negative
-    # indices, so -1 would silently hit the last ghost slot; an index == G
-    # is out of bounds and dropped by mode="drop".
+    # recv pad = G (one past the ghost region, into place_round's slack):
+    # each row is one contiguous run start..start+cnt, then pads.
     send_idx = np.full((P, R, S), -1, dtype=np.int32)
     recv_idx = np.full((P, R, S), G, dtype=np.int32)
     for p in ps.parts:
@@ -295,7 +294,7 @@ def halo_ppermute(x_own, send_idx, recv_idx, perms, nghost_max: int,
     code path untouched — the traced program is bit-identical to one
     built before the wire option existed (the zero-overhead clause).
     """
-    ghosts = jnp.zeros(x_own.shape[:-1] + (nghost_max,), dtype=x_own.dtype)
+    ghosts = ghost_buffer(x_own, send_idx.shape[-1], nghost_max)
     for r, perm in enumerate(perms):
         if not perm:
             continue
@@ -307,9 +306,28 @@ def halo_ppermute(x_own, send_idx, recv_idx, perms, nghost_max: int,
             rbuf = wire_decode(
                 jax.lax.ppermute(wire_encode(sbuf, wire), axis_name, perm),
                 wire, x_own.dtype)
-        # pad recv indices == nghost_max are out of bounds -> dropped
-        ghosts = ghosts.at[..., recv_idx[r]].set(rbuf, mode="drop")
-    return ghosts
+        ghosts = place_round(ghosts, rbuf, recv_idx[r], nghost_max)
+    return jax.lax.slice_in_dim(ghosts, 0, nghost_max, axis=-1)
+
+
+def ghost_buffer(x_own, max_msg: int, nghost_max: int):
+    """Zero ghost vector with ``max_msg`` slots of slack behind it, so a
+    message placed by :func:`place_round` never runs off the end."""
+    return jnp.zeros(x_own.shape[:-1] + (nghost_max + max_msg,),
+                     dtype=x_own.dtype)
+
+
+def place_round(ghosts, rbuf, recv_row, nghost_max: int):
+    """Write one round's message into its ghost slots.  A round's
+    receive slots are one contiguous run (build_halo_tables numbers each
+    neighbour's ghosts consecutively; pad = ``nghost_max``), so a masked
+    dynamic_update_slice at the run's start places it: no scatter in the
+    solver's loop (contract C5)."""
+    start = recv_row[0]
+    cur = jax.lax.dynamic_slice_in_dim(ghosts, start, rbuf.shape[-1],
+                                       axis=-1)
+    new = jnp.where(recv_row < nghost_max, rbuf, cur)
+    return jax.lax.dynamic_update_slice_in_dim(ghosts, new, start, axis=-1)
 
 
 def halo_allgather(x_own, pack_idx, ghost_src_part, ghost_src_pos,

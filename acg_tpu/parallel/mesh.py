@@ -38,11 +38,8 @@ def make_mesh(nparts: int, devices=None) -> jax.sharding.Mesh:
             Status.ERR_MESH,
             f"need {nparts} devices for {nparts} parts, have {len(devices)}")
     if nparts == len(devices) and nparts > 1:
-        try:
-            from jax.experimental import mesh_utils
+        from jax.experimental import mesh_utils
 
-            arr = mesh_utils.create_device_mesh((nparts,), devices=devices)
-            return jax.sharding.Mesh(arr, (PARTS_AXIS,))
-        except Exception:       # fall back to enumeration order
-            pass
+        arr = mesh_utils.create_device_mesh((nparts,), devices=devices)
+        return jax.sharding.Mesh(arr, (PARTS_AXIS,))
     return jax.sharding.Mesh(np.asarray(devices[:nparts]), (PARTS_AXIS,))

@@ -30,6 +30,20 @@ import jax
 import numpy as np
 
 
+def _cluster_size_from_env(env) -> int:
+    """Processes the environment says this job spans (1 when it names
+    none): the TPU worker list (GKE / Cloud TPU: ``TPU_PROCESS_ADDRESSES``
+    or ``TPU_WORKER_HOSTNAMES``, times ``MEGASCALE_NUM_SLICES``), Slurm's
+    ``SLURM_NTASKS`` or Open MPI's ``OMPI_COMM_WORLD_SIZE``."""
+    hosts = (env.get("TPU_PROCESS_ADDRESSES")
+             or env.get("TPU_WORKER_HOSTNAMES") or "")
+    n = max(len([h for h in hosts.split(",") if h.strip()]), 1)
+    n *= max(int(env.get("MEGASCALE_NUM_SLICES") or 1), 1)
+    for key in ("SLURM_NTASKS", "OMPI_COMM_WORLD_SIZE"):
+        n = max(n, int(env.get(key) or 1))
+    return n
+
+
 def init_multihost(coordinator_address: str | None = None,
                    num_processes: int | None = None,
                    process_id: int | None = None) -> None:
@@ -37,28 +51,46 @@ def init_multihost(coordinator_address: str | None = None,
 
     MUST be the first JAX call of the process (``jax.distributed.initialize``
     precedes any backend use — the same contract as MPI_Init, reference
-    cuda/acg-cuda.c:891).  The already-initialized check therefore inspects
-    the distributed global state directly instead of calling any backend
-    API.  With no arguments this is the cluster-autodetect path (TPU pods
-    fill them from the environment) and a plain single-process run is a
-    silent no-op; an EXPLICIT ``coordinator_address`` that fails to connect
-    propagates the error — silently degrading a pod run to N independent
-    single-host runs would produce wrong results with no diagnostic."""
-    try:
-        from jax._src import distributed as _dist
+    cuda/acg-cuda.c:891).  The cluster is given explicitly, by the
+    arguments or by ``JAX_COORDINATOR_ADDRESS`` / ``JAX_NUM_PROCESSES`` /
+    ``JAX_PROCESS_ID``, and a given cluster that fails to connect raises.
+    Without one, a host the environment names as single
+    (:func:`_cluster_size_from_env`) runs single-process and this is a
+    no-op; an environment naming several processes raises, because
+    silently degrading a pod run to N independent single-host runs would
+    produce wrong results with no diagnostic.  JAX's own autodetection
+    is never used: its Cloud TPU probe queries the GCE metadata server
+    (jax/_src/clusters/cloud_tpu_cluster.py ``GceTpuCluster.
+    is_env_present`` -> ``get_metadata``), a network call that a sealed
+    host cannot answer (PR 21: the chip machines have no metadata
+    server)."""
+    import os
 
-        if getattr(_dist.global_state, "client", None) is not None:
-            return              # already initialized
-    except ImportError:         # private-module layout changed: fall through
-        pass
-    try:
-        jax.distributed.initialize(coordinator_address=coordinator_address,
-                                   num_processes=num_processes,
-                                   process_id=process_id)
-    except (ValueError, RuntimeError):
-        if coordinator_address is not None:
-            raise               # explicit cluster request must not degrade
-        # no cluster environment detected: single-process run, nothing to do
+    from jax._src import distributed as _dist
+
+    from acg_tpu.errors import AcgError, Status
+
+    if _dist.global_state.client is not None:
+        return                  # already initialized
+    env = os.environ
+    coordinator_address = (coordinator_address
+                           or env.get("JAX_COORDINATOR_ADDRESS"))
+    if not coordinator_address:
+        n = _cluster_size_from_env(env)
+        if n > 1:
+            raise AcgError(Status.ERR_NOT_SUPPORTED,
+                           f"the environment names a {n}-process job but "
+                           f"no coordinator: set JAX_COORDINATOR_ADDRESS, "
+                           f"JAX_NUM_PROCESSES and JAX_PROCESS_ID (or pass "
+                           f"them) so every host joins one runtime")
+        return
+    if num_processes is None and env.get("JAX_NUM_PROCESSES"):
+        num_processes = int(env["JAX_NUM_PROCESSES"])
+    if process_id is None and env.get("JAX_PROCESS_ID"):
+        process_id = int(env["JAX_PROCESS_ID"])
+    jax.distributed.initialize(coordinator_address=coordinator_address,
+                               num_processes=num_processes,
+                               process_id=process_id)
 
 
 def make_global_array(global_shape, sharding, fill_shard) -> jax.Array:

@@ -19,14 +19,14 @@ here a property of the shared schedule.  Slots without a partner self-copy
 (device_id = own index); their payload is dropped by the pad scatter
 indices.
 
-Status: compiles AND executes on real TPU hardware — the loopback
-payload round-trip (scripts/check_rdma_tpu.py) is bit-exact on the
-attached chip (2026-07-30).  Multi-chip transfer awaits a real mesh
-(Mosaic remote DMA is not supported by the CPU interpreter used in CI,
-where this module is trace-tested only); select via ``HaloMethod.RDMA``
-once profiled there.  The transport moves (R, S) message blocks;
-gather/scatter to/from ghost slots stays in XLA where it is already
-optimal.
+Status: NOT SUPPORTED.  ``build_sharded`` refuses ``HaloMethod.RDMA``
+with ERR_NOT_SUPPORTED on every platform: the puts target a peer's VMEM
+with no barrier handshake that the peer's kernel is running (Pallas'
+remote-copy protocol wants a barrier semaphore and a ``collective_id``),
+and the kernel has never run across chips.  A PR that adds the handshake
+and proves it on four chips (``chip_smoke.py --chips 4``) lifts the gate.
+The transport moves (R, S) message blocks; placing them into ghost slots
+stays in XLA.
 """
 
 from __future__ import annotations
@@ -38,20 +38,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x -> 0.5+ and
-# moved has_side_effects between releases; resolve whichever spelling this
-# jaxlib ships and drop unknown fields so the RDMA tier degrades cleanly
-# (an AttributeError here used to take down even trace-only CI use of this
-# module) instead of binding to one version's API.
-def _compiler_params(**kw):
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:
-        return None
-    import dataclasses as _dc
-
-    known = {f.name for f in _dc.fields(cls)}
-    return cls(**{k: v for k, v in kw.items() if k in known})
+from acg_tpu.ops.pallas_kernels import pallas_call
+from acg_tpu.parallel.halo import ghost_buffer, place_round
 
 
 def _rdma_kernel(nrounds, dev_ref, sendbuf_ref, recvbuf_ref,
@@ -83,19 +71,15 @@ def rdma_exchange(sendbuf: jax.Array, devices: jax.Array,
     Must be called inside ``shard_map``.  ``sendbuf[r]`` is delivered into
     the returned array's slot r on device ``devices[r]``.
 
-    Hardware notes (validated on-chip 2026-07-30, bit-exact loopback):
-    slots are staged as (8, S'/8) 2-D blocks behind a leading slot axis —
+    Slots are staged as (8, S'/8) 2-D blocks behind a leading slot axis:
     Mosaic requires ``.at[r]`` memref slices to land on sublane-tile
-    boundaries, so a flat (R, S) buffer with small R is rejected ("Slice
-    shape along dimension 0 must be aligned to tiling").  ``collective_id``
-    must be left unset on current Mosaic unless a custom barrier
-    semaphore is used.
+    boundaries, so a flat (R, S) buffer with small R is rejected.
     """
     R, S = sendbuf.shape
     assert R == nrounds
     Sp = -(-S // 1024) * 1024
     sb = jnp.pad(sendbuf, ((0, 0), (0, Sp - S))).reshape(R, 8, Sp // 8)
-    out = pl.pallas_call(
+    out = pallas_call(
         functools.partial(_rdma_kernel, nrounds),
         out_shape=jax.ShapeDtypeStruct((R, 8, Sp // 8), sendbuf.dtype),
         in_specs=[
@@ -107,7 +91,7 @@ def rdma_exchange(sendbuf: jax.Array, devices: jax.Array,
             pltpu.SemaphoreType.DMA((R,)),
             pltpu.SemaphoreType.DMA((R,)),
         ],
-        compiler_params=_compiler_params(has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
     )(devices, sb)
     return out.reshape(R, Sp)[:, :S]
 
@@ -125,7 +109,7 @@ def halo_rdma(x_own, send_idx, recv_idx, partner_row, nghost_max: int,
     devices = jnp.where(partner_row >= 0, partner_row, me).astype(jnp.int32)
     sendbuf = x_own[jnp.clip(send_idx, 0, None)]          # (R, S)
     recvbuf = rdma_exchange(sendbuf, devices, nrounds=R)
-    ghosts = jnp.zeros((nghost_max,), dtype=x_own.dtype)
+    ghosts = ghost_buffer(x_own, send_idx.shape[-1], nghost_max)
     for r in range(R):
-        ghosts = ghosts.at[recv_idx[r]].set(recvbuf[r], mode="drop")
-    return ghosts
+        ghosts = place_round(ghosts, recvbuf[r], recv_idx[r], nghost_max)
+    return jax.lax.slice_in_dim(ghosts, 0, nghost_max, axis=-1)

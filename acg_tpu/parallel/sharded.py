@@ -159,11 +159,26 @@ def _stencil_probe() -> bool:
     return stencil_available()
 
 
+def _prefer_stencil(ps: PartitionedSystem, spec, vec_dtype,
+                    mat_dtype) -> bool:
+    """Auto takes the matrix-free tier per
+    :func:`acg_tpu.ops.stencil.prefer_stencil` at the shard length."""
+    from acg_tpu.ops.pallas_kernels import LANES
+    from acg_tpu.ops.stencil import prefer_stencil
+
+    if vec_dtype is None:
+        return True
+    nown = max((p.nown for p in ps.parts), default=1)
+    return prefer_stencil(-(-nown // LANES) * LANES, spec.offsets,
+                          spec.coeffs, vec_dtype, mat_dtype)
+
+
 def resolve_local_fmt(ps: PartitionedSystem, fmt: str = "auto",
                       try_rcm: bool = True, vec_dtype=None,
                       sgell_interpret: bool = False,
                       stencil_interpret: bool = False,
-                      tier_report: dict | None = None):
+                      tier_report: dict | None = None,
+                      mat_dtype="auto"):
     """THE fmt="auto" decision, shared by every entry point: returns
     ``(ps, fmt, extra)`` with fmt resolved to "dia"/"sgell"/"ell";
     ``extra`` is the resolved DIA offsets, the per-part sgell packs, or
@@ -219,7 +234,9 @@ def resolve_local_fmt(ps: PartitionedSystem, fmt: str = "auto",
         spec, why = recognize_parts(ps, vec_dtype, per_part=ppo)
         if tier_report is not None:
             tier_report["stencil"] = _stencil_report(spec, why)
-        if spec is not None and (stencil_interpret or _stencil_probe()):
+        if spec is not None and (stencil_interpret or (
+                _stencil_probe()
+                and _prefer_stencil(ps, spec, vec_dtype, mat_dtype))):
             if tier_report is not None:
                 fill_tier_report(tier_report, ps, "stencil", vec_dtype,
                                  per_part=ppo)
@@ -452,7 +469,7 @@ class ShardedSystem:
             _, fmt, extra = resolve_local_fmt(
                 ps, fmt, try_rcm=False, vec_dtype=vdt,
                 sgell_interpret=sgell_interpret,
-                stencil_interpret=stencil_interpret)
+                stencil_interpret=stencil_interpret, mat_dtype=mat_dtype)
             if fmt == "dia":
                 loffsets = extra
             elif fmt == "sgell":
@@ -565,7 +582,7 @@ class ShardedSystem:
                 stack[i, pos, :] = dm.bands[:, :NOWN]
             # storage tiers, mirroring DeviceDia.from_dia: lossless bf16
             # first (measured faster than the int8 tier end-to-end on v5e,
-            # BENCH_r02/PERF.md), then exact two-value int8 compression
+            # pre-bring-up record), then exact two-value int8 compression
             # (per-shard scales), else the vector dtype.  The bf16 scan
             # runs once; the stack is already at vdt (built above).
             ok_two = False

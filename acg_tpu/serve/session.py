@@ -282,7 +282,10 @@ class Session:
     :mod:`acg_tpu.partition.cache` (``"auto"`` = the process default,
     ``None`` = off); ``share_prepared=False`` opts out of the
     process-level prepared-operator cache (tests use this to measure
-    cold builds)."""
+    cold builds).  ``nrhs`` is the batch width the single-chip operator
+    tier is chosen for (a SolverService's ``max_batch``: the stencil and
+    batched-DIA kernels plan differently per width —
+    acg_tpu/ops/stencil.py ``prefer_stencil``)."""
 
     def __init__(self, A=None, *, path: str | None = None, nparts: int = 1,
                  part=None,
@@ -293,7 +296,7 @@ class Session:
                  options: SolverOptions = SolverOptions(),
                  tracer: SpanTracer | None = None, log=None,
                  prep_cache="auto", share_prepared: bool = True,
-                 recycle: bool = False):
+                 recycle: bool = False, nrhs: int = 1):
         if (A is None) == (path is None):
             raise AcgError(Status.ERR_INVALID_VALUE,
                            "Session needs exactly one of A or path")
@@ -307,6 +310,7 @@ class Session:
         self.dtype = np.dtype(dtype)
         self.fmt = fmt
         self.mat_dtype = mat_dtype
+        self.nrhs = int(nrhs)
         self.halo = HaloMethod(halo)
         self.partition_method = partition_method
         self.seed = int(seed)
@@ -377,7 +381,7 @@ class Session:
             return None
         return (ghash.full, self.nparts, self.dtype.name, self.fmt,
                 str(self.mat_dtype), self.halo.value,
-                self.partition_method, self.seed)
+                self.partition_method, self.seed, self.nrhs)
 
     def _prepare(self):
         """Partition + tier resolution + device placement, once — or a
@@ -420,7 +424,7 @@ class Session:
             with self.tracer.span("operator-build"):
                 self._dev = build_device_operator(
                     self.A, dtype=self.dtype, fmt=self.fmt,
-                    mat_dtype=self.mat_dtype)
+                    mat_dtype=self.mat_dtype, nrhs=self.nrhs)
         self.counters["prepared"]["misses"] += 1
         _M_PREPARED.labels(outcome="miss").inc()
         if key is not None:

@@ -95,10 +95,10 @@ class SolveResult:
     operator_format: str = ""
     kernel: str = ""
     # WHY the kernel tier is what it is, when a requested feature changed
-    # it (VERDICT r5 weak #7: pipe2d silently disengages under
-    # replace_every; forced formats pin a tier): "" when the tier is the
-    # unconstrained auto choice, else e.g.
-    # "pipe2d disengaged: replace_every=50" or "format forced: ell".
+    # it (VERDICT r5 weak #7: pipe2d silently disengaged; forced formats
+    # pin a tier): "" when the tier is the unconstrained auto choice,
+    # else e.g. "pipe2d disengaged: fault injection" or
+    # "format forced: ell".
     # Rendered after the kernel name in the -v stats block.
     kernel_note: str = ""
     # per-iteration residual-norm² trajectory, length niterations+1
@@ -187,7 +187,7 @@ def path_names(fmt: str, plan_kind: str | None = None,
 
 
 def kernel_disengagement_note(pipelined: bool, plan, pipe_rt,
-                              replace_every: int, fault,
+                              fault,
                               forced_fmt: str = "auto",
                               stencil: bool = False,
                               stencil_interpret: bool = False) -> str:
@@ -197,8 +197,7 @@ def kernel_disengagement_note(pipelined: bool, plan, pipe_rt,
 
     A pipelined solve on the resident DIA tier takes the single-kernel
     pipelined iteration (pipe2d) unless something disengages it —
-    ``replace_every`` (the kernel has no replacement path), fault
-    injection (no injection sites), or the kernel probe/VMEM plan.  The
+    fault injection (no injection sites) or the kernel probe/VMEM plan.  The
     reasons are tested in the same order as the gate
     (acg_tpu/ops/pallas_kernels.py ``pipe2d_rt_for``) so the note names
     the FIRST condition that actually bit."""
@@ -207,9 +206,7 @@ def kernel_disengagement_note(pipelined: bool, plan, pipe_rt,
         notes.append(f"format forced: {forced_fmt}")
     if (pipelined and plan is not None and plan[0] == "resident"
             and pipe_rt is None):
-        if replace_every != 0:
-            why = f"replace_every={replace_every}"
-        elif fault is not None:
+        if fault is not None:
             why = "fault injection"
         else:
             from acg_tpu.ops.pallas_kernels import pallas_spmv_available
@@ -222,9 +219,7 @@ def kernel_disengagement_note(pipelined: bool, plan, pipe_rt,
         # the matrix-free single-kernel pipelined iteration, same
         # first-condition-that-bit ordering as the DIA pipe2d gate
         # (acg_tpu/solvers/cg.py _stencil_pipe_rt)
-        if replace_every != 0:
-            why = f"replace_every={replace_every}"
-        elif fault is not None:
+        if fault is not None:
             why = "fault injection"
         else:
             from acg_tpu.ops.pallas_kernels import pallas_spmv_available

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from acg_tpu.config import replace_period
 from acg_tpu.robust.faults import (SITE_CARRY, SITE_HALO, SITE_SPMV,
                                    inject_reduction, inject_vector)
 
@@ -393,7 +394,6 @@ def cg_sstep_while(block_fn, b, x0, p0, rr0, shifts0, stop2, s: int,
         B = b.shape[0]
         e_p = jnp.tile(e_p, (B, 1))
         e_r = jnp.tile(e_r, (B, 1))
-        rows = jnp.arange(B)
 
     def hist_put(hist, pos, mask, val):
         """Write ``val`` at each system's own cursor ``pos`` where
@@ -401,8 +401,14 @@ def cg_sstep_while(block_fn, b, x0, p0, rr0, shifts0, stop2, s: int,
         discipline of the other loops, but at PER-SYSTEM positions —
         systems pause and resume, so the global k cannot serve)."""
         if batched:
-            cur = hist[rows, pos]
-            return hist.at[rows, pos].set(jnp.where(mask, val, cur))
+            # one dynamic_update_slice per system: the per-row cursor
+            # form hist.at[rows, pos] lowers to a scatter (contract C5)
+            for i in range(B):
+                at = (jnp.asarray(i, pos.dtype), pos[i])
+                cur = jax.lax.dynamic_slice(hist, at, (1, 1))
+                hist = jax.lax.dynamic_update_slice(
+                    hist, jnp.where(mask[i], val[i], cur), at)
+            return hist
         return hist.at[pos].set(jnp.where(mask, val, hist[pos]))
 
     ksys0 = (jnp.zeros((B,), jnp.int32) if batched
@@ -596,9 +602,27 @@ def cg_pipelined_while(matvec, dot2, b, x0, stop2, maxits: int,
     (Cools/Vanroose-style): the recurred r, w, s, z drift from their true
     values by accumulated rounding, stalling the attainable accuracy of
     pipelined CG; periodically recomputing r = b - Ax, w = Ar, s = Ap,
-    z = As restores it at the cost of 4 extra operator applications per
-    replacement step.  The reference ships no such correction — its
-    pipelined solver simply stalls at the drift floor.
+    z = As restores it at the cost of 4 extra operator applications and
+    one extra reduction per replacement step.  ``None`` resolves by the
+    vector dtype (:func:`replace_period`): fp32 recurrences drift past
+    rtol 1e-5 within ~50 iterations at 128³ and diverge without it.  The
+    reference ships no such correction — its pipelined solver simply
+    stalls at the drift floor.
+
+    Every replaced state (periodic or certification) also RE-DERIVES the
+    next step's scalars from it: beta = -(r, s)/(p, s) keeps the next
+    direction A-conjugate to p, and the denominator (p', Ap') becomes
+    delta + beta (r, s).  Carrying the recurred gamma_prev/alpha_prev
+    across a replacement instead mixes a true gamma into a drifted
+    beta = gamma/gamma_prev, and in fp32 the resulting denominators
+    threw x off by 100x per replacement (PR 21: 160³ took 1,734
+    iterations at R=10 and failed at R=20; with the re-derivation
+    419-430 for R = 10..100, classic CG 413).  The re-derivation needs p
+    to still be a direction of the true problem: where the true gamma is
+    more than 4x off the recurred one (a drift gap, typically a failed
+    exit certification past the attainable floor) or the pair cannot
+    give a positive step (r.s >= 0 or p.s <= 0), the next step restarts
+    from r instead (beta = 0).
 
     Exit CERTIFICATION: the recurred gamma is a drifting estimate, and
     past the attainable floor it decouples downward while the TRUE
@@ -620,9 +644,8 @@ def cg_pipelined_while(matvec, dot2, b, x0, stop2, maxits: int,
     delta): the single-kernel pipelined iteration
     (acg_tpu/ops/pallas_kernels.py cg_pipelined_iter_pallas), where q
     never exists in HBM and the dot operands are never re-read.
-    Requires ``replace_every == 0`` (the replacement path recomputes the
-    recurrences through ``matvec``, which stays available for the exit
-    certifier either way).
+    Replacement steps recompute the recurrences through ``matvec`` after
+    the kernel's step, exactly as the open-coded body does.
 
     ``certify=False`` (static) removes the in-body certification branch
     entirely.  Callers pass it exactly when NO stopping criterion is
@@ -667,6 +690,7 @@ def cg_pipelined_while(matvec, dot2, b, x0, stop2, maxits: int,
     solves).
     """
     batched = b.ndim == 2
+    replace_every = replace_period(replace_every, b.dtype)
     if carry_in is None:
         r = b - matvec(x0)
         w = matvec(r)
@@ -693,6 +717,7 @@ def cg_pipelined_while(matvec, dot2, b, x0, stop2, maxits: int,
     any_crit = (atol2 > 0.0) | (rtol2 > 0.0)
     zero = jnp.zeros_like(b)
     one = jnp.asarray(1.0, b.dtype)
+    zero_s = jnp.zeros_like(gamma0)
 
     def _met(g):
         return (g < thresh2) | (any_crit & (g == 0.0))
@@ -706,13 +731,22 @@ def cg_pipelined_while(matvec, dot2, b, x0, stop2, maxits: int,
             done = done & (kk % check_every == 0)
         return done
 
-    def _replace_state(x, r, w, p, s, z):
-        """Recompute the recurred vectors from their definitions."""
+    def _replace_step(args):
+        """Recompute the recurred vectors from their definitions and
+        re-reduce: (r, w, s, z, gamma, delta, r.s, p.s) — the last two
+        re-derive the next step's scalars (see docstring)."""
+        x, _, _, p, _, _ = args
         r = b - matvec(x)
         w = matvec(r)
         s = matvec(p)
         z = matvec(s)
-        return r, w, s, z
+        g, d = dot2(r, r, w, r)
+        rs, ps = dot2(r, s, p, s)
+        return r, w, s, z, g, d, rs, ps
+
+    def _keep(args, g, d):
+        """The not-replaced branch of every replacement cond."""
+        return args[1], args[2], args[4], args[5], g, d, zero_s, zero_s
 
     if carry_in is not None:
         init = carry_in[:-2]
@@ -736,8 +770,6 @@ def cg_pipelined_while(matvec, dot2, b, x0, stop2, maxits: int,
             alive = jnp.isfinite(gamma) & jnp.isfinite(c[7])
         return (k < limit) & ~_exit_test(gamma, k) & alive
 
-    if iter_step is not None and replace_every > 0:
-        raise ValueError("iter_step requires replace_every == 0")
     if iter_step is not None and fault is not None:
         raise ValueError("fault injection requires iter_step=None (the "
                          "single-kernel pipelined iteration exposes no "
@@ -768,7 +800,6 @@ def cg_pipelined_while(matvec, dot2, b, x0, stop2, maxits: int,
         if iter_step is not None:
             z, p, s, x, r, w, gamma_new, delta_new = iter_step(
                 z, r, p, w, s, x, alpha, beta)
-            just_replaced = jnp.asarray(False)
         else:
             q = matvec(w)   # overlaps the reduction in the sharded case
             q = inject_vector(fault, SITE_SPMV, k, q)
@@ -780,27 +811,22 @@ def cg_pipelined_while(matvec, dot2, b, x0, stop2, maxits: int,
             x = x + bc(alpha) * p
             r = r - bc(alpha) * s
             w = w - bc(alpha) * z
-            if replace_every > 0:
-                just_replaced = (k + 1) % replace_every == 0
-                r, w, s, z = jax.lax.cond(
-                    just_replaced,
-                    lambda a: _replace_state(*a),
-                    lambda a: (a[1], a[2], a[4], a[5]),
-                    (x, r, w, p, s, z))
-            else:
-                just_replaced = jnp.asarray(False)
             gamma_new, delta_new = dot2(r, r, w, r)
             gamma_new = inject_reduction(fault, k, gamma_new)
+        g_rec = gamma_new            # recurred, before any replacement
+        if replace_every > 0:
+            just_replaced = (k + 1) % replace_every == 0
+            r, w, s, z, gamma_new, delta_new, rs, ps = jax.lax.cond(
+                just_replaced, _replace_step,
+                lambda a: _keep(a, gamma_new, delta_new),
+                (x, r, w, p, s, z))
+        else:
+            just_replaced = jnp.asarray(False)
+            rs = ps = zero_s
 
         # exit certification (see docstring): a recurred gamma that would
         # exit the loop is re-derived from the true residual before the
         # exit decision stands — paid only on candidate iterations
-        def _certify(args):
-            x, r, w, p, s, z = args
-            r, w, s, z = _replace_state(x, r, w, p, s, z)
-            g, d = dot2(r, r, w, r)
-            return r, w, s, z, g, d
-
         if certify:
             cand = _exit_test(gamma_new, k + 1)
             if batched:
@@ -811,31 +837,46 @@ def cg_pipelined_while(matvec, dot2, b, x0, stop2, maxits: int,
                 need = cand & ~just_replaced
 
                 def _certify_sel(args):
-                    rc, wc, sc, zc, gc, dc = _certify(args)
+                    rc, wc, sc, zc, gc, dc, rsc, psc = _replace_step(args)
                     m = bc(need)
                     return (jnp.where(m, rc, args[1]),
                             jnp.where(m, wc, args[2]),
                             jnp.where(m, sc, args[4]),
                             jnp.where(m, zc, args[5]),
                             jnp.where(need, gc, gamma_new),
-                            jnp.where(need, dc, delta_new))
+                            jnp.where(need, dc, delta_new),
+                            jnp.where(need, rsc, rs),
+                            jnp.where(need, psc, ps))
 
-                r, w, s, z, gamma_new, delta_new = jax.lax.cond(
+                r, w, s, z, gamma_new, delta_new, rs, ps = jax.lax.cond(
                     jnp.any(need), _certify_sel,
-                    lambda a: (a[1], a[2], a[4], a[5], gamma_new,
-                               delta_new),
+                    lambda a: _keep(a, gamma_new, delta_new)[:6] + (rs, ps),
                     (x, r, w, p, s, z))
             else:
                 # a just-replaced gamma_new IS the true residual — don't
                 # redo the identical replacement in the certifier
-                r, w, s, z, gamma_new, delta_new = jax.lax.cond(
-                    cand & ~just_replaced,
-                    _certify,
-                    lambda a: (a[1], a[2], a[4], a[5], gamma_new,
-                               delta_new),
+                need = cand & ~just_replaced
+                r, w, s, z, gamma_new, delta_new, rs2, ps2 = jax.lax.cond(
+                    need, _replace_step,
+                    lambda a: _keep(a, gamma_new, delta_new),
                     (x, r, w, p, s, z))
+                rs = jnp.where(need, rs2, rs)
+                ps = jnp.where(need, ps2, ps)
         else:
-            cand = jnp.asarray(False)
+            cand = need = jnp.asarray(False)
+        # the scalars the NEXT step derives beta and its denominator from:
+        # re-derived from a replaced state, else the recurred ones
+        replaced = just_replaced | need
+        usable = ((rs < 0.0) & (ps > 0.0) & (gamma_new <= 4.0 * g_rec)
+                  & (g_rec <= 4.0 * gamma_new))
+        beta_r = -rs / jnp.where(usable, ps, one)
+        gamma_prev_next = jnp.where(
+            replaced & usable,
+            gamma_new / jnp.where(usable, beta_r, one), gamma)
+        alpha_prev_next = jnp.where(
+            replaced & usable, -gamma_new / jnp.where(usable, rs, one),
+            alpha)
+        bad = bad | (replaced & ~usable)
         if batched:
             # freeze finished systems: carries, per-system scalars, and
             # the history row all stop advancing
@@ -844,8 +885,8 @@ def cg_pipelined_while(matvec, dot2, b, x0, stop2, maxits: int,
                                 for v, o in zip((x, r, w, p, s, z), olds))
             gamma_new = jnp.where(active, gamma_new, gamma)
             delta_new = jnp.where(active, delta_new, delta)
-            gamma_prev = jnp.where(active, gamma, gamma_prev)
-            alpha_prev = jnp.where(active, alpha, alpha_prev)
+            gamma_prev = jnp.where(active, gamma_prev_next, gamma_prev)
+            alpha_prev = jnp.where(active, alpha_prev_next, alpha_prev)
             fresh = jnp.where(active, bad, fresh)
             certified = jnp.where(active, cand | just_replaced, certified)
             hist = hist.at[:, k + 1].set(jnp.where(active, gamma_new,
@@ -864,8 +905,8 @@ def cg_pipelined_while(matvec, dot2, b, x0, stop2, maxits: int,
                     alpha_prev, k + 1, fresh, certified, hist, done, ksys)
         hist = hist.at[k + 1].set(gamma_new)
         _maybe_monitor(monitor, monitor_every, k + 1, gamma_new)
-        return (x, r, w, p, s, z, gamma_new, delta_new, gamma, alpha,
-                k + 1, bad, cand | just_replaced, hist)
+        return (x, r, w, p, s, z, gamma_new, delta_new, gamma_prev_next,
+                alpha_prev_next, k + 1, bad, cand | just_replaced, hist)
 
     if carry_in is None:
         true0 = jnp.full(jnp.shape(gamma0), True)
@@ -1028,6 +1069,9 @@ def cg_pipelined_deep_while(matvec, dots, dot, b, x0, stop2, depth: int,
     makes kret/rr/flag/drift per-system (B,) with the usual frozen-
     system discipline."""
     batched = b.ndim == 2
+    # the deep pipeline's segments are its replacement schedule; unset
+    # means one segment (its drift ladder restarts, see docstring)
+    replace_every = replace_every or 0
     # window width: the basis-change band is 2l+1 (p_l(A) v_{i-l}
     # spreads l rows DOWN the Krylov basis as well as up)
     l, w = depth, 2 * depth + 1

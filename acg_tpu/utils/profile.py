@@ -21,11 +21,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from acg_tpu.config import replace_period
 from acg_tpu.solvers.base import SolveStats
-from acg_tpu.utils.compat import install_shard_map_compat
 from acg_tpu.utils.stats import time_op
-
-install_shard_map_compat()
 
 
 def _fill(c, t_once: float, n: int, bytes_once: int, flops_once: int):
@@ -77,6 +75,7 @@ def profile_ops(dev, stats: SolveStats, niterations: int,
 
     # counts per the algorithm cadence (+1 gemv/dot for the r0 prologue;
     # +4 matvecs per residual-replacement step, acg_tpu/solvers/loops.py)
+    replace_every = replace_period(replace_every, vdt)
     ngemv = k + 1 + (4 * (k // replace_every)
                      if pipelined and replace_every else 0)
     ndots = 2 * k + 1
@@ -174,6 +173,7 @@ def profile_dist_ops(ss, stats: SolveStats, niterations: int,
         check_vma=False))
     t_axpy = time_op(axpy_jit, x_sh, x_sh)
 
+    replace_every = replace_period(replace_every, ss.vec_dtype)
     ngemv = k + 1 + (4 * (k // replace_every)
                      if pipelined and replace_every else 0)
     ndots = 2 * k + 1

@@ -33,9 +33,9 @@ SEG = 150
 
 
 def main():
-    from acg_tpu.utils.backend import devices_or_die
+    from acg_tpu.utils.backend import require_tpu
 
-    print("device_kind:", devices_or_die()[0].device_kind, flush=True)
+    print("device_kind:", require_tpu()[0].device_kind, flush=True)
 
     import jax.numpy as jnp
 

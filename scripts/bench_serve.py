@@ -468,9 +468,9 @@ def main(argv=None) -> int:
         force_cpu_mesh(args.cpu_mesh)
     if args.sequence:
         if not args.dry_run and not args.cpu_mesh:
-            from acg_tpu.utils.backend import devices_or_die
+            from acg_tpu.utils.backend import require_tpu
 
-            devices_or_die()
+            require_tpu()
         return run_sequence(args)
 
     from acg_tpu.config import SolverOptions
@@ -480,9 +480,9 @@ def main(argv=None) -> int:
     if args.dry_run:
         grid, n_req, jitter, maxits = 8, 8, 0.0, 40
     else:
-        from acg_tpu.utils.backend import devices_or_die
+        from acg_tpu.utils.backend import require_tpu
 
-        devices_or_die()
+        require_tpu()
         grid, n_req = args.grid, args.n_requests
         jitter, maxits = args.jitter_ms / 1e3, 400
 

@@ -1209,9 +1209,9 @@ def main(argv=None) -> int:
             else "cg:2,cg-pipelined-deep:2" if args.fleet
             else "cg:1,cg-pipelined:4")
     else:
-        from acg_tpu.utils.backend import devices_or_die
+        from acg_tpu.utils.backend import require_tpu
 
-        devices_or_die()
+        require_tpu()
         grid, maxits, n = args.grid, 600, args.n_requests
         cooldown_ms, service_ms, deadline_ms = 500.0, 250.0, 400.0
         configs = args.configs or (

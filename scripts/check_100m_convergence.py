@@ -36,9 +36,9 @@ T0 = time.time()
 
 
 def main():
-    from acg_tpu.utils.backend import devices_or_die
+    from acg_tpu.utils.backend import require_tpu
 
-    devices_or_die()
+    require_tpu()
     import jax
     import jax.numpy as jnp
 

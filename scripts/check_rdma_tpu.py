@@ -10,7 +10,10 @@ a loopback transfer whose payload must round-trip bit-exactly.  On a
 multi-chip mesh the identical program moves the same slots between
 chips; run with more devices when a real mesh is available.
 
-Usage: python scripts/check_rdma_tpu.py   (uses the default platform)
+The solver refuses the rdma tier (ERR_NOT_SUPPORTED) until a multi-chip
+run proves it; this loopback check is not that proof.
+
+Usage: python scripts/check_rdma_tpu.py   (needs a TPU)
 Prints one JSON line {"rdma_loopback": "ok", ...} on success.
 """
 
@@ -24,14 +27,9 @@ import numpy as np
 
 
 def main():
-    from acg_tpu.utils.backend import devices_or_die
+    from acg_tpu.utils.backend import require_tpu
 
-    devs = devices_or_die()
-    if devs[0].platform != "tpu":
-        print(json.dumps({"rdma_loopback": "skipped",
-                          "reason": f"platform {devs[0].platform}, "
-                                    "Mosaic remote DMA needs TPU"}))
-        return 0
+    devs = require_tpu()
 
     import jax
     import jax.numpy as jnp

@@ -178,7 +178,7 @@ def _stagnation_probe(A, hub, solver: str, dtype) -> dict:
         return {"niterations": int(res.niterations),
                 "iters_per_sec": float(measured),
                 "predicted_iters_per_sec":
-                    float(model.predicted_iters_per_sec),
+                    model.as_dict()["predicted_iters_per_sec"],
                 "findings": [f.kind for f in found + drift]}
     finally:
         sess.close()
@@ -322,9 +322,9 @@ def main(argv=None) -> int:
 
         force_cpu_mesh(8)
     else:
-        from acg_tpu.utils.backend import devices_or_die
+        from acg_tpu.utils.backend import require_tpu
 
-        devices_or_die()
+        require_tpu()
     if args.dry_run:
         args.grid, args.maxits = 10, 200
         args.scrapes, args.requests_per_scrape = 2, 3

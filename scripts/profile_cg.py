@@ -16,9 +16,9 @@ from acg_tpu.sparse import poisson3d_7pt
 GRID = 128
 REPS = 200
 
-from acg_tpu.utils.backend import devices_or_die
+from acg_tpu.utils.backend import require_tpu
 
-dev = devices_or_die()[0]
+dev = require_tpu()[0]
 print("device_kind:", dev.device_kind)
 
 dtype = np.float32

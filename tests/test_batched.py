@@ -42,24 +42,28 @@ def _hist_close(got, want, rtol, floor_rel):
 
 
 def _assert_matches_sequential(solver, A, bs, opts=OPTS, x_rtol=1e-6,
-                               hist_rtol=1e-5, floor_rel=1e-14, **kw):
-    """Batched solve == the B independent solves: same per-system
-    iteration counts, matching trajectories and solutions."""
+                               hist_rtol=1e-5, floor_rel=1e-14,
+                               its_slack=0, **kw):
+    """Batched solve == the B independent solves: per-system iteration
+    counts within ``its_slack``, matching trajectories and solutions."""
     seq = [solver(A, b, options=opts, **kw) for b in bs]
     res = solver(A, np.stack(bs), options=opts, **kw)
     assert res.nrhs == len(bs)
-    assert list(res.iterations_per_system) == [r.niterations for r in seq]
-    assert res.niterations == max(r.niterations for r in seq)
+    its = [int(k) for k in res.iterations_per_system]
+    assert all(abs(k - r.niterations) <= its_slack
+               for k, r in zip(its, seq)), (its, [r.niterations for r in seq])
+    assert res.niterations == max(its)
     assert bool(res.converged) and all(res.converged_per_system)
     for i, r in enumerate(seq):
         np.testing.assert_allclose(res.x[i], r.x, rtol=x_rtol,
                                    atol=x_rtol * np.abs(r.x).max())
         hi = res.residual_history[i]
-        _hist_close(hi[: r.niterations + 1], r.residual_history,
+        k = min(its[i], r.niterations)
+        _hist_close(hi[: k + 1], r.residual_history[: k + 1],
                     hist_rtol, floor_rel)
         # the active-mask freeze: history stops advancing at this
         # system's own exit (NaN fill past it)
-        assert np.all(np.isnan(hi[r.niterations + 1:]))
+        assert np.all(np.isnan(hi[its[i] + 1:]))
     return res, seq
 
 
@@ -94,15 +98,19 @@ def test_batched_matches_sequential_f32_bf16_bands():
     A = poisson2d_5pt(12)
     b1, b2 = _rhs_pair(A)
     opts = SolverOptions(maxits=400, residual_rtol=1e-5)
+    # f32 reductions over the batch axis and over one vector are summed
+    # in different orders, and the XLA of jax 0.9 fuses them differently
+    # enough that a system can cross rtol 1e-5 one iteration apart: the
+    # claim at f32 is the same exit within one iteration
     for mat_dtype in ("auto", None):
         _assert_matches_sequential(cg, A, (b1, b2), opts=opts,
                                    x_rtol=2e-3, hist_rtol=1e-2,
-                                   floor_rel=1e-7, dtype=np.float32,
-                                   mat_dtype=mat_dtype)
+                                   floor_rel=1e-7, its_slack=1,
+                                   dtype=np.float32, mat_dtype=mat_dtype)
         _assert_matches_sequential(cg_pipelined, A, (b1, b2), opts=opts,
                                    x_rtol=2e-3, hist_rtol=5e-2,
-                                   floor_rel=1e-6, dtype=np.float32,
-                                   mat_dtype=mat_dtype)
+                                   floor_rel=1e-6, its_slack=1,
+                                   dtype=np.float32, mat_dtype=mat_dtype)
 
 
 def test_batched_sgell_interpret():
@@ -285,9 +293,11 @@ def test_batched_dist_matches_sequential():
         for i, r in enumerate(seq):
             np.testing.assert_allclose(res.x[i], r.x, rtol=1e-6,
                                        atol=1e-10)
-            np.testing.assert_allclose(
-                res.residual_history[i][: r.niterations + 1],
-                r.residual_history, rtol=1e-6, atol=1e-30)
+            # same trajectory down to the attainable-accuracy floor
+            # (below it both are rounding noise: 3.9e-6 apart at 1.6e-20
+            # under jax 0.9)
+            _hist_close(res.residual_history[i][: r.niterations + 1],
+                        r.residual_history, 1e-6, 1e-14)
 
 
 def test_batched_dist_allgather_halo():

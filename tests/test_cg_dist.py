@@ -224,9 +224,9 @@ def test_dist_auto_keeps_ell_for_scattered():
     np.testing.assert_allclose(res.x, xstar, atol=1e-7)
 
 
-def test_halo_rdma_clear_error_off_tpu():
-    """--halo rdma needs real multi-chip TPU; elsewhere the error must be
-    immediate and actionable, not a Mosaic compile failure."""
+def test_halo_rdma_refused():
+    """--halo rdma is unverified across chips: refused on every platform
+    with an immediate, actionable error, never a wrong or silent result."""
     A = poisson2d_5pt(8)
     with pytest.raises(AcgError) as ei:
         build_sharded(A, nparts=4, method=HaloMethod.RDMA)

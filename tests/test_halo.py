@@ -113,3 +113,22 @@ def test_rdma_halo_traces():
     # abstract evaluation only (no device execution)
     out = jax.eval_shape(mapped, x, ss.send_idx, ss.recv_idx, ss.partner)
     assert out.shape == (4, ss.nghost_max)
+
+
+def test_place_round_writes_runs_without_scatter():
+    """A round's receive slots are one contiguous run (pad = G): the
+    masked dynamic_update_slice places it in any round order, leaves
+    inactive rounds out, and lowers without a scatter (contract C5)."""
+    from acg_tpu.parallel.halo import ghost_buffer, place_round
+
+    G, S = 6, 3
+    g = ghost_buffer(jnp.zeros(4), S, G)
+    rounds = [(jnp.array([4., 5., 9.]), jnp.array([4, 5, G])),
+              (jnp.array([7., 7., 7.]), jnp.array([G, G, G])),
+              (jnp.array([1., 2., 3.]), jnp.array([1, 2, 3]))]
+    for rbuf, rows in rounds:
+        g = place_round(g, rbuf, rows.astype(jnp.int32), G)
+    np.testing.assert_array_equal(np.asarray(g[:G]), [0, 1, 2, 3, 4, 5])
+    txt = jax.jit(lambda g, r, i: place_round(g, r, i, G)).lower(
+        g, rounds[0][0], rounds[0][1].astype(jnp.int32)).as_text()
+    assert "scatter" not in txt

@@ -35,6 +35,32 @@ def test_init_multihost_single_process_noop():
     assert jax.process_count() == 1
 
 
+@pytest.mark.parametrize("env,raises", [
+    ({"TPU_WORKER_HOSTNAMES": "localhost"}, False),
+    ({"TPU_WORKER_HOSTNAMES": "h0,h1,h2,h3"}, True),
+    ({"TPU_WORKER_HOSTNAMES": "h0", "MEGASCALE_NUM_SLICES": "2"}, True),
+    ({"SLURM_NTASKS": "4"}, True),
+])
+def test_init_multihost_without_coordinator(monkeypatch, env, raises):
+    """No coordinator given: a host the environment names as single runs
+    single-process; an environment naming several processes raises
+    instead of degrading to independent single-host runs (and JAX's
+    metadata-server autodetection is never tried)."""
+    from acg_tpu.errors import AcgError, Status
+
+    monkeypatch.delenv("JAX_COORDINATOR_ADDRESS", raising=False)
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    if raises:
+        with pytest.raises(AcgError) as ei:
+            init_multihost()
+        assert ei.value.status == Status.ERR_NOT_SUPPORTED
+        assert "JAX_COORDINATOR_ADDRESS" in str(ei.value)
+    else:
+        init_multihost()
+        assert jax.process_count() == 1
+
+
 def test_make_global_array_roundtrip():
     mesh = make_mesh(8)
     shard = jax.sharding.NamedSharding(

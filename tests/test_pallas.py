@@ -241,11 +241,13 @@ def test_pallas_probe_false_on_cpu():
         assert pk.pallas_spmv_available("resident2d") is False
         assert pk.pallas_spmv_available("fused2d") is False
         assert pk.pallas_spmv_available("hbm2d") is False
+        # the reason is kept, not swallowed
+        assert pk.probe_errors()["fused2d"].startswith("no TPU")
     finally:
         pk._SPMV_PROBE.clear()
 
 
-# ── ELL gather kernel (acg_tpu/ops/pallas_spmv.py) ───────────────────────
+# ── ELL gather kernel (acg_tpu/ops/pallas_spmv.py; out of auto routing) ───
 
 def test_ell_matvec_pallas_matches_oracle():
     from acg_tpu.ops.pallas_spmv import ell_matvec_pallas
@@ -280,27 +282,33 @@ def test_ell_matvec_pallas_scattered_bf16():
                                    rtol=1e-5, atol=1e-6)
 
 
-def test_ell_probe_false_on_cpu_and_best_falls_back():
+def test_ell_forced_raises_without_probe_and_runs_interpreted():
+    """The ELL kernel is out of automatic routing (OUT_OF_AUTO): a forced
+    call raises ERR_NOT_SUPPORTED where its probe fails (here: no TPU),
+    never falling back quietly; interpreted, it matches the oracle."""
+    from acg_tpu.errors import AcgError, Status
     from acg_tpu.ops import pallas_kernels as pk
-    from acg_tpu.ops import pallas_spmv as pe
+    from acg_tpu.ops.pallas_spmv import ell_matvec_forced
     from acg_tpu.ops.spmv import ell_matvec
 
+    assert "ell" in pk.OUT_OF_AUTO and "ell" in pk._PROBE_GROUPS
+    rng = np.random.default_rng(23)
+    n, W = 256, 5
+    vals = jnp.asarray(rng.standard_normal((n, W)).astype(np.float32))
+    cols = jnp.asarray(rng.integers(0, n, (n, W)).astype(np.int32))
+    x = jnp.asarray(rng.standard_normal(n).astype(np.float32))
     pk._SPMV_PROBE.pop("ell", None)
     try:
-        assert pe.pallas_ell_available() is False
-        rng = np.random.default_rng(23)
-        n, W = 256, 5
-        vals = jnp.asarray(rng.standard_normal((n, W)).astype(np.float32))
-        cols = jnp.asarray(rng.integers(0, n, (n, W)).astype(np.int32))
-        x = jnp.asarray(rng.standard_normal(n).astype(np.float32))
-        y = pe.ell_matvec_best(vals, cols, x)       # must take XLA path
-        np.testing.assert_allclose(np.asarray(y),
-                                   np.asarray(ell_matvec(vals, cols, x)),
-                                   rtol=1e-6)
+        with pytest.raises(AcgError) as ei:
+            ell_matvec_forced(vals, cols, x)
+        assert ei.value.status == Status.ERR_NOT_SUPPORTED
+        assert "no TPU" in str(ei.value)
     finally:
         pk._SPMV_PROBE.pop("ell", None)
-
-
+    y = ell_matvec_forced(vals, cols, x, interpret=True)
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(ell_matvec(vals, cols, x)),
+                               rtol=1e-5, atol=1e-6)
 
 
 def test_dia_matvec_best_routes_to_hbm2d(monkeypatch):

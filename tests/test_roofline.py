@@ -4,10 +4,11 @@ chip-table resolution, batched scaling, and the sharded variant."""
 import numpy as np
 import pytest
 
-from acg_tpu.obs.roofline import (CHIP_HBM_GBPS, DEFAULT_HBM_GBPS,
+from acg_tpu.obs.roofline import (CHIP_HBM_GBPS,
                                   RooflineModel, hbm_gbps_for,
                                   roofline_for_operator,
                                   roofline_for_sharded)
+from acg_tpu.errors import AcgError, Status
 from acg_tpu.solvers.base import _cg_blas1_bytes
 from acg_tpu.sparse import poisson2d_5pt, poisson3d_7pt
 
@@ -25,10 +26,26 @@ def test_hbm_gbps_resolution():
     assert hbm_gbps_for("TPU v5p") == CHIP_HBM_GBPS["TPU v5p"]
     # longest-substring match: "TPU v5 lite" must NOT hit "TPU v5"
     assert hbm_gbps_for("TPU v5 lite") == CHIP_HBM_GBPS["TPU v5 lite"]
-    assert hbm_gbps_for("cpu") == DEFAULT_HBM_GBPS
-    assert hbm_gbps_for(None) == DEFAULT_HBM_GBPS
     # an explicit override always wins
     assert hbm_gbps_for("TPU v5e", override=100.0) == 100.0
+    assert hbm_gbps_for("cpu", override=100.0) == 100.0
+
+
+@pytest.mark.parametrize("kind", ["cpu", None, "TPU v9x"])
+def test_hbm_gbps_unknown_kind_raises(kind):
+    # no default peak: a kind without a published figure is an error
+    with pytest.raises(AcgError) as e:
+        hbm_gbps_for(kind)
+    assert e.value.status == Status.ERR_NOT_SUPPORTED
+
+
+def test_roofline_off_chip_is_not_measured():
+    _, dev = _dia_dev()
+    m = roofline_for_operator(dev, solver="cg")     # CPU: no device kind
+    assert m.hbm_gbps is None and m.device_kind is None
+    assert m.as_dict()["predicted_iters_per_sec"] is None
+    assert "predicted ceiling: not measured" in m.report()
+    assert m.frac(100.0) != m.frac(100.0)           # NaN
 
 
 def test_dia_operator_bytes_at_storage_width():

@@ -271,3 +271,18 @@ def test_sgell_idx_narrow_gating(monkeypatch):
     assert sgell_idx_narrow(idx).dtype == np.int8
     # interpret mode always keeps int32
     assert sgell_idx_narrow(idx, interpret=True).dtype == np.int32
+
+
+def test_pack_refuses_more_slots_than_smem_holds(monkeypatch):
+    """The kernel's scalar-prefetch tables live in SMEM (1 MiB on v5e):
+    a pack with more slots than MAX_SLOTS does not take the tier."""
+    from acg_tpu.ops import sgell as sg
+    from acg_tpu.sparse import poisson2d_5pt
+
+    A = poisson2d_5pt(64, dtype=np.float32)
+    full = sg.pack_csr(A, np.float32)
+    assert full["vals"] is not None and full["S"] > 2
+    monkeypatch.setattr(sg, "MAX_SLOTS", 2)
+    assert sg.pack_csr(A, np.float32)["vals"] is None
+    assert sg.build_device_sgell(A, dtype=np.float32, min_fill=0.0,
+                                 interpret=True) is None

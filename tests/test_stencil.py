@@ -162,6 +162,24 @@ def test_auto_stays_stored_without_probe():
     assert isinstance(dev, DeviceDia)
 
 
+def test_auto_prefers_dia_kernel_past_stencil_vmem(monkeypatch):
+    """Past the stencil kernel's VMEM bound auto takes the stored DIA
+    tier when ITS Pallas kernel plans (256³ f32 on the chip: the HBM-ring
+    kernel, not the XLA grid-shift form); with the bound back, the
+    matrix-free tier wins again."""
+    from acg_tpu.ops import pallas_kernels as pk
+    from acg_tpu.ops import stencil as st
+
+    monkeypatch.setitem(pk._SPMV_PROBE, "stencil2d", True)
+    monkeypatch.setitem(pk._SPMV_PROBE, "fused2d", True)
+    A = poisson3d_7pt(16, dtype=np.float32)
+    assert isinstance(build_device_operator(A, dtype=np.float32),
+                      DeviceStencil)
+    monkeypatch.setattr(st, "_VMEM_BUDGET", 1024)
+    dev = build_device_operator(A, dtype=np.float32)
+    assert isinstance(dev, DeviceDia)
+
+
 def test_auto_engages_with_probe(monkeypatch):
     from acg_tpu.ops import pallas_kernels as pk
 
@@ -184,14 +202,18 @@ def test_forced_stencil_errors_on_non_stencil():
 # -- end-to-end single chip -------------------------------------------------
 
 
-def test_cg_bit_consistent_with_dia_f64():
+def test_cg_consistent_with_dia_f64():
     A = poisson3d_7pt(10)
     _, b = manufactured_rhs(A, seed=0)
     r_d = cg(A, b, options=OPTS, fmt="dia")
     r_s = cg(A, b, options=OPTS, fmt="stencil")
     assert r_s.converged
     assert r_s.niterations == r_d.niterations
-    assert np.array_equal(r_s.x, r_d.x)
+    # two programs (in-register coefficients vs streamed bands): XLA may
+    # order the 7-term sum differently, so they agree to rounding, not
+    # bit for bit
+    np.testing.assert_allclose(r_s.x, r_d.x, rtol=1e-12,
+                               atol=1e-14 * np.abs(r_d.x).max())
     assert r_s.operator_format == "stencil"
     assert r_s.kernel == "xla-gridshift"
     # certified true residual
@@ -199,14 +221,16 @@ def test_cg_bit_consistent_with_dia_f64():
     assert rres < 1e-9
 
 
-def test_cg_pipelined_bit_consistent_with_dia_f64():
+def test_cg_pipelined_consistent_with_dia_f64():
     A = poisson3d_7pt(10)
     _, b = manufactured_rhs(A, seed=1)
     r_d = cg_pipelined(A, b, options=OPTS, fmt="dia")
     r_s = cg_pipelined(A, b, options=OPTS, fmt="stencil")
     assert r_s.converged
     assert r_s.niterations == r_d.niterations
-    assert np.array_equal(r_s.x, r_d.x)
+    # agreement to rounding, not bits: see the classic test above
+    np.testing.assert_allclose(r_s.x, r_d.x, rtol=1e-10,
+                               atol=1e-12 * np.abs(r_d.x).max())
     assert "stpipe2d disengaged" in r_s.kernel_note
 
 
