@@ -1,0 +1,6 @@
+"""device_idle_share.solve: percent of the traced window in which no
+operation ran on the device, mean over the cell's chips (solve loops)."""
+
+
+def read(run):
+    return None if run.trace is None else 100.0 * run.trace.idle_share
