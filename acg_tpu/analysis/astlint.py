@@ -20,6 +20,10 @@ Each rule encodes a hazard this repo has already paid for once:
   monitor path (``acg_tpu/obs/monitor.py``) — an unthrottled callback
   is a per-iteration host transfer, exactly what contract rule C6
   fails compiled programs for.
+- **E5 kernel-name**: a ``pallas_call`` without ``name=`` — an unnamed
+  kernel is traced under a name that moves with every compile, so no
+  profile reduction can find it again (acg_tpu/obs/trace.py names
+  kernels ``acg_<kernel>``).
 
 Deliberate exceptions carry a ``# acg: allow-<rule>`` pragma on the
 offending line (or the line above).  ``scripts/lint_source.py`` runs
@@ -42,6 +46,7 @@ RULES = {
     "traced-branch": "E3: Python branch/cast on a traced loop-carry "
                      "value inside a while-loop body",
     "debug-callback": "E4: jax.debug outside the throttled monitor path",
+    "kernel-name": "E5: pallas_call without a stable name= (acg_<kernel>)",
 }
 
 # rule E1/E3 apply to the hot subpackages only (host-side preprocessing
@@ -176,6 +181,11 @@ class _Visitor(ast.NodeVisitor):
             if not explicit:
                 self._emit(node, "axis-name",
                            f"{leaf}() without an explicit axis name")
+        if leaf == "pallas_call" \
+                and not any(kw.arg == "name" for kw in node.keywords):
+            self._emit(node, "kernel-name",
+                       "pallas_call() without name=: give the kernel a "
+                       "stable acg_<kernel> name")
         if self._in_loop_fn() and leaf in ("float", "int", "bool") \
                 and name == leaf and node.args \
                 and self._touches_params(node.args[0]):

@@ -39,6 +39,15 @@ __all__ = [
 ]
 
 
+def allreduce(v, axis_name):
+    """``v`` summed over the mesh axis (``acg.allreduce``), or ``v`` off
+    the mesh."""
+    if not axis_name:
+        return v
+    with jax.named_scope("acg.allreduce"):
+        return jax.lax.psum(v, axis_name)
+
+
 @functools.partial(jax.jit, inline=True)
 def dscal(a, x):
     """x <- a*x (ref acgvector_dscal, acg/vector.c:482)."""
@@ -112,7 +121,7 @@ def gram(V, axis_name: str | None = None):
         G = jnp.einsum("ibn,jbn->bij", V, V, precision=prec)
     else:
         G = jnp.matmul(V, V.T, precision=prec)
-    return jax.lax.psum(G, axis_name) if axis_name else G
+    return allreduce(G, axis_name)
 
 
 def block_dot(V, w, axis_name: str | None = None):
@@ -126,7 +135,7 @@ def block_dot(V, w, axis_name: str | None = None):
         d = jnp.einsum("ibn,bn->bi", V, w, precision=prec)
     else:
         d = jnp.matmul(V, w, precision=prec)
-    return jax.lax.psum(d, axis_name) if axis_name else d
+    return allreduce(d, axis_name)
 
 
 @functools.partial(jax.jit, static_argnames=("nexclude", "axis_name"))
@@ -135,7 +144,7 @@ def ddot(x, y, nexclude: int = 0, axis_name: str | None = None):
     over ``axis_name`` when given (ref acgvector_ddot / _ddotmpi,
     acg/vector.c:561-594,843).  Batched operands reduce per system."""
     d = batched_dot(_mask_tail(x, nexclude), _mask_tail(y, nexclude))
-    return jax.lax.psum(d, axis_name) if axis_name else d
+    return allreduce(d, axis_name)
 
 
 @functools.partial(jax.jit, static_argnames=("nexclude", "axis_name"))
@@ -144,7 +153,7 @@ def dnrm2sqr(x, nexclude: int = 0, axis_name: str | None = None):
     acg/vector.c:620)."""
     xm = _mask_tail(x, nexclude)
     d = batched_dot(xm, xm)
-    return jax.lax.psum(d, axis_name) if axis_name else d
+    return allreduce(d, axis_name)
 
 
 @functools.partial(jax.jit, static_argnames=("nexclude", "axis_name"))
@@ -157,7 +166,7 @@ def dnrm2(x, nexclude: int = 0, axis_name: str | None = None):
 def dasum(x, nexclude: int = 0, axis_name: str | None = None):
     """sum |x_i| (ref acgvector_dasum, acg/vector.c:652)."""
     d = jnp.sum(jnp.abs(_mask_tail(x, nexclude)), axis=-1)
-    return jax.lax.psum(d, axis_name) if axis_name else d
+    return allreduce(d, axis_name)
 
 
 @functools.partial(jax.jit, static_argnames=("nexclude",))

@@ -47,13 +47,15 @@ LANES = 128
 TILE_ROWS = 8          # float32 min sublane tile
 
 
-def pallas_call(kernel, **kw):
+def pallas_call(kernel, *, name: str, **kw):
     """``pl.pallas_call`` traced with 64-bit types off.  Under
     ``jax_enable_x64`` the kernels' index arithmetic traces as int64,
     and Mosaic's lowering of the int64 -> int32 conversion recurses
     without end (jax 0.9); every kernel in this package goes through
-    here so an f64 solve elsewhere in the process cannot break them."""
-    call = pl.pallas_call(kernel, **kw)
+    here so an f64 solve elsewhere in the process cannot break them.
+    ``name`` (``acg_<kernel>``) is the kernel's stable name in profiler
+    traces and compiled programs (acg_tpu/obs/trace.py)."""
+    call = pl.pallas_call(kernel, name=name, **kw)
     if kw.get("interpret"):
         return call               # the interpreter runs any dtype
 
@@ -133,6 +135,7 @@ def dia_matvec_pallas_2d(bands, offsets: tuple, x, rows_tile: int = 512,
           else jnp.zeros((D,), dtype=x.dtype))
     y = pallas_call(
         functools.partial(_dia2d_kernel, offsets, rows_tile, scaled),
+        name="acg_dia2d",
         out_shape=jax.ShapeDtypeStruct((R, LANES), x.dtype),
         grid=(R // rows_tile,),
         in_specs=[
@@ -234,6 +237,7 @@ def dia_matvec_pallas_2d_padded(bands_pad, offsets: tuple, x_pad,
     outs = pallas_call(
         functools.partial(_dia2d_padded_kernel, offsets, rows_tile, scaled,
                           with_dot),
+        name="acg_dia2d_padded",
         out_shape=tuple(out_shape),
         grid=(ntiles,),
         in_specs=[
@@ -329,6 +333,7 @@ def cg_pipelined_iter_pallas(bands_pad, offsets: tuple, w_pad, z_pad,
     vec = jax.ShapeDtypeStruct((Rp, LANES), dt)
     outs = pallas_call(
         functools.partial(_pipe2d_kernel, offsets, rows_tile, scaled),
+        name="acg_pipe2d",
         out_shape=(vec,) * 6 + (jax.ShapeDtypeStruct((1, 2), dt),),
         grid=(ntiles,),
         in_specs=[
@@ -425,6 +430,7 @@ def dia_matvec_pallas_2d_padded_batched(bands_pad, offsets: tuple, x_pad,
     outs = pallas_call(
         functools.partial(_dia2d_padded_batched_kernel, offsets, rows_tile,
                           scaled, with_dot),
+        name="acg_dia2d_padded_batched",
         out_shape=tuple(out_shape),
         grid=(ntiles, B),
         in_specs=[
@@ -645,6 +651,7 @@ def dia_matvec_pallas_hbm2d(bands_pad, offsets: tuple, x_pad,
     outs = pallas_call(
         functools.partial(_dia_hbm2d_kernel, windows, rows_tile, scaled,
                           with_dot, Rp, nbuf),
+        name="acg_dia_hbm2d",
         out_shape=tuple(out_shape),
         grid=(ntiles,),
         in_specs=[
@@ -812,6 +819,7 @@ def dia_matvec_pallas_hbm2d_ring(bands_pad, offsets: tuple, x_pad,
         functools.partial(_dia_hbm2d_ring_kernel, offsets, rows_tile,
                           T_ring, qmin_t, qmax_t, scaled, with_dot,
                           ntiles),
+        name="acg_dia_hbm2d_ring",
         out_shape=tuple(out_shape),
         grid=(ntiles,),
         in_specs=[

@@ -62,6 +62,7 @@ def ell_matvec_pallas(vals, colidx, x, tile: int = 512,
     xp = x.reshape(1, n)
     y = pallas_call(
         _ell_kernel,
+        name="acg_ell",
         out_shape=jax.ShapeDtypeStruct((n, 1), x.dtype),
         grid=(n // tile,),
         in_specs=[

@@ -319,6 +319,7 @@ def sgell_matvec_pallas(vals, idx, seg, tile, first, x_pad,
     )
     y = pallas_call(
         _sgell_kernel, grid_spec=grid_spec,
+        name="acg_sgell",
         out_shape=jax.ShapeDtypeStruct((ntiles * SUBL, LANES), x_pad.dtype),
         interpret=interpret,
     )(seg.reshape(-1), tile, first, *([x3d] * SUBL), vals, idx)

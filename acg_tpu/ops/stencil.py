@@ -380,6 +380,7 @@ def stencil_matvec_pallas_padded(grid: tuple, offsets: tuple,
     outs = pallas_call(
         functools.partial(_stencil2d_padded_kernel, grid, offsets, digits,
                           coeffs, rows_tile, n, hrows, with_dot),
+        name="acg_stencil2d_padded",
         out_shape=tuple(out_shape),
         grid=(ntiles,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
@@ -445,6 +446,7 @@ def stencil_matvec_pallas_padded_batched(grid: tuple, offsets: tuple,
     outs = pallas_call(
         functools.partial(_stencil2d_batched_kernel, grid, offsets,
                           digits, coeffs, rows_tile, n, hrows, with_dot),
+        name="acg_stencil2d_batched",
         out_shape=tuple(out_shape),
         grid=(ntiles, B),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
@@ -524,6 +526,7 @@ def cg_pipelined_iter_stencil(grid: tuple, offsets: tuple, digits: tuple,
     outs = pallas_call(
         functools.partial(_stpipe2d_kernel, grid, offsets, digits, coeffs,
                           rows_tile, n, hrows),
+        name="acg_stpipe2d",
         out_shape=(vec,) * 6 + (jax.ShapeDtypeStruct((1, 2), dt),),
         grid=(ntiles,),
         in_specs=[

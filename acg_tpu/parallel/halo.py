@@ -25,7 +25,10 @@ Two methods (config ``HaloMethod``):
   topologies).
 
 All tables are built in :func:`build_halo_tables` from the host-side
-:class:`~acg_tpu.partition.graph.PartitionedSystem`.
+:class:`~acg_tpu.partition.graph.PartitionedSystem`.  The exchange's ops
+carry the scopes ``acg.halo.pack`` (send-buffer gather, wire encode),
+``acg.halo.exchange`` (the collective) and ``acg.halo.unpack`` (wire
+decode, ghost placement).
 """
 
 from __future__ import annotations
@@ -294,20 +297,25 @@ def halo_ppermute(x_own, send_idx, recv_idx, perms, nghost_max: int,
     code path untouched — the traced program is bit-identical to one
     built before the wire option existed (the zero-overhead clause).
     """
-    ghosts = ghost_buffer(x_own, send_idx.shape[-1], nghost_max)
+    with jax.named_scope("acg.halo.unpack"):
+        ghosts = ghost_buffer(x_own, send_idx.shape[-1], nghost_max)
     for r, perm in enumerate(perms):
         if not perm:
             continue
-        # pad gathers 0; the send-pack gather is the halo design itself
-        sbuf = x_own[..., jnp.clip(send_idx[r], 0, None)]  # acg: allow-gather
-        if wire == "f32":
+        with jax.named_scope("acg.halo.pack"):
+            # pad gathers 0; the send-pack gather is the halo design
+            idx = jnp.clip(send_idx[r], 0, None)
+            sbuf = x_own[..., idx]  # acg: allow-gather
+            if wire != "f32":
+                sbuf = wire_encode(sbuf, wire)
+        with jax.named_scope("acg.halo.exchange"):
             rbuf = jax.lax.ppermute(sbuf, axis_name, perm)
-        else:
-            rbuf = wire_decode(
-                jax.lax.ppermute(wire_encode(sbuf, wire), axis_name, perm),
-                wire, x_own.dtype)
-        ghosts = place_round(ghosts, rbuf, recv_idx[r], nghost_max)
-    return jax.lax.slice_in_dim(ghosts, 0, nghost_max, axis=-1)
+        with jax.named_scope("acg.halo.unpack"):
+            if wire != "f32":
+                rbuf = wire_decode(rbuf, wire, x_own.dtype)
+            ghosts = place_round(ghosts, rbuf, recv_idx[r], nghost_max)
+    with jax.named_scope("acg.halo.unpack"):
+        return jax.lax.slice_in_dim(ghosts, 0, nghost_max, axis=-1)
 
 
 def ghost_buffer(x_own, max_msg: int, nghost_max: int):
@@ -338,16 +346,18 @@ def halo_allgather(x_own, pack_idx, ghost_src_part, ghost_src_pos,
     ``wire`` != "f32" gathers the encoded pack and decodes every part's
     replica before the (owner, position) gather, so the position tables
     are untouched by the int16-delta header offset."""
-    pack = x_own[..., jnp.clip(pack_idx, 0, None)]  # acg: allow-gather
-    if wire == "f32":
+    with jax.named_scope("acg.halo.pack"):
+        pack = x_own[..., jnp.clip(pack_idx, 0, None)]  # acg: allow-gather
+        if wire != "f32":
+            pack = wire_encode(pack, wire)
+    with jax.named_scope("acg.halo.exchange"):
         allpacks = jax.lax.all_gather(pack, axis_name)  # (P, [B,] pack)
-    else:
-        allpacks = wire_decode(
-            jax.lax.all_gather(wire_encode(pack, wire), axis_name),
-            wire, x_own.dtype)
-    if x_own.ndim == 2:
-        # gather (owner, position) per ghost, then put the system axis
-        # back in front: (G, B) -> (B, G)
-        return jnp.moveaxis(allpacks[ghost_src_part, :, ghost_src_pos],
-                            0, -1)
-    return allpacks[ghost_src_part, ghost_src_pos]
+    with jax.named_scope("acg.halo.unpack"):
+        if wire != "f32":
+            allpacks = wire_decode(allpacks, wire, x_own.dtype)
+        if x_own.ndim == 2:
+            # gather (owner, position) per ghost, then put the system
+            # axis back in front: (G, B) -> (B, G)
+            return jnp.moveaxis(
+                allpacks[ghost_src_part, :, ghost_src_pos], 0, -1)
+        return allpacks[ghost_src_part, ghost_src_pos]

@@ -81,6 +81,7 @@ def rdma_exchange(sendbuf: jax.Array, devices: jax.Array,
     sb = jnp.pad(sendbuf, ((0, 0), (0, Sp - S))).reshape(R, 8, Sp // 8)
     out = pallas_call(
         functools.partial(_rdma_kernel, nrounds),
+        name="acg_rdma_halo",
         out_shape=jax.ShapeDtypeStruct((R, 8, Sp // 8), sendbuf.dtype),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -105,11 +106,16 @@ def halo_rdma(x_own, send_idx, recv_idx, partner_row, nghost_max: int,
     ``partner_row`` its (R,) partner ids (-1 = inactive slot).
     """
     R = send_idx.shape[0]
-    me = jax.lax.axis_index(axis_name)
-    devices = jnp.where(partner_row >= 0, partner_row, me).astype(jnp.int32)
-    sendbuf = x_own[jnp.clip(send_idx, 0, None)]          # (R, S)
-    recvbuf = rdma_exchange(sendbuf, devices, nrounds=R)
-    ghosts = ghost_buffer(x_own, send_idx.shape[-1], nghost_max)
-    for r in range(R):
-        ghosts = place_round(ghosts, recvbuf[r], recv_idx[r], nghost_max)
-    return jax.lax.slice_in_dim(ghosts, 0, nghost_max, axis=-1)
+    with jax.named_scope("acg.halo.pack"):
+        me = jax.lax.axis_index(axis_name)
+        devices = jnp.where(partner_row >= 0, partner_row,
+                            me).astype(jnp.int32)
+        sendbuf = x_own[jnp.clip(send_idx, 0, None)]      # (R, S)
+    with jax.named_scope("acg.halo.exchange"):
+        recvbuf = rdma_exchange(sendbuf, devices, nrounds=R)
+    with jax.named_scope("acg.halo.unpack"):
+        ghosts = ghost_buffer(x_own, send_idx.shape[-1], nghost_max)
+        for r in range(R):
+            ghosts = place_round(ghosts, recvbuf[r], recv_idx[r],
+                                 nghost_max)
+        return jax.lax.slice_in_dim(ghosts, 0, nghost_max, axis=-1)

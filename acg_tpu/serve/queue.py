@@ -41,6 +41,7 @@ import numpy as np
 
 from acg_tpu.errors import AcgError, Status
 from acg_tpu.obs import metrics as _metrics
+from acg_tpu.obs.trace import span
 from acg_tpu.solvers.base import SolveResult, SolveStats
 
 # runtime telemetry (acg_tpu/obs/metrics.py; no-ops until
@@ -435,93 +436,100 @@ class CoalescingQueue:
                    left_behind: int = 0) -> None:
         nreal = len(batch)
         bucket = self.policy.bucket_for(nreal)
-        npad = bucket - nreal
-        # warm starts (ISSUE 20): a batch with ANY x0 aboard dispatches
-        # with an x0 operand — absent-x0 mates ride the zero vector
-        # (the exact donor a no-x0 solve starts from, so their demuxed
-        # results stay bit-identical); padding replicates the LAST
-        # ticket's effective x0, mirroring the b padding law.  A batch
-        # with no x0 calls the one-argument dispatch exactly as before
-        # (bare-queue users bind single-arg dispatchers).
-        any_x0 = any(t.x0 is not None for t in batch)
-        x0b = None
-        if bucket == 1:
-            bb = batch[0].b             # 1-D legacy path, bit-for-bit
-            if any_x0:
-                x0b = batch[0].x0
-        else:
-            # pad with REPLICAS of the last request (a duplicate system
-            # follows an identical trajectory and freezes with its twin;
-            # a zero system would trip the p'Ap breakdown guard)
-            bb = np.stack([t.b for t in batch]
-                          + [batch[-1].b] * npad)
-            if any_x0:
-                eff = [t.x0 if t.x0 is not None
-                       else np.zeros_like(t.b) for t in batch]
-                x0b = np.stack(eff + [eff[-1]] * npad)
-        t0 = time.perf_counter()
-        for i, t in enumerate(batch):
-            if t.trace is not None:
-                t.trace.event("coalesced", index=i, batch=nreal,
-                              bucket=bucket)
-        res, err, meta = None, None, {}
-        try:
-            res = (self._dispatch(bb) if x0b is None
-                   else self._dispatch(bb, x0b))
-            if isinstance(res, tuple):      # (SolveResult, meta) form
-                res, meta = res
-        except AcgError as e:
-            res = getattr(e, "result", None)
-            err = e
-            meta = getattr(e, "dispatch_meta", {})
-        except Exception as e:          # never strand waiting tickets
-            err = AcgError(Status.ERR_INVALID_VALUE,
-                           f"dispatch failed: {e}")
-        wall = time.perf_counter() - t0
-        self._last_dispatch_t = time.perf_counter()
-        self.counters["batches"] += 1
-        self.counters["padded"] += npad
-        self.counters["total_occupancy"] += nreal / bucket
-        _M_BATCHES.labels(bucket=bucket).inc()
-        _M_OCCUPANCY.observe(nreal / bucket)
-        for i, t in enumerate(batch):
-            t.index = i
-            t.batch_size = nreal
-            t.bucket = bucket
-            t.dispatch_wall = wall
-            t.depth_at_dispatch = left_behind
-            t.dispatch_meta = meta
-            t.queue_wait = t0 - t.enqueue_t
-            self.counters["total_wait"] += t.queue_wait
-            _M_WAIT.observe(t.queue_wait)
-            if t.trace is not None:
-                t.trace.event(
-                    "dispatch", wall_ms=round(wall * 1e3, 3),
-                    solver=meta.get("solver"),
-                    cache_hit=bool(meta.get("cache_hit", False)))
-            if res is not None:
-                my = demux_result(res, i,
-                                  bnrm2=float(np.linalg.norm(t.b)))
-                if my.converged or err is None:
-                    t.result_value = my
-                    t.error = None
+        # one batch: stack and pad the right-hand sides, dispatch,
+        # demultiplex into the tickets — each stage an acg.serve.* span
+        with span("acg.serve.batch", n=nreal, bucket=bucket):
+            npad = bucket - nreal
+            # warm starts (ISSUE 20): a batch with ANY x0 aboard dispatches
+            # with an x0 operand — absent-x0 mates ride the zero vector
+            # (the exact donor a no-x0 solve starts from, so their demuxed
+            # results stay bit-identical); padding replicates the LAST
+            # ticket's effective x0, mirroring the b padding law.  A batch
+            # with no x0 calls the one-argument dispatch exactly as before
+            # (bare-queue users bind single-arg dispatchers).
+            with span("acg.serve.stack"):
+                any_x0 = any(t.x0 is not None for t in batch)
+                x0b = None
+                if bucket == 1:
+                    bb = batch[0].b             # 1-D legacy path, bit-for-bit
+                    if any_x0:
+                        x0b = batch[0].x0
                 else:
-                    # per-request error carrying the demuxed partial
-                    # result, like the plain solvers' AcgError contract
-                    e = AcgError(my.status)
-                    e.result = my
-                    t.error = e
-            else:
-                t.error = err
-            t.done = True
-            if t.trace is not None:
-                st = (t.result_value.status.name
-                      if t.result_value is not None
-                      else getattr(getattr(t.error, "status", None),
-                                   "name", "ERR"))
-                t.trace.event("demux", index=i, status=st)
-        with self._cv:
-            self._inflight -= len(batch)
+                    # pad with REPLICAS of the last request (a duplicate
+                    # system follows an identical trajectory and freezes
+                    # with its twin; a zero system would trip the p'Ap
+                    # breakdown guard)
+                    bb = np.stack([t.b for t in batch]
+                                  + [batch[-1].b] * npad)
+                    if any_x0:
+                        eff = [t.x0 if t.x0 is not None
+                               else np.zeros_like(t.b) for t in batch]
+                        x0b = np.stack(eff + [eff[-1]] * npad)
+            t0 = time.perf_counter()
+            for i, t in enumerate(batch):
+                if t.trace is not None:
+                    t.trace.event("coalesced", index=i, batch=nreal,
+                                  bucket=bucket)
+            with span("acg.serve.dispatch"):
+                res, err, meta = None, None, {}
+                try:
+                    res = (self._dispatch(bb) if x0b is None
+                           else self._dispatch(bb, x0b))
+                    if isinstance(res, tuple):      # (SolveResult, meta) form
+                        res, meta = res
+                except AcgError as e:
+                    res = getattr(e, "result", None)
+                    err = e
+                    meta = getattr(e, "dispatch_meta", {})
+                except Exception as e:          # never strand waiting tickets
+                    err = AcgError(Status.ERR_INVALID_VALUE,
+                                   f"dispatch failed: {e}")
+            wall = time.perf_counter() - t0
+            self._last_dispatch_t = time.perf_counter()
+            self.counters["batches"] += 1
+            self.counters["padded"] += npad
+            self.counters["total_occupancy"] += nreal / bucket
+            _M_BATCHES.labels(bucket=bucket).inc()
+            _M_OCCUPANCY.observe(nreal / bucket)
+            with span("acg.serve.demux"):
+                for i, t in enumerate(batch):
+                    t.index = i
+                    t.batch_size = nreal
+                    t.bucket = bucket
+                    t.dispatch_wall = wall
+                    t.depth_at_dispatch = left_behind
+                    t.dispatch_meta = meta
+                    t.queue_wait = t0 - t.enqueue_t
+                    self.counters["total_wait"] += t.queue_wait
+                    _M_WAIT.observe(t.queue_wait)
+                    if t.trace is not None:
+                        t.trace.event(
+                            "dispatch", wall_ms=round(wall * 1e3, 3),
+                            solver=meta.get("solver"),
+                            cache_hit=bool(meta.get("cache_hit", False)))
+                    if res is not None:
+                        my = demux_result(res, i,
+                                          bnrm2=float(np.linalg.norm(t.b)))
+                        if my.converged or err is None:
+                            t.result_value = my
+                            t.error = None
+                        else:
+                            # per-request error carrying the demuxed partial
+                            # result, like the plain solvers' AcgError contract
+                            e = AcgError(my.status)
+                            e.result = my
+                            t.error = e
+                    else:
+                        t.error = err
+                    t.done = True
+                    if t.trace is not None:
+                        st = (t.result_value.status.name
+                              if t.result_value is not None
+                              else getattr(getattr(t.error, "status", None),
+                                           "name", "ERR"))
+                        t.trace.event("demux", index=i, status=st)
+            with self._cv:
+                self._inflight -= len(batch)
 
     def stats(self) -> dict:
         c = self.counters

@@ -57,6 +57,7 @@ from acg_tpu.config import SolverOptions
 from acg_tpu.errors import AcgError, Status
 from acg_tpu.obs import metrics as _metrics
 from acg_tpu.obs.events import FlightRecorder, new_trace_id
+from acg_tpu.obs.trace import span
 from acg_tpu.serve.admission import (AdmissionPolicy, AdmissionRecord,
                                      BreakerBoard, RollingWindow,
                                      HALF_OPEN, OPEN)
@@ -345,18 +346,22 @@ class SolverService:
                                                has_x0=x0 is not None))
         meta["cache_hit"] = hit
         ok = False
+        # the session spans this dispatch closed: its requests' audit
+        # documents carry them (SpanTracer.as_dicts)
+        first = self.session.tracer.mark()
         try:
             res = self.session.solve(bb, solver=solver,
                                      options=self.options, x0=x0,
                                      fault=fault)
             ok = bool(res.converged)
-            return res, meta
         except AcgError as e:
             e.dispatch_meta = meta
             raise
         finally:
+            meta["span_marks"] = (first, self.session.tracer.mark())
             if self._board is not None:
                 self._board.record(solver, nrhs, self.session.dtype, ok)
+        return res, meta
 
     # -- submission -----------------------------------------------------
 
@@ -523,9 +528,10 @@ class SolverService:
                         record: AdmissionRecord | None,
                         count: bool = True
                         ) -> tuple[ServeResponse, bool]:
-        """Wait, classify, retry/recover, audit.  ``count=False`` is
-        the repoll path: the request was already counted into the
-        failure/shed/window stats when its terminal timeout was issued."""
+        """Wait, then respond (:meth:`_respond`, one
+        ``acg.serve.respond`` span).  ``count=False`` is the repoll
+        path: the request was already counted into the failure/shed/
+        window stats when its terminal timeout was issued."""
         rec = (record if record is not None
                else AdmissionRecord(policy=self.admission))
         # the caller's timeout never waits past the request deadline
@@ -533,8 +539,7 @@ class SolverService:
         rem = rec.remaining_s()
         if rem is not None:
             eff = rem if eff is None else min(eff, rem)
-        res, err, resil_report = None, None, None
-        recovered = False
+        res, err = None, None
         try:
             res = ticket.result(None if eff is None
                                 else max(eff, 0.0))
@@ -579,6 +584,17 @@ class SolverService:
         except AcgError as e:
             err = e
             res = getattr(e, "result", None)
+        tr = ticket.trace
+        with span("acg.serve.respond",
+                  trace_id=tr.trace_id if tr is not None else ""):
+            return self._respond(ticket, rec, res, err, count)
+
+    def _respond(self, ticket: Ticket, rec: AdmissionRecord, res, err,
+                 count: bool) -> tuple[ServeResponse, bool]:
+        """Classify a finished request — retry or recover it, certify a
+        warm start — and assemble its response and audit document."""
+        resil_report = None
+        recovered = False
         # the authoritative per-dispatch bit, recorded by _dispatch
         # BEFORE the solve (a cold signature compiles = a miss)
         exec_hit = bool(ticket.dispatch_meta.get("cache_hit", False))
@@ -906,7 +922,7 @@ class SolverService:
             solver=self.solver, options=self.options, res=stub,
             stats=stub.stats, nunknowns=self.session.nrows,
             nparts=self.session.nparts,
-            phases=self.session.tracer.as_dicts(),
+            phases=self.session.tracer.as_dicts(since=0, until=0),
             session=self.session_block(t, False),
             admission=self._admission_block(rec),
             metrics=_metrics_block(),
@@ -935,7 +951,8 @@ class SolverService:
             options=self.options, res=res,
             stats=res.stats, nunknowns=self.session.nrows,
             nparts=self.session.nparts,
-            phases=self.session.tracer.as_dicts(),
+            phases=self.session.tracer.as_dicts(
+                *ticket.dispatch_meta.get("span_marks", (0, 0))),
             resilience=resil_report,
             session=self.session_block(ticket, exec_hit),
             admission=self._admission_block(rec),

@@ -41,7 +41,7 @@ import numpy as np
 from acg_tpu.config import HaloMethod, SolverOptions
 from acg_tpu.errors import AcgError, Status
 from acg_tpu.obs import metrics as _metrics
-from acg_tpu.obs.trace import SpanTracer
+from acg_tpu.obs.trace import SpanTracer, span
 
 # runtime telemetry (acg_tpu/obs/metrics.py; no-ops until
 # enable_metrics()): the executable / prepared-operator cache traffic
@@ -330,7 +330,7 @@ class Session:
             from acg_tpu.io import read_mtx
             from acg_tpu.sparse.csr import csr_from_mtx
 
-            with self.tracer.span("read"):
+            with self.tracer.span("read"), span("acg.prep.assemble"):
                 m = read_mtx(path, binary=binary)
                 A = csr_from_mtx(m, val_dtype=self.dtype)
         if epsilon:
@@ -404,7 +404,8 @@ class Session:
                      if self.prep_cache is not None else None)
             part = self.part
             if part is None:
-                with self.tracer.span("partition"):
+                with self.tracer.span("partition"), \
+                        span("acg.prep.partition"):
                     part = cached_partition_graph(
                         self.A, self.nparts,
                         method=self.partition_method,

@@ -39,6 +39,7 @@ import numpy as np
 from acg_tpu.config import SolverOptions
 from acg_tpu.errors import AcgError, Status
 from acg_tpu.obs.metrics import observe_solve_result
+from acg_tpu.obs.trace import span
 from acg_tpu.ops.blas1 import batched_dot, gram
 from acg_tpu.ops.spmv import DeviceEll, pad_vector
 from acg_tpu.solvers.base import (SolveResult, SolveStats,
@@ -73,15 +74,25 @@ def _fault_plan(fault, vdt):
                    f"{type(fault).__name__}")
 
 
-def _scoped_matvec(op):
-    """The operator application under a ``jax.named_scope`` — the same
-    profiler-visible annotation the distributed loops already carry
-    ("halo"/"local_spmv", cg_dist.py), so single-chip ``--profile``
-    traces name the SpMV too."""
-    def mv(v):
-        with jax.named_scope("spmv"):
-            return op.matvec(v)
-    return mv
+def _traced_call(solver: str):
+    """Decorate a solver entry: each call is one ``acg.call`` span
+    (acg_tpu/obs/trace.py), inside which the entry opens its
+    ``acg.call.*`` stages."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def entry(*args, **kwargs):
+            with span("acg.call", solver=solver):
+                return fn(*args, **kwargs)
+        return entry
+    return wrap
+
+
+def _device_wait(x, k):
+    """Wait for the solve's ``x`` and fetch its iteration count ``k`` —
+    the first sync after dispatch (``acg.call.device_wait``)."""
+    with span("acg.call.device_wait"):
+        jax.block_until_ready(x)
+        return jax.device_get(k)
 
 
 @functools.partial(jax.jit,
@@ -97,7 +108,7 @@ def _cg_device(op, b, x0, stop2, diffstop, maxits: int, track_diff: bool,
     static fields select the SpMV formulation at trace time.  ``fault``
     (a DeviceFaultPlan pytree — data, not trace structure) and ``guard``
     (static) are the resilience hooks of acg_tpu/robust/."""
-    return cg_while(_scoped_matvec(op), batched_dot,
+    return cg_while(op.matvec, batched_dot,
                     b, x0, stop2, diffstop, maxits, track_diff,
                     check_every=check_every,
                     monitor=monitor, monitor_every=monitor_every,
@@ -114,7 +125,7 @@ def _cg_device_seg(op, b, x0, stop2, diffstop, maxits: int,
                    fault=None, guard: bool = False):
     """First segment of a segmented solve (see SolverOptions.segment_iters):
     also returns the loop carry for :func:`_cg_device_seg_resume`."""
-    return cg_while(_scoped_matvec(op), batched_dot, b, x0, stop2, diffstop,
+    return cg_while(op.matvec, batched_dot, b, x0, stop2, diffstop,
                     maxits, track_diff, check_every=check_every,
                     segment=segment, want_carry=True,
                     monitor=monitor, monitor_every=monitor_every,
@@ -133,7 +144,7 @@ def _cg_device_seg_resume(op, b, carry, stop2, diffstop, maxits: int,
     while_loop body, numerically identical to the single-program solve.
     The fault plan rides along: its iteration is GLOBAL (the carried k),
     so a fault lands in whichever segment contains its iteration."""
-    return cg_while(_scoped_matvec(op), batched_dot, b, None, stop2, diffstop,
+    return cg_while(op.matvec, batched_dot, b, None, stop2, diffstop,
                     maxits, track_diff, check_every=check_every,
                     segment=segment, carry_in=carry, want_carry=True,
                     monitor=monitor, monitor_every=monitor_every,
@@ -161,7 +172,8 @@ def _run_segmented(first_fn, resume_fn, maxits: int, continue_fn=None):
     if continue_fn is None:
         continue_fn = _continue
     while continue_fn(carry):
-        *res, carry = resume_fn(carry)
+        with span("acg.call.segment"):
+            *res, carry = resume_fn(carry)
     return res
 
 
@@ -187,15 +199,16 @@ def _fused_ops(op, bands_pad, rows_tile: int, kind: str):
     batched = kind == "resident-batched"
 
     def mv(v):
-        with jax.named_scope("spmv"):
-            return kernel(bands_pad, op.offsets, v, rows_tile=rows_tile,
-                          scales=sc)
+        return kernel(bands_pad, op.offsets, v, rows_tile=rows_tile,
+                      scales=sc)
 
     def coupled(r, p, beta):
-        p = r + (beta[:, None] if batched else beta) * p
-        with jax.named_scope("spmv"):
-            t, ptap = kernel(bands_pad, op.offsets, p,
-                             rows_tile=rows_tile, with_dot=True, scales=sc)
+        # the loop runs this under acg.spmv; the kernel's fused p'Ap
+        # stays there, the direction update is BLAS-1
+        with jax.named_scope("acg.blas1"):
+            p = r + (beta[:, None] if batched else beta) * p
+        t, ptap = kernel(bands_pad, op.offsets, p,
+                         rows_tile=rows_tile, with_dot=True, scales=sc)
         return p, t, ptap
 
     return mv, coupled
@@ -395,7 +408,7 @@ def _cg_pipelined_device(op, b, x0, stop2, maxits: int,
                          guard: bool = False):
     """Pipelined CG; one fused 2-scalar reduction per iteration
     (see acg_tpu/solvers/loops.py for the recurrences)."""
-    return cg_pipelined_while(_scoped_matvec(op), _dot2, b, x0, stop2,
+    return cg_pipelined_while(op.matvec, _dot2, b, x0, stop2,
                               maxits, check_every=check_every,
                               replace_every=replace_every, certify=certify,
                               monitor=monitor, monitor_every=monitor_every,
@@ -414,7 +427,7 @@ def _cg_pipelined_device_seg(op, b, x0, stop2, maxits: int,
     """First segment of a segmented pipelined solve (the pipelined twin
     of :func:`_cg_device_seg`; wired in PR 7): also returns the loop
     carry (whose last element is the device-computed continue bit)."""
-    return cg_pipelined_while(_scoped_matvec(op), _dot2, b, x0, stop2,
+    return cg_pipelined_while(op.matvec, _dot2, b, x0, stop2,
                               maxits, check_every=check_every,
                               replace_every=replace_every, certify=certify,
                               monitor=monitor, monitor_every=monitor_every,
@@ -431,7 +444,7 @@ def _cg_pipelined_device_seg_resume(op, b, carry, stop2, maxits: int,
                                     certify: bool, segment: int,
                                     monitor=None, monitor_every: int = 0,
                                     fault=None, guard: bool = False):
-    return cg_pipelined_while(_scoped_matvec(op), _dot2, b, None, stop2,
+    return cg_pipelined_while(op.matvec, _dot2, b, None, stop2,
                               maxits, check_every=check_every,
                               replace_every=replace_every, certify=certify,
                               monitor=monitor, monitor_every=monitor_every,
@@ -602,10 +615,9 @@ def _cg_pipelined_stencil_fused(op, b, x0, stop2, maxits: int,
     (bp, xp), front = pad_dia_vectors((b, x0), n, pipe_rt, offsets)
 
     def mv(v):
-        with jax.named_scope("spmv"):
-            core = jax.lax.slice_in_dim(v, front, front + n, axis=-1)
-            y = stencil_matvec(core, grid, digits, coeffs)
-            return jnp.pad(y, [(front, v.shape[-1] - front - n)])
+        core = jax.lax.slice_in_dim(v, front, front + n, axis=-1)
+        y = stencil_matvec(core, grid, digits, coeffs)
+        return jnp.pad(y, [(front, v.shape[-1] - front - n)])
 
     def iter_step(z, r, p, w, s, x, alpha, beta):
         return cg_pipelined_iter_stencil(
@@ -646,19 +658,25 @@ def _sstep_block_fn(mv, b, s: int, batched: bool):
     """The single-chip s-step basis builder (loops.cg_sstep_while
     ``block_fn``): residual replacement r = b - Ax, the Newton-shifted
     P/R Krylov blocks through the operator's own SpMV tier, and the
-    Gram matrix as ONE tall-skinny MXU matmul (ops/blas1.py gram)."""
+    Gram matrix as ONE tall-skinny MXU matmul (ops/blas1.py gram).
+    The loop runs it under acg.blas1; the operator applications are
+    acg.spmv."""
     bc = (lambda v: v[:, None]) if batched else (lambda v: v)
 
+    def spmv(v):
+        with jax.named_scope("acg.spmv"):
+            return mv(v)
+
     def block_fn(x, p, shifts):
-        r = b - mv(x)
+        r = b - spmv(x)
         basis = [p]
         for j in range(s):
             v = basis[-1]
-            basis.append(mv(v) - bc(shifts[..., j]) * v)
+            basis.append(spmv(v) - bc(shifts[..., j]) * v)
         basis.append(r)
         for j in range(s - 1):
             v = basis[-1]
-            basis.append(mv(v) - bc(shifts[..., j]) * v)
+            basis.append(spmv(v) - bc(shifts[..., j]) * v)
         V = jnp.stack(basis)          # (2s+1, [B,] n)
         return V, gram(V)
 
@@ -697,7 +715,7 @@ def _cg_sstep_device(op, b, x0, stop2, s: int, maxits: int,
     Leja-ordered shift schedule — the spectral-recycling output a later
     solve against the same operator can feed back as ``shifts0``
     (skipping the power/Chebyshev seeding prelude entirely, ISSUE 20)."""
-    mv = _scoped_matvec(op)
+    mv = op.matvec
     batched = b.ndim == 2
     block_fn = _sstep_block_fn(mv, b, s, batched)
     r0 = b - mv(x0)
@@ -847,6 +865,7 @@ def _sstep_fallback(solve_classic, k_done, ksys, s: int, why: str,
         raise
 
 
+@_traced_call("cg-sstep")
 def cg_sstep(A, b, x0=None, options: SolverOptions = SolverOptions(),
              dtype=None, fmt: str = "auto", mat_dtype="auto",
              stats: SolveStats | None = None, fault=None,
@@ -875,27 +894,28 @@ def cg_sstep(A, b, x0=None, options: SolverOptions = SolverOptions(),
     s = _sstep_validate(o, fault)
     if shifts0 is None and recycle is not None:
         shifts0 = recycle.get_shifts(s)
-    dev, b_pad, x0_pad, perm = _prepare(A, b, x0, dtype, fmt, mat_dtype)
-    batched = b_pad.ndim == 2
-    vdt = b_pad.dtype
-    stop2 = (jnp.asarray(o.residual_atol ** 2, vdt),
-             jnp.asarray(o.residual_rtol ** 2, vdt))
-    bnrm2 = jnp.linalg.norm(b_pad, axis=-1) if batched \
-        else jnp.linalg.norm(b_pad)
-    jax.block_until_ready(bnrm2)
-    monitor = _resolve_monitor(o)
-    if shifts0 is not None:
-        shifts0 = jnp.asarray(shifts0, vdt)
-        if batched and shifts0.ndim == 1:
-            # the loop carries PER-SYSTEM shifts (Ritz refinement is
-            # per system): a shared (s,) seed tiles to (B, s)
-            shifts0 = jnp.tile(shifts0, (b_pad.shape[0], 1))
-    t0 = time.perf_counter()
-    x, k, rr, flag, rr0, hist, shifts_out = _cg_sstep_device(
-        dev, b_pad, x0_pad, stop2, s=s, maxits=o.maxits,
-        monitor=monitor, monitor_every=o.monitor_every, shifts0=shifts0)
-    jax.block_until_ready(x)
-    k = jax.device_get(k)
+    with span("acg.call.prepare"):
+        dev, b_pad, x0_pad, perm = _prepare(A, b, x0, dtype, fmt, mat_dtype)
+        batched = b_pad.ndim == 2
+        vdt = b_pad.dtype
+        stop2 = (jnp.asarray(o.residual_atol ** 2, vdt),
+                 jnp.asarray(o.residual_rtol ** 2, vdt))
+        bnrm2 = jnp.linalg.norm(b_pad, axis=-1) if batched \
+            else jnp.linalg.norm(b_pad)
+        jax.block_until_ready(bnrm2)
+        monitor = _resolve_monitor(o)
+        if shifts0 is not None:
+            shifts0 = jnp.asarray(shifts0, vdt)
+            if batched and shifts0.ndim == 1:
+                # the loop carries PER-SYSTEM shifts (Ritz refinement is
+                # per system): a shared (s,) seed tiles to (B, s)
+                shifts0 = jnp.tile(shifts0, (b_pad.shape[0], 1))
+    with span("acg.call.dispatch"):
+        t0 = time.perf_counter()
+        x, k, rr, flag, rr0, hist, shifts_out = _cg_sstep_device(
+            dev, b_pad, x0_pad, stop2, s=s, maxits=o.maxits,
+            monitor=monitor, monitor_every=o.monitor_every, shifts0=shifts0)
+    k = _device_wait(x, k)
     tsolve = time.perf_counter() - t0
     flags = np.atleast_1d(np.asarray(jax.device_get(flag)))
     if recycle is not None and np.any(flags == _CONVERGED):
@@ -928,7 +948,7 @@ def cg_sstep(A, b, x0=None, options: SolverOptions = SolverOptions(),
                                      forced_fmt=fmt)
     return _finish(dev, x, k, rr, flag, rr0, o, tsolve, pipelined=False,
                    bnrm2=bnrm2, stats=stats,
-                   x_host=_unpermute(x, dev.nrows, perm),
+                   to_host=_host_solution(dev.nrows, perm),
                    path=_describe_path(dev, perm, None) + (note,),
                    hist=hist, sstep=s)
 
@@ -990,112 +1010,114 @@ def build_device_operator(A, dtype=None, fmt: str = "auto",
     if isinstance(A, (DeviceEll, DeviceDia, DeviceSgell, DeviceStencil,
                       PermutedOperator)):
         return A
-    host_vals = getattr(A, "vals", getattr(A, "bands", None))
-    if dtype is not None:
-        ensure_x64_for(np.dtype(dtype))
-    elif host_vals is not None:
-        ensure_x64_for(host_vals.dtype)
-    if isinstance(A, EllMatrix):
-        return DeviceEll.from_ell(A, dtype=dtype, mat_dtype=mat_dtype)
-    if fmt == "stencil" and isinstance(A, (DiaMatrix, CsrMatrix)):
-        # forced matrix-free tier: recognize or ERROR (never a silent
-        # fallback — what a benchmark measures is what it asked for);
-        # the Pallas kernel inside is still probe-gated, the jnp
-        # grid-shift formulation is the everywhere-fallback
-        vdt = (np.dtype(dtype) if dtype is not None
-               else np.dtype(host_vals.dtype))
-        return DeviceStencil.from_matrix(A, dtype=vdt)
-    if isinstance(A, DiaMatrix):
-        if fmt == "auto" and stencil_available():
-            # the matrix-free tier outranks every stored tier when the
-            # system IS a verified constant-coefficient stencil and the
-            # kernel probe is green (ROADMAP item 2): zero operator
-            # stream, no band storage.  Probe-gated like every tier —
-            # off-TPU the stored ladder below is unchanged.
+    # the tier choice, any host format conversion, and the device copy
+    with span("acg.prep.upload"):
+        host_vals = getattr(A, "vals", getattr(A, "bands", None))
+        if dtype is not None:
+            ensure_x64_for(np.dtype(dtype))
+        elif host_vals is not None:
+            ensure_x64_for(host_vals.dtype)
+        if isinstance(A, EllMatrix):
+            return DeviceEll.from_ell(A, dtype=dtype, mat_dtype=mat_dtype)
+        if fmt == "stencil" and isinstance(A, (DiaMatrix, CsrMatrix)):
+            # forced matrix-free tier: recognize or ERROR (never a silent
+            # fallback — what a benchmark measures is what it asked for);
+            # the Pallas kernel inside is still probe-gated, the jnp
+            # grid-shift formulation is the everywhere-fallback
             vdt = (np.dtype(dtype) if dtype is not None
-                   else np.dtype(A.bands.dtype))
-            st, _rep = try_device_stencil(A, dtype=vdt)
-            if _prefer_stencil(st, mat_dtype, nrhs):
-                return st
-        return DeviceDia.from_dia(A, dtype=dtype, mat_dtype=mat_dtype)
-    if isinstance(A, CsrMatrix):
-        if fmt not in ("auto", "dia", "ell", "sgell", "stencil"):
-            raise AcgError(Status.ERR_INVALID_VALUE,
-                           f"unknown operator format {fmt!r} "
-                           "(auto|dia|ell|sgell|stencil)")
-        if fmt == "auto" and stencil_available():
-            vdt = (np.dtype(dtype) if dtype is not None
-                   else np.dtype(A.vals.dtype))
-            st, _rep = try_device_stencil(A, dtype=vdt)
-            if _prefer_stencil(st, mat_dtype, nrhs):
-                return st
-        if fmt == "sgell":
-            # Forced tier (the reference's explicit SpMV-algorithm
-            # selection, cuda/acg-cuda.c:329-376 --cusparse-spmv-alg):
-            # build the segmented-gather operator or ERROR — never a
-            # silent fallback, so what a benchmark measures is what it
-            # asked for.  The fill gate is lifted (min_fill=0): auto
-            # applies the break-even economics; a forced tier is for
-            # measuring them.
-            from acg_tpu.ops.sgell import (build_device_sgell,
-                                           sgell_require_available)
+                   else np.dtype(host_vals.dtype))
+            return DeviceStencil.from_matrix(A, dtype=vdt)
+        if isinstance(A, DiaMatrix):
+            if fmt == "auto" and stencil_available():
+                # the matrix-free tier outranks every stored tier when the
+                # system IS a verified constant-coefficient stencil and the
+                # kernel probe is green (ROADMAP item 2): zero operator
+                # stream, no band storage.  Probe-gated like every tier —
+                # off-TPU the stored ladder below is unchanged.
+                vdt = (np.dtype(dtype) if dtype is not None
+                       else np.dtype(A.bands.dtype))
+                st, _rep = try_device_stencil(A, dtype=vdt)
+                if _prefer_stencil(st, mat_dtype, nrhs):
+                    return st
+            return DeviceDia.from_dia(A, dtype=dtype, mat_dtype=mat_dtype)
+        if isinstance(A, CsrMatrix):
+            if fmt not in ("auto", "dia", "ell", "sgell", "stencil"):
+                raise AcgError(Status.ERR_INVALID_VALUE,
+                               f"unknown operator format {fmt!r} "
+                               "(auto|dia|ell|sgell|stencil)")
+            if fmt == "auto" and stencil_available():
+                vdt = (np.dtype(dtype) if dtype is not None
+                       else np.dtype(A.vals.dtype))
+                st, _rep = try_device_stencil(A, dtype=vdt)
+                if _prefer_stencil(st, mat_dtype, nrhs):
+                    return st
+            if fmt == "sgell":
+                # Forced tier (the reference's explicit SpMV-algorithm
+                # selection, cuda/acg-cuda.c:329-376 --cusparse-spmv-alg):
+                # build the segmented-gather operator or ERROR — never a
+                # silent fallback, so what a benchmark measures is what it
+                # asked for.  The fill gate is lifted (min_fill=0): auto
+                # applies the break-even economics; a forced tier is for
+                # measuring them.
+                from acg_tpu.ops.sgell import (build_device_sgell,
+                                               sgell_require_available)
 
-            vdt = np.dtype(dtype) if dtype is not None else A.vals.dtype
-            sgell_require_available(vdt)
-            sg = build_device_sgell(A, dtype=dtype, mat_dtype=mat_dtype,
-                                    min_fill=0.0)
-            if sg is None:
-                raise AcgError(Status.ERR_NOT_SUPPORTED,
-                               "format 'sgell' forced but the matrix did "
-                               "not pack (degenerate geometry, or more "
-                               "slots than the kernel's SMEM tables hold)")
-            return sg
-        if fmt == "auto":
-            if dia_efficiency(A) >= 0.25:
-                fmt = "dia"
-            else:
-                # bandwidth reduction before giving up on the gather-free
-                # form: RCM often recovers a banded structure from a
-                # scattered ordering (acg_tpu/sparse/rcm.py) — gathers on
-                # TPU run two orders below HBM bandwidth, so a permuted
-                # DIA operator beats ELL whenever RCM succeeds
-                from acg_tpu.sparse.rcm import permute_symmetric, rcm_order
+                vdt = np.dtype(dtype) if dtype is not None else A.vals.dtype
+                sgell_require_available(vdt)
+                sg = build_device_sgell(A, dtype=dtype, mat_dtype=mat_dtype,
+                                        min_fill=0.0)
+                if sg is None:
+                    raise AcgError(Status.ERR_NOT_SUPPORTED,
+                                   "format 'sgell' forced but the matrix did "
+                                   "not pack (degenerate geometry, or more "
+                                   "slots than the kernel's SMEM tables hold)")
+                return sg
+            if fmt == "auto":
+                if dia_efficiency(A) >= 0.25:
+                    fmt = "dia"
+                else:
+                    # bandwidth reduction before giving up on the gather-free
+                    # form: RCM often recovers a banded structure from a
+                    # scattered ordering (acg_tpu/sparse/rcm.py) — gathers on
+                    # TPU run two orders below HBM bandwidth, so a permuted
+                    # DIA operator beats ELL whenever RCM succeeds
+                    from acg_tpu.sparse.rcm import permute_symmetric, rcm_order
 
-                perm = rcm_order(A)
-                Ap = permute_symmetric(A, perm)
-                if dia_efficiency(Ap) >= 0.25:
-                    dev = DeviceDia.from_dia(DiaMatrix.from_csr(Ap),
-                                             dtype=dtype,
-                                             mat_dtype=mat_dtype)
-                    return PermutedOperator(dev, perm)
-                # RCM could not recover a band, but its bandwidth
-                # reduction is exactly what the sgell pack feeds on
-                # (locality => few x segments per 128-row group): try the
-                # sgell tier on the PERMUTED matrix first — the
-                # SuiteSparse-class answer for FEM meshes delivered in
-                # arbitrary orderings
-                from acg_tpu.ops.sgell import build_device_sgell
+                    perm = rcm_order(A)
+                    Ap = permute_symmetric(A, perm)
+                    if dia_efficiency(Ap) >= 0.25:
+                        dev = DeviceDia.from_dia(DiaMatrix.from_csr(Ap),
+                                                 dtype=dtype,
+                                                 mat_dtype=mat_dtype)
+                        return PermutedOperator(dev, perm)
+                    # RCM could not recover a band, but its bandwidth
+                    # reduction is exactly what the sgell pack feeds on
+                    # (locality => few x segments per 128-row group): try the
+                    # sgell tier on the PERMUTED matrix first — the
+                    # SuiteSparse-class answer for FEM meshes delivered in
+                    # arbitrary orderings
+                    from acg_tpu.ops.sgell import build_device_sgell
 
-                sg = build_device_sgell(Ap, dtype=dtype,
-                                        mat_dtype=mat_dtype)
-                if sg is not None:
-                    return PermutedOperator(sg, perm)
-                # the permuted ordering has equal-or-better locality, so
-                # a failed pack on Ap decides the sgell question for the
-                # original ordering too — fall through to the XLA gather
-                # ELL tier (the role of the reference's merge-path CSR
-                # kernel, acg/cg-kernels-cuda.cu:340-441, when neither
-                # DIA recovery nor segment packing applies)
-                fmt = "ell"
-        if fmt == "dia":
-            return DeviceDia.from_dia(DiaMatrix.from_csr(A), dtype=dtype,
+                    sg = build_device_sgell(Ap, dtype=dtype,
+                                            mat_dtype=mat_dtype)
+                    if sg is not None:
+                        return PermutedOperator(sg, perm)
+                    # the permuted ordering has equal-or-better locality, so
+                    # a failed pack on Ap decides the sgell question for the
+                    # original ordering too — fall through to the XLA gather
+                    # ELL tier (the role of the reference's merge-path CSR
+                    # kernel, acg/cg-kernels-cuda.cu:340-441, when neither
+                    # DIA recovery nor segment packing applies)
+                    fmt = "ell"
+            if fmt == "dia":
+                return DeviceDia.from_dia(DiaMatrix.from_csr(A), dtype=dtype,
+                                          mat_dtype=mat_dtype)
+            # an explicitly forced fmt="ell" keeps its documented contract and
+            # pins the XLA gather form (the A/B baseline)
+            return DeviceEll.from_ell(EllMatrix.from_csr(A), dtype=dtype,
                                       mat_dtype=mat_dtype)
-        # an explicitly forced fmt="ell" keeps its documented contract and
-        # pins the XLA gather form (the A/B baseline)
-        return DeviceEll.from_ell(EllMatrix.from_csr(A), dtype=dtype,
-                                  mat_dtype=mat_dtype)
-    raise AcgError(Status.ERR_INVALID_VALUE,
-                   f"unsupported operator type {type(A).__name__}")
+        raise AcgError(Status.ERR_INVALID_VALUE,
+                       f"unsupported operator type {type(A).__name__}")
 
 
 def _prepare(A, b, x0, dtype, fmt: str = "auto", mat_dtype="auto"):
@@ -1136,6 +1158,15 @@ def _prepare(A, b, x0, dtype, fmt: str = "auto", mat_dtype="auto"):
     return dev, b_pad, x0_pad, perm
 
 
+def _host_solution(nrows: int, perm):
+    """``x -> host solution`` for a padded device ``x``: un-permuted by
+    ``perm`` (new_to_old), else sliced to the ``nrows`` real rows."""
+    def to_host(x):
+        x_host = _unpermute(x, nrows, perm)
+        return np.asarray(x)[..., :nrows] if x_host is None else x_host
+    return to_host
+
+
 def _unpermute(x, nrows: int, perm):
     """Host solution in the caller's original ordering (perm is new_to_old:
     x_orig[perm] = x_permuted).  Batched x un-permutes every system."""
@@ -1148,8 +1179,8 @@ def _unpermute(x, nrows: int, perm):
 
 
 def _finish(A, x, k, rr, flag, rr0, options, tsolve, pipelined, bnrm2,
-            dxx=None, stats=None, x_host=None, path=("", ""), hist=None,
-            sstep: int = 0, solver: str | None = None):
+            dxx=None, stats=None, path=("", ""), hist=None,
+            sstep: int = 0, solver: str | None = None, *, to_host):
     """Assemble the SolveResult.  ``tsolve`` is the measured device-solve
     time (timer around the compiled loop only, matching the reference's
     tsolve which excludes the solution copyback, acg/cgcuda.c:1022-1107).
@@ -1158,138 +1189,145 @@ def _finish(A, x, k, rr, flag, rr0, options, tsolve, pipelined, bnrm2,
     the reference batching its D2H copies on a dedicated copystream
     (acg/cgcuda.c:946-951).  ``hist`` is the on-device residual-norm²
     history buffer (rides the same batched fetch; trimmed to the k+1
-    live entries here)."""
+    live entries here).  ``to_host(x)`` is the caller's host solution
+    (:func:`_host_solution` for a padded single-chip ``x``)."""
     has_dxx = dxx is not None
     has_hist = hist is not None
-    k, flag, rr, rr0, bnrm2, dxx, hist = jax.device_get(
-        (k, flag, rr, rr0, bnrm2, dxx if has_dxx else rr,
-         hist if has_hist else rr))
-    batched = np.ndim(k) == 1
-    if batched:
-        # per-system arrays; the scalar norms below summarize the WORST
-        # system BY RELATIVE RESIDUAL — rnrm2 and r0nrm2 must come from
-        # the SAME system or relative_residual pairs one system's
-        # residual with another's r0 (review finding: a converged
-        # huge-|r0| system could mask a stalled unit-scale one by an
-        # arbitrary factor)
-        ksys = np.asarray(k, dtype=np.int64)
-        flags = np.asarray(flag, dtype=np.int64)
-        rnrm2s = np.sqrt(np.asarray(rr, dtype=np.float64))
-        r0nrm2s = np.sqrt(np.asarray(rr0, dtype=np.float64))
-        k = int(ksys.max()) if ksys.size else 0
-        # a faulted system dominates the batch summary (the recovery
-        # decision is batch-wide), then breakdown, then convergence
-        flag = (_FAULT if np.any(flags == _FAULT)
-                else _BREAKDOWN if np.any(flags == _BREAKDOWN)
-                else (_CONVERGED if np.all(flags == _CONVERGED) else _OK))
-        rel = rnrm2s / np.where(r0nrm2s > 0, r0nrm2s, 1.0)
-        worst = int(np.argmax(rel)) if rel.size else 0
-        rnrm2 = float(rnrm2s[worst]) if rnrm2s.size else 0.0
-        r0nrm2 = float(r0nrm2s[worst]) if r0nrm2s.size else 0.0
-        # bnrm2 from the SAME worst system (a max over a different
-        # system would make |r|/|b| computed from the export wrong by
-        # the spread of the batch's b scales)
-        if np.ndim(bnrm2) == 1:
-            bnrm2 = np.asarray(bnrm2, dtype=np.float64)[worst]
-        nrhs = int(ksys.shape[0])
-        niters_total = int(ksys.sum())
-    else:
-        k = int(k)
-        flag = int(flag)
-        rnrm2 = float(np.sqrt(float(rr)))
-        r0nrm2 = float(np.sqrt(float(rr0)))
-        nrhs = 1
-        niters_total = k
-    if x_host is None:
-        x_host = np.asarray(x)[..., : A.nrows]
-    st = stats if stats is not None else SolveStats()
-    st.nsolves += 1
-    st.ntotaliterations += k
-    st.niterations = k
-    # useful flops: each system advances only while it is active
-    st.nflops += niters_total * cg_flops_per_iter(A.nnz, A.nrows,
-                                                  pipelined=pipelined,
-                                                  sstep=sstep)
-    st.tsolve += tsolve
-    o = options
-    if has_hist:
-        # trim the fixed-size buffer to the iterations actually run
-        # (slots past k — per system for batched solves — are NaN fill,
-        # see loops._history_init); host NumPy by the device_get above
-        hist = np.asarray(hist, dtype=np.float64)[..., : k + 1]
-    res = SolveResult(
-        x=x_host, converged=(flag == _CONVERGED), niterations=k,
-        bnrm2=float(np.max(bnrm2)), r0nrm2=r0nrm2, rnrm2=rnrm2,
-        dxnrm2=(float(np.sqrt(np.max(np.asarray(dxx, dtype=np.float64))))
-                if has_dxx else float("inf")),
-        stats=st,
-        fpexcept=("none" if (np.isfinite(rnrm2) and np.all(np.isfinite(x_host)))
-                  else "non-finite values in solution or residual"),
-        operator_format=path[0], kernel=path[1],
-        kernel_note=path[2] if len(path) > 2 else "",
-        residual_history=hist if has_hist else None,
-        nrhs=nrhs,
-        iterations_per_system=ksys if batched else None,
-        rnrm2_per_system=rnrm2s if batched else None,
-        r0nrm2_per_system=r0nrm2s if batched else None,
-        converged_per_system=(flags == _CONVERGED) if batched else None)
-
-    def _observed(r):
-        # runtime telemetry (acg_tpu/obs/metrics.py; no-op unless
-        # enable_metrics()): every terminal path below — raised or
-        # returned — records exactly once, with the FINAL status.
-        # Host-side, after the device_get above: cannot touch a trace.
-        observe_solve_result(r, solver=(solver if solver
-                                        else "cg-sstep" if sstep
-                                        else "cg-pipelined" if pipelined
-                                        else "cg"))
-        return r
-
-    if flag == _FAULT or (batched and np.any(flags == _FAULT)):
-        # the on-device finiteness guard fired (loops.py, guard=True):
-        # a first-class detection, distinct from breakdown — name what
-        # was seen (|r|² is returned; a finite |r|² with the flag set
-        # means the OTHER reduced scalar, p'Ap or the pipelined δ, was
-        # the non-finite witness)
-        res.status = Status.ERR_FAULT_DETECTED
-        res.fpexcept = (
-            f"non-finite residual reduction |r|^2 = {rnrm2!r} detected "
-            f"by the on-device guard at iteration {k}"
-            if not np.isfinite(rnrm2) else
-            f"non-finite reduction (p'Ap / delta) detected by the "
-            f"on-device guard at iteration {k} (|r|^2 still finite)")
-        err = AcgError(Status.ERR_FAULT_DETECTED,
-                       f"solve aborted at iteration {k}: {res.fpexcept}")
-        err.result = _observed(res)
-        raise err
-    if flag == _BREAKDOWN or (batched and np.any(flags == _BREAKDOWN)):
-        res.status = Status.ERR_NOT_CONVERGED_INDEFINITE_MATRIX
-        err = AcgError(Status.ERR_NOT_CONVERGED_INDEFINITE_MATRIX)
-        err.result = _observed(res)
-        raise err
-    no_criteria = (o.diffatol == 0 and o.diffrtol == 0
-                   and o.residual_atol == 0 and o.residual_rtol == 0)
-    all_conv = (np.all(flags == _CONVERGED) if batched
-                else flag == _CONVERGED)
-    if not all_conv and not no_criteria:
-        res.status = Status.ERR_NOT_CONVERGED
-        err = AcgError(Status.ERR_NOT_CONVERGED,
-                       f"CG did not converge in {o.maxits} iterations "
-                       f"(|r|/|r0| = {res.relative_residual:.3e})")
-        err.result = _observed(res)
-        raise err
-    if no_criteria:
-        res.converged = True
+    with span("acg.call.fetch"):
+        k, flag, rr, rr0, bnrm2, dxx, hist = jax.device_get(
+            (k, flag, rr, rr0, bnrm2, dxx if has_dxx else rr,
+             hist if has_hist else rr))
+    with span("acg.call.copyback"):
+        x_host = to_host(x)
+    # SolveResult assembly, the finiteness check, the metrics hook and
+    # the status classification
+    with span("acg.call.finish"):
+        batched = np.ndim(k) == 1
         if batched:
-            res.converged_per_system = np.ones(nrhs, dtype=bool)
-    if res.fpexcept != "none":
-        # non-finite values in the RESULT with no guard running (or a
-        # fixed-iteration solve that ran to maxits on NaNs): classified,
-        # not raised — the caller opted out of stopping criteria
-        res.status = Status.ERR_NONFINITE
-    return _observed(res)
+            # per-system arrays; the scalar norms below summarize the WORST
+            # system BY RELATIVE RESIDUAL — rnrm2 and r0nrm2 must come from
+            # the SAME system or relative_residual pairs one system's
+            # residual with another's r0 (review finding: a converged
+            # huge-|r0| system could mask a stalled unit-scale one by an
+            # arbitrary factor)
+            ksys = np.asarray(k, dtype=np.int64)
+            flags = np.asarray(flag, dtype=np.int64)
+            rnrm2s = np.sqrt(np.asarray(rr, dtype=np.float64))
+            r0nrm2s = np.sqrt(np.asarray(rr0, dtype=np.float64))
+            k = int(ksys.max()) if ksys.size else 0
+            # a faulted system dominates the batch summary (the recovery
+            # decision is batch-wide), then breakdown, then convergence
+            flag = (_FAULT if np.any(flags == _FAULT)
+                    else _BREAKDOWN if np.any(flags == _BREAKDOWN)
+                    else (_CONVERGED if np.all(flags == _CONVERGED) else _OK))
+            rel = rnrm2s / np.where(r0nrm2s > 0, r0nrm2s, 1.0)
+            worst = int(np.argmax(rel)) if rel.size else 0
+            rnrm2 = float(rnrm2s[worst]) if rnrm2s.size else 0.0
+            r0nrm2 = float(r0nrm2s[worst]) if r0nrm2s.size else 0.0
+            # bnrm2 from the SAME worst system (a max over a different
+            # system would make |r|/|b| computed from the export wrong by
+            # the spread of the batch's b scales)
+            if np.ndim(bnrm2) == 1:
+                bnrm2 = np.asarray(bnrm2, dtype=np.float64)[worst]
+            nrhs = int(ksys.shape[0])
+            niters_total = int(ksys.sum())
+        else:
+            k = int(k)
+            flag = int(flag)
+            rnrm2 = float(np.sqrt(float(rr)))
+            r0nrm2 = float(np.sqrt(float(rr0)))
+            nrhs = 1
+            niters_total = k
+        st = stats if stats is not None else SolveStats()
+        st.nsolves += 1
+        st.ntotaliterations += k
+        st.niterations = k
+        # useful flops: each system advances only while it is active
+        st.nflops += niters_total * cg_flops_per_iter(A.nnz, A.nrows,
+                                                      pipelined=pipelined,
+                                                      sstep=sstep)
+        st.tsolve += tsolve
+        o = options
+        if has_hist:
+            # trim the fixed-size buffer to the iterations actually run
+            # (slots past k — per system for batched solves — are NaN fill,
+            # see loops._history_init); host NumPy by the device_get above
+            hist = np.asarray(hist, dtype=np.float64)[..., : k + 1]
+        res = SolveResult(
+            x=x_host, converged=(flag == _CONVERGED), niterations=k,
+            bnrm2=float(np.max(bnrm2)), r0nrm2=r0nrm2, rnrm2=rnrm2,
+            dxnrm2=(float(np.sqrt(np.max(np.asarray(dxx, dtype=np.float64))))
+                    if has_dxx else float("inf")),
+            stats=st,
+            fpexcept=("none" if (np.isfinite(rnrm2)
+                                 and np.all(np.isfinite(x_host)))
+                      else "non-finite values in solution or residual"),
+            operator_format=path[0], kernel=path[1],
+            kernel_note=path[2] if len(path) > 2 else "",
+            residual_history=hist if has_hist else None,
+            nrhs=nrhs,
+            iterations_per_system=ksys if batched else None,
+            rnrm2_per_system=rnrm2s if batched else None,
+            r0nrm2_per_system=r0nrm2s if batched else None,
+            converged_per_system=(flags == _CONVERGED) if batched else None)
+
+        def _observed(r):
+            # runtime telemetry (acg_tpu/obs/metrics.py; no-op unless
+            # enable_metrics()): every terminal path below — raised or
+            # returned — records exactly once, with the FINAL status.
+            # Host-side, after the device_get above: cannot touch a trace.
+            observe_solve_result(r, solver=(solver if solver
+                                            else "cg-sstep" if sstep
+                                            else "cg-pipelined" if pipelined
+                                            else "cg"))
+            return r
+
+        if flag == _FAULT or (batched and np.any(flags == _FAULT)):
+            # the on-device finiteness guard fired (loops.py, guard=True):
+            # a first-class detection, distinct from breakdown — name what
+            # was seen (|r|² is returned; a finite |r|² with the flag set
+            # means the OTHER reduced scalar, p'Ap or the pipelined δ, was
+            # the non-finite witness)
+            res.status = Status.ERR_FAULT_DETECTED
+            res.fpexcept = (
+                f"non-finite residual reduction |r|^2 = {rnrm2!r} detected "
+                f"by the on-device guard at iteration {k}"
+                if not np.isfinite(rnrm2) else
+                f"non-finite reduction (p'Ap / delta) detected by the "
+                f"on-device guard at iteration {k} (|r|^2 still finite)")
+            err = AcgError(Status.ERR_FAULT_DETECTED,
+                           f"solve aborted at iteration {k}: {res.fpexcept}")
+            err.result = _observed(res)
+            raise err
+        if flag == _BREAKDOWN or (batched and np.any(flags == _BREAKDOWN)):
+            res.status = Status.ERR_NOT_CONVERGED_INDEFINITE_MATRIX
+            err = AcgError(Status.ERR_NOT_CONVERGED_INDEFINITE_MATRIX)
+            err.result = _observed(res)
+            raise err
+        no_criteria = (o.diffatol == 0 and o.diffrtol == 0
+                       and o.residual_atol == 0 and o.residual_rtol == 0)
+        all_conv = (np.all(flags == _CONVERGED) if batched
+                    else flag == _CONVERGED)
+        if not all_conv and not no_criteria:
+            res.status = Status.ERR_NOT_CONVERGED
+            err = AcgError(Status.ERR_NOT_CONVERGED,
+                           f"CG did not converge in {o.maxits} iterations "
+                           f"(|r|/|r0| = {res.relative_residual:.3e})")
+            err.result = _observed(res)
+            raise err
+        if no_criteria:
+            res.converged = True
+            if batched:
+                res.converged_per_system = np.ones(nrhs, dtype=bool)
+        if res.fpexcept != "none":
+            # non-finite values in the RESULT with no guard running (or a
+            # fixed-iteration solve that ran to maxits on NaNs): classified,
+            # not raised — the caller opted out of stopping criteria
+            res.status = Status.ERR_NONFINITE
+        return _observed(res)
 
 
+@_traced_call("cg")
 def cg(A, b, x0=None, options: SolverOptions = SolverOptions(),
        dtype=None, fmt: str = "auto", mat_dtype="auto",
        stats: SolveStats | None = None, fault=None,
@@ -1306,97 +1344,98 @@ def cg(A, b, x0=None, options: SolverOptions = SolverOptions(),
     data; pair it with ``options.guard_nonfinite`` to exercise the
     detection path (acg_tpu/robust/)."""
     o = options
-    dev, b_pad, x0_pad, perm = _prepare(A, b, x0, dtype, fmt, mat_dtype)
-    batched = b_pad.ndim == 2
-    vdt = b_pad.dtype
-    fplan = _fault_plan(fault, vdt)
-    guard = o.guard_nonfinite
-    # atol2_floor (the s-step fallback, _sstep_fallback_stop): a scalar
-    # or PER-SYSTEM (B,) squared-absolute threshold floor folded into
-    # the atol term — each system's criterion can be restored exactly
-    # where a scalar options field could only carry the batch min
-    stop2 = (jnp.asarray(o.residual_atol ** 2 if atol2_floor is None
-                         else np.maximum(o.residual_atol ** 2,
-                                         atol2_floor), vdt),
-             jnp.asarray(o.residual_rtol**2, vdt))
-    track_diff = o.diffatol > 0 or o.diffrtol > 0
-    diffstop = jnp.asarray(o.diffatol**2, vdt)  # diffrtol needs |x0|
-    if o.diffrtol > 0:
-        if batched:  # per-system |x0| -> per-system diff threshold
-            x0n = jnp.linalg.norm(x0_pad, axis=-1)
-            diffstop = jnp.maximum(diffstop,
-                                   ((o.diffrtol * x0n) ** 2).astype(vdt))
-        else:
-            x0n = float(jnp.linalg.norm(x0_pad))
-            diffstop = jnp.maximum(diffstop,
-                                   jnp.asarray((o.diffrtol * x0n) ** 2,
-                                               vdt))
-    bnrm2 = jnp.linalg.norm(b_pad, axis=-1) if batched \
-        else jnp.linalg.norm(b_pad)         # fetched with the scalar batch
-    jax.block_until_ready(bnrm2)            # keep it out of the timed window
-    plan = (_fused_plan_batched(dev, b_pad.shape[0]) if batched
-            else _fused_plan(dev))
-    monitor = _resolve_monitor(o)
-    t0 = time.perf_counter()
-    if plan is not None and o.segment_iters > 0:
-        from acg_tpu.ops.pallas_kernels import LANES, padded_halo_rows
+    with span("acg.call.prepare"):
+        dev, b_pad, x0_pad, perm = _prepare(A, b, x0, dtype, fmt, mat_dtype)
+        batched = b_pad.ndim == 2
+        vdt = b_pad.dtype
+        fplan = _fault_plan(fault, vdt)
+        guard = o.guard_nonfinite
+        # atol2_floor (the s-step fallback, _sstep_fallback_stop): a scalar
+        # or PER-SYSTEM (B,) squared-absolute threshold floor folded into
+        # the atol term — each system's criterion can be restored exactly
+        # where a scalar options field could only carry the batch min
+        stop2 = (jnp.asarray(o.residual_atol ** 2 if atol2_floor is None
+                             else np.maximum(o.residual_atol ** 2,
+                                             atol2_floor), vdt),
+                 jnp.asarray(o.residual_rtol**2, vdt))
+        track_diff = o.diffatol > 0 or o.diffrtol > 0
+        diffstop = jnp.asarray(o.diffatol**2, vdt)  # diffrtol needs |x0|
+        if o.diffrtol > 0:
+            if batched:  # per-system |x0| -> per-system diff threshold
+                x0n = jnp.linalg.norm(x0_pad, axis=-1)
+                diffstop = jnp.maximum(diffstop,
+                                       ((o.diffrtol * x0n) ** 2).astype(vdt))
+            else:
+                x0n = float(jnp.linalg.norm(x0_pad))
+                diffstop = jnp.maximum(diffstop,
+                                       jnp.asarray((o.diffrtol * x0n) ** 2,
+                                                   vdt))
+        bnrm2 = jnp.linalg.norm(b_pad, axis=-1) if batched \
+            else jnp.linalg.norm(b_pad)     # fetched with the scalar batch
+        jax.block_until_ready(bnrm2)        # keep it out of the timed window
+        plan = (_fused_plan_batched(dev, b_pad.shape[0]) if batched
+                else _fused_plan(dev))
+        monitor = _resolve_monitor(o)
+    with span("acg.call.dispatch"):
+        t0 = time.perf_counter()
+        if plan is not None and o.segment_iters > 0:
+            from acg_tpu.ops.pallas_kernels import LANES, padded_halo_rows
 
-        kind, rt = plan
-        bands_pad, (bp2, xp2) = _pad_fused(dev, b_pad, x0_pad, rt)
-        x, k, rr, dxx, flag, rr0, hist = _run_segmented(
-            lambda: _cg_fused_seg(
-                dev, bands_pad, bp2, xp2, stop2, diffstop,
+            kind, rt = plan
+            bands_pad, (bp2, xp2) = _pad_fused(dev, b_pad, x0_pad, rt)
+            x, k, rr, dxx, flag, rr0, hist = _run_segmented(
+                lambda: _cg_fused_seg(
+                    dev, bands_pad, bp2, xp2, stop2, diffstop,
+                    maxits=o.maxits, track_diff=track_diff,
+                    check_every=o.check_every, rows_tile=rt, kind=kind,
+                    segment=o.segment_iters, monitor=monitor,
+                    monitor_every=o.monitor_every, fault=fplan, guard=guard),
+                lambda c: _cg_fused_seg_resume(
+                    dev, bands_pad, bp2, c, stop2, diffstop,
+                    maxits=o.maxits, track_diff=track_diff,
+                    check_every=o.check_every, rows_tile=rt, kind=kind,
+                    segment=o.segment_iters, monitor=monitor,
+                    monitor_every=o.monitor_every, fault=fplan, guard=guard),
+                o.maxits)
+            hpad = padded_halo_rows(dev.offsets, rt) * LANES
+            x = jax.lax.slice_in_dim(x, hpad,
+                                     hpad + b_pad.shape[-1], axis=-1)
+        elif plan is not None:
+            kind, rt = plan
+            x, k, rr, dxx, flag, rr0, hist = _cg_device_fused(
+                dev, b_pad, x0_pad, stop2, diffstop,
                 maxits=o.maxits, track_diff=track_diff,
                 check_every=o.check_every, rows_tile=rt, kind=kind,
-                segment=o.segment_iters, monitor=monitor,
-                monitor_every=o.monitor_every, fault=fplan, guard=guard),
-            lambda c: _cg_fused_seg_resume(
-                dev, bands_pad, bp2, c, stop2, diffstop,
+                monitor=monitor, monitor_every=o.monitor_every,
+                fault=fplan, guard=guard)
+        elif o.segment_iters > 0:
+            x, k, rr, dxx, flag, rr0, hist = _run_segmented(
+                lambda: _cg_device_seg(
+                    dev, b_pad, x0_pad, stop2, diffstop, maxits=o.maxits,
+                    track_diff=track_diff, check_every=o.check_every,
+                    segment=o.segment_iters, monitor=monitor,
+                    monitor_every=o.monitor_every, fault=fplan, guard=guard),
+                lambda c: _cg_device_seg_resume(
+                    dev, b_pad, c, stop2, diffstop, maxits=o.maxits,
+                    track_diff=track_diff, check_every=o.check_every,
+                    segment=o.segment_iters, monitor=monitor,
+                    monitor_every=o.monitor_every, fault=fplan, guard=guard),
+                o.maxits)
+        else:
+            x, k, rr, dxx, flag, rr0, hist = _cg_device(
+                dev, b_pad, x0_pad, stop2, diffstop,
                 maxits=o.maxits, track_diff=track_diff,
-                check_every=o.check_every, rows_tile=rt, kind=kind,
-                segment=o.segment_iters, monitor=monitor,
-                monitor_every=o.monitor_every, fault=fplan, guard=guard),
-            o.maxits)
-        hpad = padded_halo_rows(dev.offsets, rt) * LANES
-        x = jax.lax.slice_in_dim(x, hpad,
-                                 hpad + b_pad.shape[-1], axis=-1)
-    elif plan is not None:
-        kind, rt = plan
-        x, k, rr, dxx, flag, rr0, hist = _cg_device_fused(
-            dev, b_pad, x0_pad, stop2, diffstop,
-            maxits=o.maxits, track_diff=track_diff,
-            check_every=o.check_every, rows_tile=rt, kind=kind,
-            monitor=monitor, monitor_every=o.monitor_every,
-            fault=fplan, guard=guard)
-    elif o.segment_iters > 0:
-        x, k, rr, dxx, flag, rr0, hist = _run_segmented(
-            lambda: _cg_device_seg(
-                dev, b_pad, x0_pad, stop2, diffstop, maxits=o.maxits,
-                track_diff=track_diff, check_every=o.check_every,
-                segment=o.segment_iters, monitor=monitor,
-                monitor_every=o.monitor_every, fault=fplan, guard=guard),
-            lambda c: _cg_device_seg_resume(
-                dev, b_pad, c, stop2, diffstop, maxits=o.maxits,
-                track_diff=track_diff, check_every=o.check_every,
-                segment=o.segment_iters, monitor=monitor,
-                monitor_every=o.monitor_every, fault=fplan, guard=guard),
-            o.maxits)
-    else:
-        x, k, rr, dxx, flag, rr0, hist = _cg_device(
-            dev, b_pad, x0_pad, stop2, diffstop,
-            maxits=o.maxits, track_diff=track_diff,
-            check_every=o.check_every,
-            monitor=monitor, monitor_every=o.monitor_every,
-            fault=fplan, guard=guard)
-    jax.block_until_ready(x)
-    k = jax.device_get(k)         # scalar, or per-system (B,) when batched
+                check_every=o.check_every,
+                monitor=monitor, monitor_every=o.monitor_every,
+                fault=fplan, guard=guard)
+    k = _device_wait(x, k)        # scalar, or per-system (B,) when batched
     tsolve = time.perf_counter() - t0
     from acg_tpu.solvers.base import kernel_disengagement_note
     note = kernel_disengagement_note(False, plan, None, None,
                                      forced_fmt=fmt)
     return _finish(dev, x, k, rr, flag, rr0, o, tsolve, pipelined=False,
                    bnrm2=bnrm2, dxx=dxx if track_diff else None, stats=stats,
-                   x_host=_unpermute(x, dev.nrows, perm),
+                   to_host=_host_solution(dev.nrows, perm),
                    path=_describe_path(
                        dev, perm, plan,
                        nrhs=b_pad.shape[0] if batched else 1) + (note,),
@@ -1777,96 +1816,99 @@ def aot_step(A, b, x0=None, options: SolverOptions = SolverOptions(),
                                          forced_fmt=fmt)
     path = path + (note,)
 
+    @_traced_call("cg-pipelined-deep" if deep_kind
+                  else "cg-pipelined" if pipelined else "cg")
     def solve(b, x0=None, stats=None, options=None) -> SolveResult:
         # per-dispatch options: tolerance VALUES re-bind as runtime
         # operands of the SAME executable; static fields must match
-        oo = o if options is None else check_aot_options(o, options)
-        _, b_pad, x0_pad, _ = _prepare(A_res, b, x0, dtype, fmt,
-                                       mat_dtype)
-        if b_pad.shape != shape or b_pad.dtype != vdt:
-            raise AcgError(Status.ERR_INVALID_VALUE,
-                           f"AOT signature mismatch: executable was "
-                           f"compiled for shape {shape} dtype {vdt}, "
-                           f"got {b_pad.shape} {b_pad.dtype}")
-        stop2 = (jnp.asarray(oo.residual_atol ** 2, vdt),
-                 jnp.asarray(oo.residual_rtol ** 2, vdt))
-        # the diffstop the jit path computes (cg()), including the
-        # per-system (B,) threshold a batched diffrtol derives from |x0|
-        diffstop = jnp.asarray(oo.diffatol ** 2, vdt)
-        if oo.diffrtol > 0:
-            if batched:
-                x0n = jnp.linalg.norm(x0_pad, axis=-1)
-                diffstop = jnp.maximum(
-                    diffstop, ((oo.diffrtol * x0n) ** 2).astype(vdt))
-            else:
-                x0n = float(jnp.linalg.norm(x0_pad))
-                diffstop = jnp.maximum(
-                    diffstop, jnp.asarray((oo.diffrtol * x0n) ** 2,
-                                          vdt))
-        bnrm2 = jnp.linalg.norm(b_pad, axis=-1) if batched \
-            else jnp.linalg.norm(b_pad)
-        jax.block_until_ready(bnrm2)    # out of the timed window (cg())
-        t0 = time.perf_counter()
-        path2 = path
-        if deep_kind:
-            # the host re-dispatch driver of cg_pipelined_deep against
-            # the fixed executable: no classic-CG fallback here (AOT
-            # never re-traces) — persistent breakdown/drift surfaces as
-            # the returned flag instead
-            l = oo.pipeline_depth
-            sshape = shape[:-1]
-            x_op = x0_pad
-            k_op = jnp.zeros((), jnp.int32)
-            rr0 = jnp.zeros(sshape, vdt)
-            flags_op = jnp.zeros(sshape, jnp.int32)
-            hist = jnp.zeros(sshape + (oo.maxits + 1,), vdt)
-            ksys_op = jnp.zeros(sshape, jnp.int32) if batched else None
-            fails = ndisp = 0
-            while True:
-                ndisp += 1
-                (x_op, k, rr, flag, rr0, hist, k_op, more,
-                 drift) = compiled(dev, b_pad, x_op, stop2,
-                                   k_start=k_op, rr0_in=rr0,
-                                   flags_in=flags_op, hist_in=hist,
-                                   ksys_in=ksys_op)
+        with span("acg.call.prepare"):
+            oo = o if options is None else check_aot_options(o, options)
+            _, b_pad, x0_pad, _ = _prepare(A_res, b, x0, dtype, fmt,
+                                           mat_dtype)
+            if b_pad.shape != shape or b_pad.dtype != vdt:
+                raise AcgError(Status.ERR_INVALID_VALUE,
+                               f"AOT signature mismatch: executable was "
+                               f"compiled for shape {shape} dtype {vdt}, "
+                               f"got {b_pad.shape} {b_pad.dtype}")
+            stop2 = (jnp.asarray(oo.residual_atol ** 2, vdt),
+                     jnp.asarray(oo.residual_rtol ** 2, vdt))
+            # the diffstop the jit path computes (cg()), including the
+            # per-system (B,) threshold a batched diffrtol derives from |x0|
+            diffstop = jnp.asarray(oo.diffatol ** 2, vdt)
+            if oo.diffrtol > 0:
                 if batched:
-                    ksys_op = k
-                flags_h = np.atleast_1d(
-                    np.asarray(jax.device_get(flag)))
-                drift_h = np.atleast_1d(
-                    np.asarray(jax.device_get(drift)))
-                k_h = int(jax.device_get(k_op))
-                if np.any(flags_h == _FAULT):
-                    break
-                bad = bool(np.any(flags_h == _BREAKDOWN)
-                           or np.any(drift_h))
-                fails = fails + 1 if bad else 0
-                if fails >= _DEEP_MAX_BAD:
-                    break
-                flags_op = jnp.where(flag == _BREAKDOWN, _OK,
-                                     flag).astype(jnp.int32)
-                live = np.any((flags_h == _OK)
-                              | (flags_h == _BREAKDOWN))
-                if not (live and k_h < oo.maxits):
-                    break
-            x, dxx = x_op, None
-            path2 = path[:-1] + (
-                f"deep pipeline depth {l}, {ndisp} dispatch(es)"
-                + ("; " + path[-1] if path[-1] else ""),)
-        elif pipelined:
-            x, k, rr, flag, rr0, hist = compiled(
-                dev, b_pad, x0_pad, stop2, fault=None)
-            dxx = None
-        else:
-            x, k, rr, dxx, flag, rr0, hist = compiled(
-                dev, b_pad, x0_pad, stop2, diffstop, fault=None)
-        jax.block_until_ready(x)
-        k = jax.device_get(k)           # real sync (see cg())
+                    x0n = jnp.linalg.norm(x0_pad, axis=-1)
+                    diffstop = jnp.maximum(
+                        diffstop, ((oo.diffrtol * x0n) ** 2).astype(vdt))
+                else:
+                    x0n = float(jnp.linalg.norm(x0_pad))
+                    diffstop = jnp.maximum(
+                        diffstop, jnp.asarray((oo.diffrtol * x0n) ** 2,
+                                              vdt))
+            bnrm2 = jnp.linalg.norm(b_pad, axis=-1) if batched \
+                else jnp.linalg.norm(b_pad)
+            jax.block_until_ready(bnrm2)    # out of the timed window (cg())
+        with span("acg.call.dispatch"):
+            t0 = time.perf_counter()
+            path2 = path
+            if deep_kind:
+                # the host re-dispatch driver of cg_pipelined_deep against
+                # the fixed executable: no classic-CG fallback here (AOT
+                # never re-traces) — persistent breakdown/drift surfaces as
+                # the returned flag instead
+                l = oo.pipeline_depth
+                sshape = shape[:-1]
+                x_op = x0_pad
+                k_op = jnp.zeros((), jnp.int32)
+                rr0 = jnp.zeros(sshape, vdt)
+                flags_op = jnp.zeros(sshape, jnp.int32)
+                hist = jnp.zeros(sshape + (oo.maxits + 1,), vdt)
+                ksys_op = jnp.zeros(sshape, jnp.int32) if batched else None
+                fails = ndisp = 0
+                while True:
+                    ndisp += 1
+                    (x_op, k, rr, flag, rr0, hist, k_op, more,
+                     drift) = compiled(dev, b_pad, x_op, stop2,
+                                       k_start=k_op, rr0_in=rr0,
+                                       flags_in=flags_op, hist_in=hist,
+                                       ksys_in=ksys_op)
+                    if batched:
+                        ksys_op = k
+                    flags_h = np.atleast_1d(
+                        np.asarray(jax.device_get(flag)))
+                    drift_h = np.atleast_1d(
+                        np.asarray(jax.device_get(drift)))
+                    k_h = int(jax.device_get(k_op))
+                    if np.any(flags_h == _FAULT):
+                        break
+                    bad = bool(np.any(flags_h == _BREAKDOWN)
+                               or np.any(drift_h))
+                    fails = fails + 1 if bad else 0
+                    if fails >= _DEEP_MAX_BAD:
+                        break
+                    flags_op = jnp.where(flag == _BREAKDOWN, _OK,
+                                         flag).astype(jnp.int32)
+                    live = np.any((flags_h == _OK)
+                                  | (flags_h == _BREAKDOWN))
+                    if not (live and k_h < oo.maxits):
+                        break
+                x, dxx = x_op, None
+                path2 = path[:-1] + (
+                    f"deep pipeline depth {l}, {ndisp} dispatch(es)"
+                    + ("; " + path[-1] if path[-1] else ""),)
+            elif pipelined:
+                x, k, rr, flag, rr0, hist = compiled(
+                    dev, b_pad, x0_pad, stop2, fault=None)
+                dxx = None
+            else:
+                x, k, rr, dxx, flag, rr0, hist = compiled(
+                    dev, b_pad, x0_pad, stop2, diffstop, fault=None)
+        k = _device_wait(x, k)          # real sync (see cg())
         tsolve = time.perf_counter() - t0
         return _finish(dev, x, k, rr, flag, rr0, oo, tsolve,
                        pipelined=pipelined or deep_kind, bnrm2=bnrm2,
                        dxx=dxx if track_diff else None, stats=stats,
-                       x_host=_unpermute(x, dev.nrows, perm),
+                       to_host=_host_solution(dev.nrows, perm),
                        path=path2, hist=hist,
                        solver=("cg-pipelined-deep" if deep_kind
                                else None))
@@ -1877,6 +1919,7 @@ def aot_step(A, b, x0=None, options: SolverOptions = SolverOptions(),
                     shape=shape, vec_dtype=vdt, path=path)
 
 
+@_traced_call("cg-pipelined")
 def cg_pipelined(A, b, x0=None, options: SolverOptions = SolverOptions(),
                  dtype=None, fmt: str = "auto", mat_dtype="auto",
                  stats: SolveStats | None = None,
@@ -1888,106 +1931,107 @@ def cg_pipelined(A, b, x0=None, options: SolverOptions = SolverOptions(),
     if o.diffatol > 0 or o.diffrtol > 0:
         raise AcgError(Status.ERR_NOT_SUPPORTED,
                        "pipelined CG supports residual-based stopping only")
-    dev, b_pad, x0_pad, perm = _prepare(A, b, x0, dtype, fmt, mat_dtype)
-    batched = b_pad.ndim == 2
-    vdt = b_pad.dtype
-    fplan = _fault_plan(fault, vdt)
-    guard = o.guard_nonfinite
-    stop2 = (jnp.asarray(o.residual_atol**2, vdt),
-             jnp.asarray(o.residual_rtol**2, vdt))
-    bnrm2 = jnp.linalg.norm(b_pad, axis=-1) if batched \
-        else jnp.linalg.norm(b_pad)
-    jax.block_until_ready(bnrm2)
-    # batched pipelined solves run the plain loop: the operator matvec
-    # itself routes (B, n) vectors through the batched SpMV kernel when
-    # its gate passes (dia_matvec_best), and the pipelined recurrences
-    # have no <p, Ap> reduction for the fused-dot kernel to win on
-    plan = None if batched else _fused_plan(dev)
-    # exit certification is only needed when an exit can be claimed; a
-    # fixed-iteration solve (the benchmark protocol) statically drops the
-    # certifier branch, whose lax.cond was measured carrying ~4 extra
-    # vector streams/iter through the conditional (PERF.md round 5)
-    certify = o.residual_atol > 0 or o.residual_rtol > 0
-    monitor = _resolve_monitor(o)
-    pipe_rt = None
-    # the matrix-free single-kernel pipelined iteration (stencil tier):
-    # same role as pipe_rt on the DIA tier, gated the same way; the
-    # segmented driver keeps the open-coded body (its carry-resume
-    # contract is the plain loop's)
-    st_rt = (None if batched or o.segment_iters > 0
-             else _stencil_pipe_rt(dev, fplan))
-    t0 = time.perf_counter()
-    if plan is not None and o.segment_iters > 0:
-        # segmented fused pipelined solve (PR 7: the pipelined twin of
-        # classic's carry-resume segmentation): pad once, re-dispatch
-        # the SAME loop body per segment from the exact carry
-        from acg_tpu.ops.pallas_kernels import LANES, padded_halo_rows
+    with span("acg.call.prepare"):
+        dev, b_pad, x0_pad, perm = _prepare(A, b, x0, dtype, fmt, mat_dtype)
+        batched = b_pad.ndim == 2
+        vdt = b_pad.dtype
+        fplan = _fault_plan(fault, vdt)
+        guard = o.guard_nonfinite
+        stop2 = (jnp.asarray(o.residual_atol**2, vdt),
+                 jnp.asarray(o.residual_rtol**2, vdt))
+        bnrm2 = jnp.linalg.norm(b_pad, axis=-1) if batched \
+            else jnp.linalg.norm(b_pad)
+        jax.block_until_ready(bnrm2)
+        # batched pipelined solves run the plain loop: the operator matvec
+        # itself routes (B, n) vectors through the batched SpMV kernel when
+        # its gate passes (dia_matvec_best), and the pipelined recurrences
+        # have no <p, Ap> reduction for the fused-dot kernel to win on
+        plan = None if batched else _fused_plan(dev)
+        # exit certification is only needed when an exit can be claimed; a
+        # fixed-iteration solve (the benchmark protocol) statically drops the
+        # certifier branch, whose lax.cond was measured carrying ~4 extra
+        # vector streams/iter through the conditional (PERF.md round 5)
+        certify = o.residual_atol > 0 or o.residual_rtol > 0
+        monitor = _resolve_monitor(o)
+        pipe_rt = None
+        # the matrix-free single-kernel pipelined iteration (stencil tier):
+        # same role as pipe_rt on the DIA tier, gated the same way; the
+        # segmented driver keeps the open-coded body (its carry-resume
+        # contract is the plain loop's)
+        st_rt = (None if batched or o.segment_iters > 0
+                 else _stencil_pipe_rt(dev, fplan))
+    with span("acg.call.dispatch"):
+        t0 = time.perf_counter()
+        if plan is not None and o.segment_iters > 0:
+            # segmented fused pipelined solve (PR 7: the pipelined twin of
+            # classic's carry-resume segmentation): pad once, re-dispatch
+            # the SAME loop body per segment from the exact carry
+            from acg_tpu.ops.pallas_kernels import LANES, padded_halo_rows
 
-        kind, rt = plan
-        pipe_rt = (None if fplan is not None
-                   else _pipe2d_rt(dev, plan))
-        bands_pad, (bp2, xp2) = _pad_fused(dev, b_pad, x0_pad, rt)
-        x, k, rr, flag, rr0, hist = _run_segmented(
-            lambda: _cg_pipelined_fused_seg(
-                dev, bands_pad, bp2, xp2, stop2, maxits=o.maxits,
-                check_every=o.check_every,
-                replace_every=o.replace_every, rows_tile=rt, kind=kind,
-                certify=certify, pipe_rt=pipe_rt,
-                segment=o.segment_iters, monitor=monitor,
-                monitor_every=o.monitor_every, fault=fplan, guard=guard),
-            lambda c: _cg_pipelined_fused_seg_resume(
-                dev, bands_pad, bp2, c, stop2, maxits=o.maxits,
-                check_every=o.check_every,
-                replace_every=o.replace_every, rows_tile=rt, kind=kind,
-                certify=certify, pipe_rt=pipe_rt,
-                segment=o.segment_iters, monitor=monitor,
-                monitor_every=o.monitor_every, fault=fplan, guard=guard),
-            o.maxits, continue_fn=_pipelined_continue)
-        hpad = padded_halo_rows(dev.offsets, rt) * LANES
-        x = jax.lax.slice_in_dim(x, hpad, hpad + b_pad.shape[-1],
-                                 axis=-1)
-    elif plan is not None:
-        kind, rt = plan
-        # the single-kernel pipelined iteration exposes no injection
-        # sites — injection solves run the open-coded body instead
-        pipe_rt = (None if fplan is not None
-                   else _pipe2d_rt(dev, plan))
-        x, k, rr, flag, rr0, hist = _cg_pipelined_device_fused(
-            dev, b_pad, x0_pad, stop2, maxits=o.maxits,
-            check_every=o.check_every, replace_every=o.replace_every,
-            rows_tile=rt, kind=kind, certify=certify,
-            pipe_rt=pipe_rt,
-            monitor=monitor, monitor_every=o.monitor_every,
-            fault=fplan, guard=guard)
-    elif st_rt is not None:
-        x, k, rr, flag, rr0, hist = _cg_pipelined_stencil_fused(
-            dev, b_pad, x0_pad, stop2, maxits=o.maxits,
-            check_every=o.check_every, certify=certify, pipe_rt=st_rt,
-            replace_every=o.replace_every, monitor=monitor,
-            monitor_every=o.monitor_every, guard=guard)
-    elif o.segment_iters > 0:
-        x, k, rr, flag, rr0, hist = _run_segmented(
-            lambda: _cg_pipelined_device_seg(
+            kind, rt = plan
+            pipe_rt = (None if fplan is not None
+                       else _pipe2d_rt(dev, plan))
+            bands_pad, (bp2, xp2) = _pad_fused(dev, b_pad, x0_pad, rt)
+            x, k, rr, flag, rr0, hist = _run_segmented(
+                lambda: _cg_pipelined_fused_seg(
+                    dev, bands_pad, bp2, xp2, stop2, maxits=o.maxits,
+                    check_every=o.check_every,
+                    replace_every=o.replace_every, rows_tile=rt, kind=kind,
+                    certify=certify, pipe_rt=pipe_rt,
+                    segment=o.segment_iters, monitor=monitor,
+                    monitor_every=o.monitor_every, fault=fplan, guard=guard),
+                lambda c: _cg_pipelined_fused_seg_resume(
+                    dev, bands_pad, bp2, c, stop2, maxits=o.maxits,
+                    check_every=o.check_every,
+                    replace_every=o.replace_every, rows_tile=rt, kind=kind,
+                    certify=certify, pipe_rt=pipe_rt,
+                    segment=o.segment_iters, monitor=monitor,
+                    monitor_every=o.monitor_every, fault=fplan, guard=guard),
+                o.maxits, continue_fn=_pipelined_continue)
+            hpad = padded_halo_rows(dev.offsets, rt) * LANES
+            x = jax.lax.slice_in_dim(x, hpad, hpad + b_pad.shape[-1],
+                                     axis=-1)
+        elif plan is not None:
+            kind, rt = plan
+            # the single-kernel pipelined iteration exposes no injection
+            # sites — injection solves run the open-coded body instead
+            pipe_rt = (None if fplan is not None
+                       else _pipe2d_rt(dev, plan))
+            x, k, rr, flag, rr0, hist = _cg_pipelined_device_fused(
                 dev, b_pad, x0_pad, stop2, maxits=o.maxits,
-                check_every=o.check_every,
-                replace_every=o.replace_every, certify=certify,
-                segment=o.segment_iters, monitor=monitor,
-                monitor_every=o.monitor_every, fault=fplan, guard=guard),
-            lambda c: _cg_pipelined_device_seg_resume(
-                dev, b_pad, c, stop2, maxits=o.maxits,
-                check_every=o.check_every,
-                replace_every=o.replace_every, certify=certify,
-                segment=o.segment_iters, monitor=monitor,
-                monitor_every=o.monitor_every, fault=fplan, guard=guard),
-            o.maxits, continue_fn=_pipelined_continue)
-    else:
-        x, k, rr, flag, rr0, hist = _cg_pipelined_device(
-            dev, b_pad, x0_pad, stop2, maxits=o.maxits,
-            check_every=o.check_every, replace_every=o.replace_every,
-            certify=certify, monitor=monitor,
-            monitor_every=o.monitor_every, fault=fplan, guard=guard)
-    jax.block_until_ready(x)
-    k = jax.device_get(k)        # k may be per-system
+                check_every=o.check_every, replace_every=o.replace_every,
+                rows_tile=rt, kind=kind, certify=certify,
+                pipe_rt=pipe_rt,
+                monitor=monitor, monitor_every=o.monitor_every,
+                fault=fplan, guard=guard)
+        elif st_rt is not None:
+            x, k, rr, flag, rr0, hist = _cg_pipelined_stencil_fused(
+                dev, b_pad, x0_pad, stop2, maxits=o.maxits,
+                check_every=o.check_every, certify=certify, pipe_rt=st_rt,
+                replace_every=o.replace_every, monitor=monitor,
+                monitor_every=o.monitor_every, guard=guard)
+        elif o.segment_iters > 0:
+            x, k, rr, flag, rr0, hist = _run_segmented(
+                lambda: _cg_pipelined_device_seg(
+                    dev, b_pad, x0_pad, stop2, maxits=o.maxits,
+                    check_every=o.check_every,
+                    replace_every=o.replace_every, certify=certify,
+                    segment=o.segment_iters, monitor=monitor,
+                    monitor_every=o.monitor_every, fault=fplan, guard=guard),
+                lambda c: _cg_pipelined_device_seg_resume(
+                    dev, b_pad, c, stop2, maxits=o.maxits,
+                    check_every=o.check_every,
+                    replace_every=o.replace_every, certify=certify,
+                    segment=o.segment_iters, monitor=monitor,
+                    monitor_every=o.monitor_every, fault=fplan, guard=guard),
+                o.maxits, continue_fn=_pipelined_continue)
+        else:
+            x, k, rr, flag, rr0, hist = _cg_pipelined_device(
+                dev, b_pad, x0_pad, stop2, maxits=o.maxits,
+                check_every=o.check_every, replace_every=o.replace_every,
+                certify=certify, monitor=monitor,
+                monitor_every=o.monitor_every, fault=fplan, guard=guard)
+    k = _device_wait(x, k)       # k may be per-system
     tsolve = time.perf_counter() - t0
     from acg_tpu.ops.stencil import DeviceStencil
     from acg_tpu.solvers.base import kernel_disengagement_note
@@ -2006,7 +2050,7 @@ def cg_pipelined(A, b, x0=None, options: SolverOptions = SolverOptions(),
             stencil_interpret=is_st and dev.interpret)
     return _finish(dev, x, k, rr, flag, rr0, o, tsolve, pipelined=True,
                    bnrm2=bnrm2, stats=stats,
-                   x_host=_unpermute(x, dev.nrows, perm),
+                   to_host=_host_solution(dev.nrows, perm),
                    path=path + (note,), hist=hist)
 
 
@@ -2056,7 +2100,7 @@ def _cg_pipelined_deep_device(op, b, x0, stop2, depth: int, maxits: int,
     certification are one jitted program (loops.cg_pipelined_deep_while).
     All restart state is operands, so every dispatch of a solve — first
     or resumed — reuses this ONE compiled executable."""
-    mv = _scoped_matvec(op)
+    mv = op.matvec
 
     def dots_fn(U, v):
         # the fused (2l+1)-dot block: one reduction over the vector axis
@@ -2075,6 +2119,7 @@ def _cg_pipelined_deep_device(op, b, x0, stop2, depth: int, maxits: int,
         monitor=monitor, monitor_every=monitor_every, guard=guard)
 
 
+@_traced_call("cg-pipelined-deep")
 def cg_pipelined_deep(A, b, x0=None,
                       options: SolverOptions = SolverOptions(),
                       dtype=None, fmt: str = "auto", mat_dtype="auto",
@@ -2106,81 +2151,82 @@ def cg_pipelined_deep(A, b, x0=None,
                             mat_dtype=mat_dtype, stats=stats,
                             fault=fault)
     l = _deep_validate(o, fault)
-    dev, b_pad, x0_pad, perm = _prepare(A, b, x0, dtype, fmt, mat_dtype)
-    batched = b_pad.ndim == 2
-    vdt = b_pad.dtype
-    stop2 = (jnp.asarray(o.residual_atol ** 2, vdt),
-             jnp.asarray(o.residual_rtol ** 2, vdt))
-    bnrm2 = jnp.linalg.norm(b_pad, axis=-1) if batched \
-        else jnp.linalg.norm(b_pad)
-    jax.block_until_ready(bnrm2)
-    certify = o.residual_atol > 0 or o.residual_rtol > 0
-    monitor = _resolve_monitor(o)
-    if shifts0 is not None:
-        shifts0 = jnp.asarray(shifts0, vdt)
-        if batched and shifts0.ndim == 1:
-            shifts0 = jnp.tile(shifts0, (b_pad.shape[0], 1))
-    sshape = b_pad.shape[:-1]
-    # restart operands (see the loop's dispatch protocol)
-    x_op = x0_pad
-    k_op = jnp.zeros((), jnp.int32)
-    rr0_op = jnp.zeros(sshape, vdt)
-    flags_op = jnp.zeros(sshape, jnp.int32)
-    hist_op = jnp.zeros(sshape + (o.maxits + 1,), vdt)
-    ksys_op = jnp.zeros(sshape, jnp.int32) if batched else None
-    fails = ndisp = 0
-    t0 = time.perf_counter()
-    while True:
-        (x_op, kret, rr, flag, rr0_op, hist_op, k_op, more,
-         drift) = _cg_pipelined_deep_device(
-            dev, b_pad, x_op, stop2, depth=l, maxits=o.maxits,
-            check_every=o.check_every, replace_every=o.replace_every,
-            certify=certify, k_start=k_op, rr0_in=rr0_op,
-            flags_in=flags_op, hist_in=hist_op, ksys_in=ksys_op,
-            monitor=monitor, monitor_every=o.monitor_every,
-            guard=o.guard_nonfinite, shifts0=shifts0)
-        ndisp += 1
-        if batched:
-            ksys_op = kret
-        flags_h = np.atleast_1d(np.asarray(jax.device_get(flag)))
-        drift_h = np.atleast_1d(np.asarray(jax.device_get(drift)))
-        k_h = int(jax.device_get(k_op))
-        if np.any(flags_h == _FAULT):
-            break    # the finiteness guard fired: no restart, surface it
-        bad = bool(np.any(flags_h == _BREAKDOWN) or np.any(drift_h))
-        fails = fails + 1 if bad else 0
-        if fails >= _DEEP_MAX_BAD:
-            # ISSUE 7 discipline: never silently wrong — classic CG
-            # re-solves from the last safe iterate
-            why = ("indefinite Gram/LDL pivot" if np.any(
-                flags_h == _BREAKDOWN) else "certified-exit drift")
-            ksys_h = (np.asarray(jax.device_get(kret)) if batched
-                      else None)
-            x_part = _unpermute(x_op, dev.nrows, perm)
-            if x_part is None:
-                x_part = np.asarray(x_op)[..., : dev.nrows]
-            x_part = _sstep_fallback_x0(x_part, x0, rr, rr0_op)
-            o2 = dataclasses.replace(o, pipeline_depth=1,
-                                     maxits=max(o.maxits - k_h, 0))
-            floor = _sstep_fallback_stop(o, rr0_op)
-            return _sstep_fallback(
-                lambda: cg(A, b, x0=x_part, options=o2, dtype=dtype,
-                           fmt=fmt, mat_dtype=mat_dtype, stats=stats,
-                           atol2_floor=floor),
-                k_h, ksys_h, l, why,
-                spent_flops=k_h * cg_flops_per_iter(
-                    dev.nnz, dev.nrows, pipelined=True),
-                label=f"cg-pipelined-deep(l={l})")
-        # restart: breakdown systems get one more chance with a fresh
-        # basis (the re-dispatch replaces their residual); drift systems
-        # are still _OK and simply keep iterating
-        live = np.any((flags_h == _OK) | (flags_h == _BREAKDOWN))
-        flags_op = jnp.where(flag == _BREAKDOWN, _OK,
-                             flag).astype(jnp.int32)
-        if not (live and k_h < o.maxits):
-            break
-    jax.block_until_ready(x_op)
-    k_get = jax.device_get(kret)
+    with span("acg.call.prepare"):
+        dev, b_pad, x0_pad, perm = _prepare(A, b, x0, dtype, fmt, mat_dtype)
+        batched = b_pad.ndim == 2
+        vdt = b_pad.dtype
+        stop2 = (jnp.asarray(o.residual_atol ** 2, vdt),
+                 jnp.asarray(o.residual_rtol ** 2, vdt))
+        bnrm2 = jnp.linalg.norm(b_pad, axis=-1) if batched \
+            else jnp.linalg.norm(b_pad)
+        jax.block_until_ready(bnrm2)
+        certify = o.residual_atol > 0 or o.residual_rtol > 0
+        monitor = _resolve_monitor(o)
+        if shifts0 is not None:
+            shifts0 = jnp.asarray(shifts0, vdt)
+            if batched and shifts0.ndim == 1:
+                shifts0 = jnp.tile(shifts0, (b_pad.shape[0], 1))
+        sshape = b_pad.shape[:-1]
+        # restart operands (see the loop's dispatch protocol)
+        x_op = x0_pad
+        k_op = jnp.zeros((), jnp.int32)
+        rr0_op = jnp.zeros(sshape, vdt)
+        flags_op = jnp.zeros(sshape, jnp.int32)
+        hist_op = jnp.zeros(sshape + (o.maxits + 1,), vdt)
+        ksys_op = jnp.zeros(sshape, jnp.int32) if batched else None
+        fails = ndisp = 0
+    with span("acg.call.dispatch"):
+        t0 = time.perf_counter()
+        while True:
+            (x_op, kret, rr, flag, rr0_op, hist_op, k_op, more,
+             drift) = _cg_pipelined_deep_device(
+                dev, b_pad, x_op, stop2, depth=l, maxits=o.maxits,
+                check_every=o.check_every, replace_every=o.replace_every,
+                certify=certify, k_start=k_op, rr0_in=rr0_op,
+                flags_in=flags_op, hist_in=hist_op, ksys_in=ksys_op,
+                monitor=monitor, monitor_every=o.monitor_every,
+                guard=o.guard_nonfinite, shifts0=shifts0)
+            ndisp += 1
+            if batched:
+                ksys_op = kret
+            flags_h = np.atleast_1d(np.asarray(jax.device_get(flag)))
+            drift_h = np.atleast_1d(np.asarray(jax.device_get(drift)))
+            k_h = int(jax.device_get(k_op))
+            if np.any(flags_h == _FAULT):
+                break    # the finiteness guard fired: no restart, surface it
+            bad = bool(np.any(flags_h == _BREAKDOWN) or np.any(drift_h))
+            fails = fails + 1 if bad else 0
+            if fails >= _DEEP_MAX_BAD:
+                # ISSUE 7 discipline: never silently wrong — classic CG
+                # re-solves from the last safe iterate
+                why = ("indefinite Gram/LDL pivot" if np.any(
+                    flags_h == _BREAKDOWN) else "certified-exit drift")
+                ksys_h = (np.asarray(jax.device_get(kret)) if batched
+                          else None)
+                x_part = _unpermute(x_op, dev.nrows, perm)
+                if x_part is None:
+                    x_part = np.asarray(x_op)[..., : dev.nrows]
+                x_part = _sstep_fallback_x0(x_part, x0, rr, rr0_op)
+                o2 = dataclasses.replace(o, pipeline_depth=1,
+                                         maxits=max(o.maxits - k_h, 0))
+                floor = _sstep_fallback_stop(o, rr0_op)
+                return _sstep_fallback(
+                    lambda: cg(A, b, x0=x_part, options=o2, dtype=dtype,
+                               fmt=fmt, mat_dtype=mat_dtype, stats=stats,
+                               atol2_floor=floor),
+                    k_h, ksys_h, l, why,
+                    spent_flops=k_h * cg_flops_per_iter(
+                        dev.nnz, dev.nrows, pipelined=True),
+                    label=f"cg-pipelined-deep(l={l})")
+            # restart: breakdown systems get one more chance with a fresh
+            # basis (the re-dispatch replaces their residual); drift systems
+            # are still _OK and simply keep iterating
+            live = np.any((flags_h == _OK) | (flags_h == _BREAKDOWN))
+            flags_op = jnp.where(flag == _BREAKDOWN, _OK,
+                                 flag).astype(jnp.int32)
+            if not (live and k_h < o.maxits):
+                break
+    k_get = _device_wait(x_op, kret)
     tsolve = time.perf_counter() - t0
     from acg_tpu.solvers.base import kernel_disengagement_note
     note = kernel_disengagement_note(False, None, None, None,
@@ -2189,6 +2235,6 @@ def cg_pipelined_deep(A, b, x0=None,
             + ("; " + note if note else ""))
     return _finish(dev, x_op, k_get, rr, flag, rr0_op, o, tsolve,
                    pipelined=True, bnrm2=bnrm2, stats=stats,
-                   x_host=_unpermute(x_op, dev.nrows, perm),
+                   to_host=_host_solution(dev.nrows, perm),
                    path=_describe_path(dev, perm, None) + (note,),
                    hist=hist_op, solver="cg-pipelined-deep")

@@ -34,6 +34,7 @@ from jax.sharding import PartitionSpec as P
 
 from acg_tpu.config import HaloMethod, SolverOptions
 from acg_tpu.errors import AcgError, Status
+from acg_tpu.obs.trace import span
 from acg_tpu.ops.spmv import ell_matvec
 from acg_tpu.parallel.mesh import PARTS_AXIS
 from acg_tpu.parallel.sharded import ShardedSystem, resolve_local_fmt
@@ -41,11 +42,12 @@ from acg_tpu.partition.graph import PartitionedSystem, partition_system
 from acg_tpu.partition.partitioner import partition_graph
 from acg_tpu.solvers.base import SolveResult, SolveStats
 from acg_tpu.solvers.cg import (_CONVERGED, _GRAM_BAD, _cheb_leja_nodes,
-                                _deflate_x0, _finish,
+                                _deflate_x0, _device_wait, _finish,
                                 _pipelined_continue, _power_lmax,
                                 _run_segmented, _sstep_certify,
                                 _sstep_fallback, _sstep_fallback_stop,
-                                _sstep_fallback_x0, _sstep_validate)
+                                _sstep_fallback_x0, _sstep_validate,
+                                _traced_call)
 from acg_tpu.solvers.loops import (cg_pipelined_deep_while,
                                    cg_pipelined_while, cg_sstep_while,
                                    cg_while)
@@ -261,17 +263,16 @@ def _shard_solver(ss: ShardedSystem, kind: str, maxits: int,
             # begin/local/end/interface schedule (acg/cgcuda.c:847-883)
             return halo_fn(x_own, sidx, ridx, ptnr, pidx, gsp, gpp)
 
-        from acg_tpu.ops.blas1 import batched_dot
+        from acg_tpu.ops.blas1 import allreduce, batched_dot
 
         def dot(a, c):
             # batched_dot is exactly jnp.vdot on 1-D shards; per-system
             # (B,) on batched shards — ONE psum either way
-            return jax.lax.psum(batched_dot(a, c), PARTS_AXIS)
+            return allreduce(batched_dot(a, c), PARTS_AXIS)
 
         def dot2(a1, b1, a2, b2):
-            s = jax.lax.psum(jnp.stack([batched_dot(a1, b1),
-                                        batched_dot(a2, b2)]),
-                             PARTS_AXIS)
+            s = allreduce(jnp.stack([batched_dot(a1, b1),
+                                     batched_dot(a2, b2)]), PARTS_AXIS)
             return s[0], s[1]
 
         coupled = None
@@ -294,12 +295,12 @@ def _shard_solver(ss: ShardedSystem, kind: str, maxits: int,
                 # path below is structurally unfusable and is pinned in
                 # BOTH directions.  The named scopes are what the HLO
                 # tests key on.
-                with jax.named_scope("halo"):
-                    gh = halo_of(x)
-                with jax.named_scope("local_spmv"):
+                gh = halo_of(x)
+                with jax.named_scope("acg.spmv.local"):
                     y_local = jax.lax.optimization_barrier(
                         local_mv(x, lops))
-                return y_local + ell_matvec(iv, ic, gh)
+                with jax.named_scope("acg.spmv.iface"):
+                    return y_local + ell_matvec(iv, ic, gh)
         else:
             # the fused padded path, per shard: vectors carry a permanent
             # zero halo (padded once per SOLVE, zero per-iteration pads —
@@ -324,25 +325,30 @@ def _shard_solver(ss: ShardedSystem, kind: str, maxits: int,
                 return jax.lax.slice(xp, (front,), (front + nown,))
 
             def matvec(xp):
-                with jax.named_scope("halo"):
-                    gh = halo_of(own_view(xp))
-                with jax.named_scope("local_spmv"):
+                with jax.named_scope("acg.halo.pack"):
+                    xo = own_view(xp)
+                gh = halo_of(xo)
+                with jax.named_scope("acg.spmv.local"):
                     t = kernel(bands_pad, offsets, xp, rows_tile=rt,
                                scales=scales)
-                return t.at[front: front + nown].add(
-                    ell_matvec(iv, ic, gh))
+                with jax.named_scope("acg.spmv.iface"):
+                    return t.at[front: front + nown].add(
+                        ell_matvec(iv, ic, gh))
 
             def coupled(r, p, beta):
-                p = r + beta * p
-                po = own_view(p)
-                with jax.named_scope("halo"):
-                    gh = halo_of(po)
-                with jax.named_scope("local_spmv"):
+                with jax.named_scope("acg.blas1"):
+                    p = r + beta * p
+                    po = own_view(p)
+                gh = halo_of(po)
+                with jax.named_scope("acg.spmv.local"):
                     t, pdot = kernel(bands_pad, offsets, p, rows_tile=rt,
                                      with_dot=True, scales=scales)
-                iface = ell_matvec(iv, ic, gh)
-                t = t.at[front: front + nown].add(iface)
-                ptap = jax.lax.psum(pdot + jnp.vdot(po, iface), PARTS_AXIS)
+                with jax.named_scope("acg.spmv.iface"):
+                    iface = ell_matvec(iv, ic, gh)
+                    t = t.at[front: front + nown].add(iface)
+                with jax.named_scope("acg.blas1"):
+                    pdot = pdot + jnp.vdot(po, iface)
+                ptap = allreduce(pdot, PARTS_AXIS)
                 return p, t, ptap
 
             if pipe_rt is not None:
@@ -357,19 +363,22 @@ def _shard_solver(ss: ShardedSystem, kind: str, maxits: int,
                     #   delta = delta_k - alpha·<I, r'>
                     # (p, s, x, r, gamma are q-free and unaffected;
                     # derivation in PERF.md round 5)
-                    with jax.named_scope("halo"):
-                        gh = halo_of(own_view(w))
-                    with jax.named_scope("local_spmv"):
+                    with jax.named_scope("acg.halo.pack"):
+                        wo = own_view(w)
+                    gh = halo_of(wo)
+                    with jax.named_scope("acg.spmv.local"):
                         zk, pk, sk, xk, rk, wk, gk, dk = \
                             cg_pipelined_iter_pallas(
                                 bands_pad, offsets, w, z, r, p, s, x,
                                 alpha, beta, rows_tile=pipe_rt,
                                 scales=scales)
-                    iface = ell_matvec(iv, ic, gh)
-                    z2 = zk.at[front: front + nown].add(iface)
-                    w2 = wk.at[front: front + nown].add(-alpha * iface)
-                    dloc = dk - alpha * jnp.vdot(iface, own_view(rk))
-                    tot = jax.lax.psum(jnp.stack([gk, dloc]), PARTS_AXIS)
+                    with jax.named_scope("acg.spmv.iface"):
+                        iface = ell_matvec(iv, ic, gh)
+                        z2 = zk.at[front: front + nown].add(iface)
+                        w2 = wk.at[front: front + nown].add(-alpha * iface)
+                    with jax.named_scope("acg.blas1"):
+                        dloc = dk - alpha * jnp.vdot(iface, own_view(rk))
+                    tot = allreduce(jnp.stack([gk, dloc]), PARTS_AXIS)
                     return z2, pk, sk, xk, rk, w2, tot[0], tot[1]
 
         carry_out = ()
@@ -418,25 +427,30 @@ def _shard_solver(ss: ShardedSystem, kind: str, maxits: int,
                 # shape either way)
                 lead = v.shape[:-1]
                 if v.ndim > 2:
-                    v = v.reshape((-1, v.shape[-1]))
-                with jax.named_scope("deep_halo"):
-                    if method == HaloMethod.PPERMUTE:
-                        out = halo_ppermute(v, dsi, dri, deep_perms,
-                                            gd, PARTS_AXIS, wire=wire)
-                    else:
-                        out = halo_allgather(v, dpck, dgsp, dgpp,
-                                             PARTS_AXIS, wire=wire)
-                return (out.reshape(lead + out.shape[-1:])
-                        if len(lead) > 1 else out)
+                    with jax.named_scope("acg.halo.pack"):
+                        v = v.reshape((-1, v.shape[-1]))
+                if method == HaloMethod.PPERMUTE:
+                    out = halo_ppermute(v, dsi, dri, deep_perms,
+                                        gd, PARTS_AXIS, wire=wire)
+                else:
+                    out = halo_allgather(v, dpck, dgsp, dgpp,
+                                         PARTS_AXIS, wire=wire)
+                with jax.named_scope("acg.halo.unpack"):
+                    return (out.reshape(lead + out.shape[-1:])
+                            if len(lead) > 1 else out)
 
             def ext_mv(ve):
-                vo = jax.lax.slice_in_dim(ve, 0, nown, axis=-1)
-                vg = jax.lax.slice_in_dim(ve, nown, nown + gd, axis=-1)
-                with jax.named_scope("local_spmv"):
-                    yo = local_mv(vo, lops) + ell_matvec(difv, difc, vg)
-                with jax.named_scope("skin_spmv"):
-                    yg = ell_matvec(dgrv, dgrc, ve)
-                return jnp.concatenate([yo, yg], axis=-1)
+                with jax.named_scope("acg.spmv"):
+                    vo = jax.lax.slice_in_dim(ve, 0, nown, axis=-1)
+                    vg = jax.lax.slice_in_dim(ve, nown, nown + gd,
+                                              axis=-1)
+                    with jax.named_scope("acg.spmv.local"):
+                        yo = local_mv(vo, lops)
+                    with jax.named_scope("acg.spmv.iface"):
+                        yo = yo + ell_matvec(difv, difc, vg)
+                    with jax.named_scope("acg.spmv.skin"):
+                        yg = ell_matvec(dgrv, dgrc, ve)
+                    return jnp.concatenate([yo, yg], axis=-1)
 
             bce = (lambda t: t[..., None]) if nrhs > 1 else (lambda t: t)
             # b's deep-ghost values are loop constants: exchanged once
@@ -504,28 +518,33 @@ def _shard_solver(ss: ShardedSystem, kind: str, maxits: int,
             def deep_halo(v):
                 lead = v.shape[:-1]
                 if v.ndim > 2:
-                    v = v.reshape((-1, v.shape[-1]))
-                with jax.named_scope("deep_halo"):
-                    if method == HaloMethod.PPERMUTE:
-                        out = halo_ppermute(v, dsi, dri, deep_perms,
-                                            gd, PARTS_AXIS, wire=wire)
-                    else:
-                        out = halo_allgather(v, dpck, dgsp, dgpp,
-                                             PARTS_AXIS, wire=wire)
-                return (out.reshape(lead + out.shape[-1:])
-                        if len(lead) > 1 else out)
+                    with jax.named_scope("acg.halo.pack"):
+                        v = v.reshape((-1, v.shape[-1]))
+                if method == HaloMethod.PPERMUTE:
+                    out = halo_ppermute(v, dsi, dri, deep_perms,
+                                        gd, PARTS_AXIS, wire=wire)
+                else:
+                    out = halo_allgather(v, dpck, dgsp, dgpp,
+                                         PARTS_AXIS, wire=wire)
+                with jax.named_scope("acg.halo.unpack"):
+                    return (out.reshape(lead + out.shape[-1:])
+                            if len(lead) > 1 else out)
 
             def ext_mv(ve):
                 # owned rows: the shard's own local tier + the deep-
                 # remapped interface ELL; ghost-interior rows: the small
                 # skin ELL over [owned | deep ghosts] (parallel/deep.py)
-                vo = jax.lax.slice_in_dim(ve, 0, nown, axis=-1)
-                vg = jax.lax.slice_in_dim(ve, nown, nown + gd, axis=-1)
-                with jax.named_scope("local_spmv"):
-                    yo = local_mv(vo, lops) + ell_matvec(difv, difc, vg)
-                with jax.named_scope("skin_spmv"):
-                    yg = ell_matvec(dgrv, dgrc, ve)
-                return jnp.concatenate([yo, yg], axis=-1)
+                with jax.named_scope("acg.spmv"):
+                    vo = jax.lax.slice_in_dim(ve, 0, nown, axis=-1)
+                    vg = jax.lax.slice_in_dim(ve, nown, nown + gd,
+                                              axis=-1)
+                    with jax.named_scope("acg.spmv.local"):
+                        yo = local_mv(vo, lops)
+                    with jax.named_scope("acg.spmv.iface"):
+                        yo = yo + ell_matvec(difv, difc, vg)
+                    with jax.named_scope("acg.spmv.skin"):
+                        yg = ell_matvec(dgrv, dgrc, ve)
+                    return jnp.concatenate([yo, yg], axis=-1)
 
             bce = (lambda t: t[..., None]) if nrhs > 1 else (lambda t: t)
             lam = _power_lmax(matvec, dot, b)
@@ -547,20 +566,19 @@ def _shard_solver(ss: ShardedSystem, kind: str, maxits: int,
             def dots_fn(U, v):
                 # the fused (2l+1)-dot block — the body's ONE psum
                 d = jnp.moveaxis(jnp.sum(U * v[None], axis=-1), 0, -1)
-                return jax.lax.psum(d, PARTS_AXIS)
+                return allreduce(d, PARTS_AXIS)
 
             cert_mv = None
             if cert_halo_fn is not None:
                 def cert_mv(v):
                     # uncompressed exchange for the entry residual and
                     # the exit certificate (see _shard_solver docstring)
-                    with jax.named_scope("cert_halo"):
-                        gh = cert_halo_fn(v, sidx, ridx, ptnr, pidx,
-                                          gsp, gpp)
-                    with jax.named_scope("local_spmv"):
+                    gh = cert_halo_fn(v, sidx, ridx, ptnr, pidx, gsp, gpp)
+                    with jax.named_scope("acg.spmv.local"):
                         y = jax.lax.optimization_barrier(
                             local_mv(v, lops))
-                    return y + ell_matvec(iv, ic, gh)
+                    with jax.named_scope("acg.spmv.iface"):
+                        return y + ell_matvec(iv, ic, gh)
 
             k_start, rr0_in, flags_in, hist_in = restart_in[:4]
             ksys_in = restart_in[4] if batched else None
@@ -688,13 +706,15 @@ def build_sharded(A, nparts: int | None = None, part=None, mesh=None,
             if nparts is None:
                 raise AcgError(Status.ERR_INVALID_VALUE,
                                "need nparts or a part vector")
-            part = cached_partition_graph(A, nparts,
-                                          method=partition_method,
-                                          seed=seed, cache=cache,
-                                          ghash=ghash)
-        ps = cached_partition_system(A, np.asarray(part),
-                                     local_order="band", cache=cache,
-                                     ghash=ghash)
+            with span("acg.prep.partition"):
+                part = cached_partition_graph(A, nparts,
+                                              method=partition_method,
+                                              seed=seed, cache=cache,
+                                              ghash=ghash)
+        with span("acg.prep.assemble"):
+            ps = cached_partition_system(A, np.asarray(part),
+                                         local_order="band", cache=cache,
+                                         ghash=ghash)
     # one shared resolver (acg_tpu/parallel/sharded.py) decides
     # DIA vs sgell vs ELL, here WITH the per-part RCM recovery pass; the
     # resolved offsets / packs ride along so ShardedSystem.build never
@@ -709,19 +729,20 @@ def build_sharded(A, nparts: int | None = None, part=None, mesh=None,
     from acg_tpu.partition.cache import PREP_STAGE_SECONDS
 
     t0 = _time.perf_counter()
-    ps, fmt, extra = resolve_local_fmt(ps, fmt, try_rcm=True,
-                                       vec_dtype=solve_dtype,
-                                       sgell_interpret=sgell_interpret,
-                                       stencil_interpret=stencil_interpret,
-                                       tier_report=tier_report,
-                                       mat_dtype=mat_dtype)
-    ss = ShardedSystem.build(ps, mesh=mesh, dtype=dtype, method=method,
-                             mat_dtype=mat_dtype, fmt=fmt,
-                             loffsets=extra if fmt == "dia" else None,
-                             spacks=extra if fmt == "sgell" else None,
-                             sgell_interpret=sgell_interpret,
-                             stspec=extra if fmt == "stencil" else None,
-                             stencil_interpret=stencil_interpret)
+    with span("acg.prep.assemble"):
+        ps, fmt, extra = resolve_local_fmt(
+            ps, fmt, try_rcm=True, vec_dtype=solve_dtype,
+            sgell_interpret=sgell_interpret,
+            stencil_interpret=stencil_interpret, tier_report=tier_report,
+            mat_dtype=mat_dtype)
+    with span("acg.prep.upload"):
+        ss = ShardedSystem.build(ps, mesh=mesh, dtype=dtype, method=method,
+                                 mat_dtype=mat_dtype, fmt=fmt,
+                                 loffsets=extra if fmt == "dia" else None,
+                                 spacks=extra if fmt == "sgell" else None,
+                                 sgell_interpret=sgell_interpret,
+                                 stspec=extra if fmt == "stencil" else None,
+                                 stencil_interpret=stencil_interpret)
     # prep-stage telemetry (no-op until enable_metrics()): the fmt
     # resolution + stack/upload wall — "shard" beside the cache layer's
     # "partition"/"system" stages (partition/cache.py)
@@ -740,209 +761,213 @@ def _solve_dist(kind: str, A, b, x0, options: SolverOptions,
                 stats: SolveStats | None, fault=None,
                 atol2_floor=None, recycle=None, **build_kw) -> SolveResult:
     o = options
-    b = np.asarray(b)
-    nrhs = b.shape[0] if b.ndim == 2 else 1
-    batched = b.ndim == 2
-    from acg_tpu.sparse.csr import CsrMatrix
-    A_csr = A if isinstance(A, CsrMatrix) else None
-    ss = build_sharded(A, **build_kw)
-    if batched and ss.method == HaloMethod.RDMA:
-        raise AcgError(Status.ERR_NOT_SUPPORTED,
-                       "multi-RHS solves support the ppermute/allgather "
-                       "halo tiers (the Pallas remote-DMA halo moves 1-D "
-                       "packs)")
-    if o.halo_wire != "f32" and ss.method == HaloMethod.RDMA:
-        raise AcgError(Status.ERR_NOT_SUPPORTED,
-                       "halo_wire compression applies to the ppermute/"
-                       "allgather halo tiers (the Pallas remote-DMA "
-                       "halo writes raw vector words)")
-    sstep = 0
-    depth = 0
-    deep = None
-    if kind == "cg-sstep":
-        sstep = _sstep_validate(o, fault)
-        if ss.method == HaloMethod.RDMA:
+    with span("acg.call.prepare"):
+        b = np.asarray(b)
+        nrhs = b.shape[0] if b.ndim == 2 else 1
+        batched = b.ndim == 2
+        from acg_tpu.sparse.csr import CsrMatrix
+        A_csr = A if isinstance(A, CsrMatrix) else None
+        ss = build_sharded(A, **build_kw)
+        if batched and ss.method == HaloMethod.RDMA:
             raise AcgError(Status.ERR_NOT_SUPPORTED,
-                           "s-step solves support the ppermute/allgather "
-                           "halo tiers (the Pallas remote-DMA halo moves "
-                           "1-D distance-1 packs, not the stacked deep "
-                           "ghost exchange)")
-        from acg_tpu.parallel.deep import build_deep_device
-
-        # the deep ghost zones (one halo exchange per s-iteration block;
-        # acg_tpu/parallel/deep.py), cached on the system per depth
-        deep = build_deep_device(ss, sstep, A=A_csr)
-    elif kind == "cg-pipelined-deep":
-        from acg_tpu.solvers.cg import _deep_validate
-
-        depth = _deep_validate(o, fault)
-        if ss.method == HaloMethod.RDMA:
+                           "multi-RHS solves support the ppermute/allgather "
+                           "halo tiers (the Pallas remote-DMA halo moves 1-D "
+                           "packs)")
+        if o.halo_wire != "f32" and ss.method == HaloMethod.RDMA:
             raise AcgError(Status.ERR_NOT_SUPPORTED,
-                           "deep-pipelined solves support the ppermute/"
+                           "halo_wire compression applies to the ppermute/"
                            "allgather halo tiers (the Pallas remote-DMA "
-                           "halo moves 1-D distance-1 packs, not the "
-                           "depth-l ghost exchange)")
-        from acg_tpu.parallel.deep import build_deep_device
+                           "halo writes raw vector words)")
+        sstep = 0
+        depth = 0
+        deep = None
+        if kind == "cg-sstep":
+            sstep = _sstep_validate(o, fault)
+            if ss.method == HaloMethod.RDMA:
+                raise AcgError(Status.ERR_NOT_SUPPORTED,
+                               "s-step solves support the ppermute/allgather "
+                               "halo tiers (the Pallas remote-DMA halo moves "
+                               "1-D distance-1 packs, not the stacked deep "
+                               "ghost exchange)")
+            from acg_tpu.parallel.deep import build_deep_device
 
-        # the depth-l ghost zones feed the fill chain's matrix powers
-        deep = build_deep_device(ss, depth, A=A_csr)
-    vdt = np.dtype(ss.vec_dtype)
-    if x0 is not None:
-        # the shared multi-RHS x0 shape contract (base.conform_x0_batch):
-        # broadcast a 1-D x0 across the batch, reject any other mismatch
-        from acg_tpu.solvers.base import conform_x0_batch
+            # the deep ghost zones (one halo exchange per s-iteration block;
+            # acg_tpu/parallel/deep.py), cached on the system per depth
+            deep = build_deep_device(ss, sstep, A=A_csr)
+        elif kind == "cg-pipelined-deep":
+            from acg_tpu.solvers.cg import _deep_validate
 
-        x0 = conform_x0_batch(np.asarray(x0), b.shape,
-                              lambda v: np.tile(v[None, :], (nrhs, 1)))
-    b_sh = ss.to_sharded(b)
-    x0_sh = ss.to_sharded(x0) if x0 is not None \
-        else ss.zeros_sharded(nrhs if batched else None)
-    # atol2_floor: scalar or per-system (B,) squared-absolute threshold
-    # floor — the s-step fallback restoring each system's original
-    # criterion (cg.py _sstep_fallback_stop); replicated, so the spec_r
-    # stop2 operand carries it unchanged
-    stop2 = (jnp.asarray(o.residual_atol ** 2 if atol2_floor is None
-                         else np.maximum(o.residual_atol ** 2,
-                                         atol2_floor), vdt),
-             jnp.asarray(o.residual_rtol ** 2, vdt))
-    track_diff = o.diffatol > 0 or o.diffrtol > 0
-    if kind != "cg" and track_diff:
-        raise AcgError(Status.ERR_NOT_SUPPORTED,
-                       "pipelined CG supports residual-based stopping only")
-    diffstop = jnp.asarray(o.diffatol ** 2, vdt)
-    if o.diffrtol > 0:
-        if batched:
-            x0n = (jnp.linalg.norm(jnp.asarray(x0, dtype=vdt), axis=-1)
-                   if x0 is not None else jnp.zeros((nrhs,), vdt))
-            diffstop = jnp.maximum(diffstop,
-                                   ((o.diffrtol * x0n) ** 2).astype(vdt))
-        else:
-            x0n = float(jnp.linalg.norm(np.asarray(x0, dtype=vdt))) \
-                if x0 is not None else 0.0
-            diffstop = jnp.maximum(diffstop,
-                                   jnp.asarray((o.diffrtol * x0n) ** 2,
-                                               vdt))
-    # the resilience hooks, resolved exactly as the single-chip solver
-    # does (acg_tpu/solvers/cg.py): guard from the options, the fault
-    # plan converted to device arrays at the solve dtype
-    from acg_tpu.solvers.cg import _fault_plan
-    fplan = _fault_plan(fault, vdt)
-    guard = o.guard_nonfinite
-    # static certify: fixed-iteration pipelined solves drop the exit
-    # certifier branch (see loops.cg_pipelined_while; PERF.md round 5)
-    common = dict(certify=o.residual_atol > 0 or o.residual_rtol > 0,
-                  monitor_every=o.monitor_every, nrhs=nrhs,
-                  guard=guard, has_fault=fplan is not None,
-                  wire=o.halo_wire)
-    args = (ss.local_op_arrays(), ss.ivals, ss.icols, ss.send_idx,
-            ss.recv_idx, ss.partner, ss.pack_idx, ss.ghost_src_part,
-            ss.ghost_src_pos, b_sh, x0_sh, stop2, diffstop)
-    ftail = () if fplan is None else (fplan,)
-    dtail = () if deep is None else deep.arrays()
-    fb_why = None
-    t0 = time.perf_counter()
-    if kind == "cg-pipelined-deep":
-        # host re-dispatch driver (the loop's dispatch protocol): each
-        # dispatch runs ONE pipeline segment of the SAME executable —
-        # re-entry replaces the residual from its definition — until
-        # the device-computed state says done, a guard fault surfaces,
-        # or _DEEP_MAX_BAD consecutive breakdown/drift dispatches send
-        # the solve to the classic-CG fallback below
-        from acg_tpu.solvers.cg import (_BREAKDOWN, _DEEP_MAX_BAD,
-                                        _FAULT, _OK)
+            depth = _deep_validate(o, fault)
+            if ss.method == HaloMethod.RDMA:
+                raise AcgError(Status.ERR_NOT_SUPPORTED,
+                               "deep-pipelined solves support the ppermute/"
+                               "allgather halo tiers (the Pallas remote-DMA "
+                               "halo moves 1-D distance-1 packs, not the "
+                               "depth-l ghost exchange)")
+            from acg_tpu.parallel.deep import build_deep_device
 
-        fn = _shard_solver(ss, kind, o.maxits, track_diff,
-                           o.check_every, o.replace_every, deep=deep,
-                           depth=depth, **common)
-        sshape = (nrhs,) if batched else ()
-        x_sh = x0_sh
-        k_op = jnp.zeros((), jnp.int32)
-        rr0_op = jnp.zeros(sshape, vdt)
-        flags_op = jnp.zeros(sshape, jnp.int32)
-        hist_op = jnp.zeros(sshape + (o.maxits + 1,), vdt)
-        ktail = (jnp.zeros(sshape, jnp.int32),) if batched else ()
-        fails = ndisp = 0
-        while True:
-            ndisp += 1
-            (x_sh, kret, rr, dxx, flag, rr0_op, hist_op, k_op, more,
-             drift) = fn(*args[:10], x_sh, *args[11:], *dtail,
-                         k_op, rr0_op, flags_op, hist_op, *ktail)
+            # the depth-l ghost zones feed the fill chain's matrix powers
+            deep = build_deep_device(ss, depth, A=A_csr)
+        vdt = np.dtype(ss.vec_dtype)
+        if x0 is not None:
+            # the shared multi-RHS x0 shape contract (base.conform_x0_batch):
+            # broadcast a 1-D x0 across the batch, reject any other mismatch
+            from acg_tpu.solvers.base import conform_x0_batch
+
+            x0 = conform_x0_batch(np.asarray(x0), b.shape,
+                                  lambda v: np.tile(v[None, :], (nrhs, 1)))
+        b_sh = ss.to_sharded(b)
+        x0_sh = ss.to_sharded(x0) if x0 is not None \
+            else ss.zeros_sharded(nrhs if batched else None)
+        # atol2_floor: scalar or per-system (B,) squared-absolute threshold
+        # floor — the s-step fallback restoring each system's original
+        # criterion (cg.py _sstep_fallback_stop); replicated, so the spec_r
+        # stop2 operand carries it unchanged
+        stop2 = (jnp.asarray(o.residual_atol ** 2 if atol2_floor is None
+                             else np.maximum(o.residual_atol ** 2,
+                                             atol2_floor), vdt),
+                 jnp.asarray(o.residual_rtol ** 2, vdt))
+        track_diff = o.diffatol > 0 or o.diffrtol > 0
+        if kind != "cg" and track_diff:
+            raise AcgError(Status.ERR_NOT_SUPPORTED,
+                           "pipelined CG supports residual-based stopping "
+                           "only")
+        diffstop = jnp.asarray(o.diffatol ** 2, vdt)
+        if o.diffrtol > 0:
             if batched:
-                ktail = (kret,)
-            flags_h = np.atleast_1d(np.asarray(jax.device_get(flag)))
-            drift_h = np.atleast_1d(np.asarray(jax.device_get(drift)))
-            k_h = int(jax.device_get(k_op))
-            if np.any(flags_h == _FAULT):
-                break    # the guard fired: no restart, surface it
-            bad = bool(np.any(flags_h == _BREAKDOWN)
-                       or np.any(drift_h))
-            fails = fails + 1 if bad else 0
-            if fails >= _DEEP_MAX_BAD:
-                fb_why = ("indefinite Gram/LDL pivot"
-                          if np.any(flags_h == _BREAKDOWN)
-                          else "certified-exit drift")
-                break
-            # breakdown systems restart with a replaced residual; drift
-            # systems are still _OK and simply keep iterating
-            flags_op = jnp.where(flag == _BREAKDOWN, _OK,
-                                 flag).astype(jnp.int32)
-            live = np.any((flags_h == _OK) | (flags_h == _BREAKDOWN))
-            if not (live and k_h < o.maxits):
-                break
-        x, k, rr0, hist = x_sh, kret, rr0_op, hist_op
-    elif o.segment_iters > 0 and kind != "cg-sstep":
-        # host loop over device segments, the distributed twin of the
-        # single-chip _run_segmented driver: each dispatch runs the SAME
-        # shard_map'd loop body for segment_iters iterations and hands
-        # the exact loop carry to the next one — numerically identical
-        # to the single-program solve (pinned by test_cg_dist).  The
-        # pipelined carry (PR 7) ends with a device-computed continue
-        # bit; the classic carry keeps its k/flag predicate.
-        first = _shard_solver(ss, kind, o.maxits, track_diff,
-                              o.check_every, o.replace_every,
-                              segment=o.segment_iters, **common)
-        cont = _shard_solver(ss, kind, o.maxits, track_diff,
-                             o.check_every, o.replace_every,
-                             segment=o.segment_iters, resume=True,
-                             **common)
-        x, k, rr, dxx, flag, rr0, hist = _run_segmented(
-            lambda: _split7(first(*args, *ftail)),
-            lambda c: _split7(cont(*args, *c, *ftail)),
-            o.maxits,
-            continue_fn=(_pipelined_continue if kind == "cg-pipelined"
-                         else None))
-    else:
-        # spectral recycling (ISSUE 20): a RecycleState holding a
-        # refined schedule for this block size selects the ext_shifts
-        # program variant — the recycled schedule rides in as a
-        # replicated operand and the power/Chebyshev seeding prelude is
-        # gone from the traced program.  Either variant OUTPUTS its
-        # final Ritz-refined schedule, harvested below (a cold solve
-        # seeds the recycle state for the next one).
-        ext0 = None
-        if kind == "cg-sstep" and recycle is not None:
-            ext0 = recycle.get_shifts(sstep)
-        stail = ()
-        if ext0 is not None:
-            ext0 = np.asarray(ext0, vdt)
-            if batched and ext0.ndim == 1:
-                # the loop carries PER-SYSTEM shifts: tile the shared
-                # (s,) schedule to (B, s), exactly as cg_sstep does
-                ext0 = np.tile(ext0[None, :], (nrhs, 1))
-            stail = (jnp.asarray(ext0),)
-        fn = _shard_solver(ss, kind, o.maxits, track_diff, o.check_every,
-                           o.replace_every, sstep=sstep, deep=deep,
-                           ext_shifts=ext0 is not None, **common)
-        out = fn(*args, *dtail, *stail, *ftail)
-        x, k, rr, dxx, flag, rr0, hist = out[:7]
-        if kind == "cg-sstep" and recycle is not None:
-            sh_new = out[-1]
-            flags_h = np.atleast_1d(np.asarray(jax.device_get(flag)))
-            if np.any(flags_h == _CONVERGED):
-                recycle.put_shifts(
-                    sstep, np.asarray(jax.device_get(sh_new)))
-    jax.block_until_ready(x)
-    k = jax.device_get(k)         # scalar, or per-system (B,) when batched
+                x0n = (jnp.linalg.norm(jnp.asarray(x0, dtype=vdt), axis=-1)
+                       if x0 is not None else jnp.zeros((nrhs,), vdt))
+                diffstop = jnp.maximum(diffstop,
+                                       ((o.diffrtol * x0n) ** 2).astype(vdt))
+            else:
+                x0n = float(jnp.linalg.norm(np.asarray(x0, dtype=vdt))) \
+                    if x0 is not None else 0.0
+                diffstop = jnp.maximum(diffstop,
+                                       jnp.asarray((o.diffrtol * x0n) ** 2,
+                                                   vdt))
+        # the resilience hooks, resolved exactly as the single-chip solver
+        # does (acg_tpu/solvers/cg.py): guard from the options, the fault
+        # plan converted to device arrays at the solve dtype
+        from acg_tpu.solvers.cg import _fault_plan
+        fplan = _fault_plan(fault, vdt)
+        guard = o.guard_nonfinite
+        # static certify: fixed-iteration pipelined solves drop the exit
+        # certifier branch (see loops.cg_pipelined_while; PERF.md round 5)
+        common = dict(certify=o.residual_atol > 0 or o.residual_rtol > 0,
+                      monitor_every=o.monitor_every, nrhs=nrhs,
+                      guard=guard, has_fault=fplan is not None,
+                      wire=o.halo_wire)
+        args = (ss.local_op_arrays(), ss.ivals, ss.icols, ss.send_idx,
+                ss.recv_idx, ss.partner, ss.pack_idx, ss.ghost_src_part,
+                ss.ghost_src_pos, b_sh, x0_sh, stop2, diffstop)
+        ftail = () if fplan is None else (fplan,)
+        dtail = () if deep is None else deep.arrays()
+        bnrm2 = (np.linalg.norm(b, axis=-1) if batched
+                 else float(np.linalg.norm(b)))
+    with span("acg.call.dispatch"):
+        fb_why = None
+        t0 = time.perf_counter()
+        if kind == "cg-pipelined-deep":
+            # host re-dispatch driver (the loop's dispatch protocol): each
+            # dispatch runs ONE pipeline segment of the SAME executable —
+            # re-entry replaces the residual from its definition — until
+            # the device-computed state says done, a guard fault surfaces,
+            # or _DEEP_MAX_BAD consecutive breakdown/drift dispatches send
+            # the solve to the classic-CG fallback below
+            from acg_tpu.solvers.cg import (_BREAKDOWN, _DEEP_MAX_BAD,
+                                            _FAULT, _OK)
+
+            fn = _shard_solver(ss, kind, o.maxits, track_diff,
+                               o.check_every, o.replace_every, deep=deep,
+                               depth=depth, **common)
+            sshape = (nrhs,) if batched else ()
+            x_sh = x0_sh
+            k_op = jnp.zeros((), jnp.int32)
+            rr0_op = jnp.zeros(sshape, vdt)
+            flags_op = jnp.zeros(sshape, jnp.int32)
+            hist_op = jnp.zeros(sshape + (o.maxits + 1,), vdt)
+            ktail = (jnp.zeros(sshape, jnp.int32),) if batched else ()
+            fails = ndisp = 0
+            while True:
+                ndisp += 1
+                (x_sh, kret, rr, dxx, flag, rr0_op, hist_op, k_op, more,
+                 drift) = fn(*args[:10], x_sh, *args[11:], *dtail,
+                             k_op, rr0_op, flags_op, hist_op, *ktail)
+                if batched:
+                    ktail = (kret,)
+                flags_h = np.atleast_1d(np.asarray(jax.device_get(flag)))
+                drift_h = np.atleast_1d(np.asarray(jax.device_get(drift)))
+                k_h = int(jax.device_get(k_op))
+                if np.any(flags_h == _FAULT):
+                    break    # the guard fired: no restart, surface it
+                bad = bool(np.any(flags_h == _BREAKDOWN)
+                           or np.any(drift_h))
+                fails = fails + 1 if bad else 0
+                if fails >= _DEEP_MAX_BAD:
+                    fb_why = ("indefinite Gram/LDL pivot"
+                              if np.any(flags_h == _BREAKDOWN)
+                              else "certified-exit drift")
+                    break
+                # breakdown systems restart with a replaced residual; drift
+                # systems are still _OK and simply keep iterating
+                flags_op = jnp.where(flag == _BREAKDOWN, _OK,
+                                     flag).astype(jnp.int32)
+                live = np.any((flags_h == _OK) | (flags_h == _BREAKDOWN))
+                if not (live and k_h < o.maxits):
+                    break
+            x, k, rr0, hist = x_sh, kret, rr0_op, hist_op
+        elif o.segment_iters > 0 and kind != "cg-sstep":
+            # host loop over device segments, the distributed twin of the
+            # single-chip _run_segmented driver: each dispatch runs the SAME
+            # shard_map'd loop body for segment_iters iterations and hands
+            # the exact loop carry to the next one — numerically identical
+            # to the single-program solve (pinned by test_cg_dist).  The
+            # pipelined carry (PR 7) ends with a device-computed continue
+            # bit; the classic carry keeps its k/flag predicate.
+            first = _shard_solver(ss, kind, o.maxits, track_diff,
+                                  o.check_every, o.replace_every,
+                                  segment=o.segment_iters, **common)
+            cont = _shard_solver(ss, kind, o.maxits, track_diff,
+                                 o.check_every, o.replace_every,
+                                 segment=o.segment_iters, resume=True,
+                                 **common)
+            x, k, rr, dxx, flag, rr0, hist = _run_segmented(
+                lambda: _split7(first(*args, *ftail)),
+                lambda c: _split7(cont(*args, *c, *ftail)),
+                o.maxits,
+                continue_fn=(_pipelined_continue if kind == "cg-pipelined"
+                             else None))
+        else:
+            # spectral recycling (ISSUE 20): a RecycleState holding a
+            # refined schedule for this block size selects the ext_shifts
+            # program variant — the recycled schedule rides in as a
+            # replicated operand and the power/Chebyshev seeding prelude is
+            # gone from the traced program.  Either variant OUTPUTS its
+            # final Ritz-refined schedule, harvested below (a cold solve
+            # seeds the recycle state for the next one).
+            ext0 = None
+            if kind == "cg-sstep" and recycle is not None:
+                ext0 = recycle.get_shifts(sstep)
+            stail = ()
+            if ext0 is not None:
+                ext0 = np.asarray(ext0, vdt)
+                if batched and ext0.ndim == 1:
+                    # the loop carries PER-SYSTEM shifts: tile the shared
+                    # (s,) schedule to (B, s), exactly as cg_sstep does
+                    ext0 = np.tile(ext0[None, :], (nrhs, 1))
+                stail = (jnp.asarray(ext0),)
+            fn = _shard_solver(ss, kind, o.maxits, track_diff, o.check_every,
+                               o.replace_every, sstep=sstep, deep=deep,
+                               ext_shifts=ext0 is not None, **common)
+            out = fn(*args, *dtail, *stail, *ftail)
+            x, k, rr, dxx, flag, rr0, hist = out[:7]
+            if kind == "cg-sstep" and recycle is not None:
+                sh_new = out[-1]
+                flags_h = np.atleast_1d(np.asarray(jax.device_get(flag)))
+                if np.any(flags_h == _CONVERGED):
+                    recycle.put_shifts(
+                        sstep, np.asarray(jax.device_get(sh_new)))
+    k = _device_wait(x, k)        # scalar, or per-system (B,) when batched
     tsolve = time.perf_counter() - t0
     if kind == "cg-sstep":
         flags = np.atleast_1d(np.asarray(jax.device_get(flag)))
@@ -988,7 +1013,6 @@ def _solve_dist(kind: str, A, b, x0, options: SolverOptions,
         nrows = ss.nrows
         nnz = ss.nnz
 
-    x_global = ss.from_sharded(x)
     # which local-operator format + kernel tier ran (the iface operator
     # is always the tiny ELL gather; see ShardedSystem.build docstring);
     # naming shared with the single-chip solver via path_names — including
@@ -1024,14 +1048,12 @@ def _solve_dist(kind: str, A, b, x0, options: SolverOptions,
     if kind == "cg-pipelined-deep":
         path = path + (f"deep pipeline depth {depth}, {ndisp} "
                        f"dispatch(es), wire={o.halo_wire}",)
-    bnrm2 = (np.linalg.norm(b, axis=-1) if batched
-             else float(np.linalg.norm(b)))
-    return _finish(_Meta, np.zeros(0), k, rr, flag, rr0, o, tsolve,
+    return _finish(_Meta, x, k, rr, flag, rr0, o, tsolve,
                    pipelined=(kind in ("cg-pipelined",
                                        "cg-pipelined-deep")),
                    bnrm2=bnrm2,
                    dxx=dxx if track_diff else None, stats=stats,
-                   x_host=x_global, path=path, hist=hist, sstep=sstep,
+                   to_host=ss.from_sharded, path=path, hist=hist, sstep=sstep,
                    solver=("cg-pipelined-deep"
                            if kind == "cg-pipelined-deep" else None))
 
@@ -1284,108 +1306,109 @@ def aot_step(A, b=None, x0=None,
         nrows = ss.nrows
         nnz = ss.nnz
 
+    @_traced_call(kind + "-dist")
     def solve(b, x0=None, stats=None, options=None) -> SolveResult:
         from acg_tpu.solvers.cg import check_aot_options
 
         # per-dispatch options: tolerance VALUES re-bind as runtime
         # operands of the SAME executable; static fields must match
-        oo = o if options is None else check_aot_options(o, options)
-        b = np.asarray(b)
-        if b.shape != shape:
-            raise AcgError(Status.ERR_INVALID_VALUE,
-                           f"AOT signature mismatch: executable was "
-                           f"compiled for shape {shape}, got {b.shape}")
-        if x0 is not None:
-            from acg_tpu.solvers.base import conform_x0_batch
+        with span("acg.call.prepare"):
+            oo = o if options is None else check_aot_options(o, options)
+            b = np.asarray(b)
+            if b.shape != shape:
+                raise AcgError(Status.ERR_INVALID_VALUE,
+                               f"AOT signature mismatch: executable was "
+                               f"compiled for shape {shape}, got {b.shape}")
+            if x0 is not None:
+                from acg_tpu.solvers.base import conform_x0_batch
 
-            x0 = conform_x0_batch(np.asarray(x0), b.shape,
-                                  lambda v: np.tile(v[None, :],
-                                                    (nrhs, 1)))
-        b_sh = ss.to_sharded(b)
-        x0_sh = (ss.to_sharded(x0) if x0 is not None
-                 else ss.zeros_sharded(nrhs if batched else None))
-        stop2 = (jnp.asarray(oo.residual_atol ** 2, vdt),
-                 jnp.asarray(oo.residual_rtol ** 2, vdt))
-        diffstop = jnp.asarray(oo.diffatol ** 2, vdt)
-        if oo.diffrtol > 0:
-            if batched:
-                x0n = (jnp.linalg.norm(jnp.asarray(x0, dtype=vdt),
-                                       axis=-1)
-                       if x0 is not None else jnp.zeros((nrhs,), vdt))
-                diffstop = jnp.maximum(
-                    diffstop, ((oo.diffrtol * x0n) ** 2).astype(vdt))
-            else:
-                x0n = (float(jnp.linalg.norm(np.asarray(x0, dtype=vdt)))
-                       if x0 is not None else 0.0)
-                diffstop = jnp.maximum(
-                    diffstop, jnp.asarray((oo.diffrtol * x0n) ** 2,
-                                          vdt))
-        bnrm2 = (np.linalg.norm(b, axis=-1) if batched
-                 else float(np.linalg.norm(b)))
-        t0 = time.perf_counter()
-        ndisp = 1
-        if deep_kind:
-            # the host re-dispatch driver of _solve_dist, against the
-            # fixed executable: no classic-CG fallback here (AOT never
-            # re-traces) — persistent breakdown/drift surfaces as the
-            # returned flag instead
-            from acg_tpu.solvers.cg import (_BREAKDOWN, _DEEP_MAX_BAD,
-                                            _FAULT, _OK)
-
-            sshape = (nrhs,) if batched else ()
-            x_sh = x0_sh
-            k_op = jnp.zeros((), jnp.int32)
-            rr0 = jnp.zeros(sshape, vdt)
-            flags_op = jnp.zeros(sshape, jnp.int32)
-            hist = jnp.zeros(sshape + (oo.maxits + 1,), vdt)
-            ktail = ((jnp.zeros(sshape, jnp.int32),)
-                     if batched else ())
-            fails = ndisp = 0
-            while True:
-                ndisp += 1
-                (x_sh, k, rr, dxx, flag, rr0, hist, k_op, more,
-                 drift) = compiled(*static_args, b_sh, x_sh, stop2,
-                                   diffstop, *darrs, k_op, rr0,
-                                   flags_op, hist, *ktail)
+                x0 = conform_x0_batch(np.asarray(x0), b.shape,
+                                      lambda v: np.tile(v[None, :],
+                                                        (nrhs, 1)))
+            b_sh = ss.to_sharded(b)
+            x0_sh = (ss.to_sharded(x0) if x0 is not None
+                     else ss.zeros_sharded(nrhs if batched else None))
+            stop2 = (jnp.asarray(oo.residual_atol ** 2, vdt),
+                     jnp.asarray(oo.residual_rtol ** 2, vdt))
+            diffstop = jnp.asarray(oo.diffatol ** 2, vdt)
+            if oo.diffrtol > 0:
                 if batched:
-                    ktail = (k,)
-                flags_h = np.atleast_1d(
-                    np.asarray(jax.device_get(flag)))
-                drift_h = np.atleast_1d(
-                    np.asarray(jax.device_get(drift)))
-                k_h = int(jax.device_get(k_op))
-                if np.any(flags_h == _FAULT):
-                    break
-                bad = bool(np.any(flags_h == _BREAKDOWN)
-                           or np.any(drift_h))
-                fails = fails + 1 if bad else 0
-                if fails >= _DEEP_MAX_BAD:
-                    break
-                flags_op = jnp.where(flag == _BREAKDOWN, _OK,
-                                     flag).astype(jnp.int32)
-                live = np.any((flags_h == _OK)
-                              | (flags_h == _BREAKDOWN))
-                if not (live and k_h < oo.maxits):
-                    break
-            x = x_sh
-        else:
-            x, k, rr, dxx, flag, rr0, hist = compiled(
-                *static_args, b_sh, x0_sh, stop2, diffstop)
-        jax.block_until_ready(x)
-        k = jax.device_get(k)           # real sync (see cg())
+                    x0n = (jnp.linalg.norm(jnp.asarray(x0, dtype=vdt),
+                                           axis=-1)
+                           if x0 is not None else jnp.zeros((nrhs,), vdt))
+                    diffstop = jnp.maximum(
+                        diffstop, ((oo.diffrtol * x0n) ** 2).astype(vdt))
+                else:
+                    x0n = (float(jnp.linalg.norm(np.asarray(x0, dtype=vdt)))
+                           if x0 is not None else 0.0)
+                    diffstop = jnp.maximum(
+                        diffstop, jnp.asarray((oo.diffrtol * x0n) ** 2,
+                                              vdt))
+            bnrm2 = (np.linalg.norm(b, axis=-1) if batched
+                     else float(np.linalg.norm(b)))
+        with span("acg.call.dispatch"):
+            t0 = time.perf_counter()
+            ndisp = 1
+            if deep_kind:
+                # the host re-dispatch driver of _solve_dist, against the
+                # fixed executable: no classic-CG fallback here (AOT never
+                # re-traces) — persistent breakdown/drift surfaces as the
+                # returned flag instead
+                from acg_tpu.solvers.cg import (_BREAKDOWN, _DEEP_MAX_BAD,
+                                                _FAULT, _OK)
+
+                sshape = (nrhs,) if batched else ()
+                x_sh = x0_sh
+                k_op = jnp.zeros((), jnp.int32)
+                rr0 = jnp.zeros(sshape, vdt)
+                flags_op = jnp.zeros(sshape, jnp.int32)
+                hist = jnp.zeros(sshape + (oo.maxits + 1,), vdt)
+                ktail = ((jnp.zeros(sshape, jnp.int32),)
+                         if batched else ())
+                fails = ndisp = 0
+                while True:
+                    ndisp += 1
+                    (x_sh, k, rr, dxx, flag, rr0, hist, k_op, more,
+                     drift) = compiled(*static_args, b_sh, x_sh, stop2,
+                                       diffstop, *darrs, k_op, rr0,
+                                       flags_op, hist, *ktail)
+                    if batched:
+                        ktail = (k,)
+                    flags_h = np.atleast_1d(
+                        np.asarray(jax.device_get(flag)))
+                    drift_h = np.atleast_1d(
+                        np.asarray(jax.device_get(drift)))
+                    k_h = int(jax.device_get(k_op))
+                    if np.any(flags_h == _FAULT):
+                        break
+                    bad = bool(np.any(flags_h == _BREAKDOWN)
+                               or np.any(drift_h))
+                    fails = fails + 1 if bad else 0
+                    if fails >= _DEEP_MAX_BAD:
+                        break
+                    flags_op = jnp.where(flag == _BREAKDOWN, _OK,
+                                         flag).astype(jnp.int32)
+                    live = np.any((flags_h == _OK)
+                                  | (flags_h == _BREAKDOWN))
+                    if not (live and k_h < oo.maxits):
+                        break
+                x = x_sh
+            else:
+                x, k, rr, dxx, flag, rr0, hist = compiled(
+                    *static_args, b_sh, x0_sh, stop2, diffstop)
+        k = _device_wait(x, k)          # real sync (see cg())
         tsolve = time.perf_counter() - t0
-        x_global = ss.from_sharded(x)
         path2 = path
         if deep_kind:
             path2 = path + (f"deep pipeline depth {o.pipeline_depth}, "
                             f"{ndisp} dispatch(es), "
                             f"wire={o.halo_wire}",)
-        return _finish(_Meta, np.zeros(0), k, rr, flag, rr0, oo, tsolve,
+        return _finish(_Meta, x, k, rr, flag, rr0, oo, tsolve,
                        pipelined=(kind in ("cg-pipelined",
                                            "cg-pipelined-deep")),
                        bnrm2=bnrm2,
                        dxx=dxx if track_diff else None, stats=stats,
-                       x_host=x_global, path=path2, hist=hist,
+                       to_host=ss.from_sharded, path=path2, hist=hist,
                        solver=("cg-pipelined-deep" if deep_kind
                                else None))
 
@@ -1393,6 +1416,7 @@ def aot_step(A, b=None, x0=None,
                     vec_dtype=vdt, path=path)
 
 
+@_traced_call("cg-dist")
 def cg_dist(A, b, x0=None, options: SolverOptions = SolverOptions(),
             stats: SolveStats | None = None, fault=None,
             **build_kw) -> SolveResult:
@@ -1403,6 +1427,7 @@ def cg_dist(A, b, x0=None, options: SolverOptions = SolverOptions(),
                        **build_kw)
 
 
+@_traced_call("cg-pipelined-dist")
 def cg_pipelined_dist(A, b, x0=None,
                       options: SolverOptions = SolverOptions(),
                       stats: SolveStats | None = None, fault=None,
@@ -1412,6 +1437,7 @@ def cg_pipelined_dist(A, b, x0=None,
                        fault=fault, **build_kw)
 
 
+@_traced_call("cg-sstep-dist")
 def cg_sstep_dist(A, b, x0=None,
                   options: SolverOptions = SolverOptions(),
                   stats: SolveStats | None = None, fault=None,
@@ -1435,6 +1461,7 @@ def cg_sstep_dist(A, b, x0=None,
                        fault=fault, recycle=recycle, **build_kw)
 
 
+@_traced_call("cg-recycled-dist")
 def cg_recycled_dist(A, b, x0=None,
                      options: SolverOptions = SolverOptions(),
                      stats: SolveStats | None = None, fault=None,
@@ -1455,6 +1482,7 @@ def cg_recycled_dist(A, b, x0=None,
                        **build_kw)
 
 
+@_traced_call("cg-pipelined-deep-dist")
 def cg_pipelined_deep_dist(A, b, x0=None,
                            options: SolverOptions = SolverOptions(),
                            stats: SolveStats | None = None, fault=None,

@@ -27,6 +27,14 @@ its per-system iteration count is pinned — while the while_loop runs
 until every system is finished or maxits.  The 1-D path compiles to the
 exact same program as before (batching is gated on static ``b.ndim``),
 so B=1 via a 1-D vector is bit-for-bit today's solver.
+
+LAYER SCOPES: every op of a loop carries one ``acg.*`` name scope
+(acg_tpu/obs/trace.py): the operator applications ``acg.spmv``, the dots
+and vector updates ``acg.blas1``, the loop predicate, scalar recurrences
+and history writes ``acg.check``.  The scopes wrap the functions passed
+in, so a caller's finer scopes inside them (the distributed halo stages,
+the psum's ``acg.allreduce``) stay innermost.  Scopes are op metadata
+only: the compiled program is unchanged.
 """
 
 from __future__ import annotations
@@ -46,6 +54,17 @@ _OK, _CONVERGED, _BREAKDOWN, _FAULT = 0, 1, 2, 3
 # CG from the current iterate and says so in SolveResult.kernel_note
 # (never silently wrong — ISSUE 7 acceptance)
 _GRAM_BAD = 4
+
+
+def _scoped(name: str, fn):
+    """``fn`` with every call under ``jax.named_scope(name)``."""
+    if fn is None:
+        return None
+
+    def call(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+    return call
 
 
 def _history_init(rr0, maxits: int):
@@ -146,11 +165,15 @@ def cg_while(matvec, dot, b, x0, stop2, diffstop, maxits: int,
     # broadcast a (B,) per-system scalar against (B, n) system vectors;
     # identity in the 1-D path, so that trace is unchanged
     bc = (lambda s: s[:, None]) if batched else (lambda s: s)
+    matvec = _scoped("acg.spmv", matvec)
+    dot = _scoped("acg.blas1", dot)
     if coupled_step is None:
         def coupled_step(r, p, beta):
-            p = r + bc(beta) * p
+            with jax.named_scope("acg.blas1"):
+                p = r + bc(beta) * p
             t = matvec(p)
             return p, t, dot(p, t)
+    coupled_step = _scoped("acg.spmv", coupled_step)
 
     if carry_in is None:
         r = b - matvec(x0)
@@ -206,7 +229,8 @@ def cg_while(matvec, dot, b, x0, stop2, diffstop, maxits: int,
             # frozen systems keep their direction (beta keeps recurring
             # on a frozen rr, so an unmasked p would drift — harmless to
             # x/r under alpha = 0, but kept finite and fixed on principle)
-            p_new = jnp.where(bc(active), p_new, p)
+            with jax.named_scope("acg.blas1"):
+                p_new = jnp.where(bc(active), p_new, p)
         p = p_new
         # Indefiniteness witness: for SPD A, p'Ap > 0 whenever p != 0, and
         # p != 0 whenever r != 0 (p·r = rr > 0), so p'Ap < 0 — or == 0
@@ -220,11 +244,13 @@ def cg_while(matvec, dot, b, x0, stop2, diffstop, maxits: int,
         alpha = jnp.where(safe, rr / jnp.where(safe, ptap, 1.0), 0.0)
         if batched:
             alpha = jnp.where(active, alpha, 0.0)   # freeze x and r
-        x = x + bc(alpha) * p
+        with jax.named_scope("acg.blas1"):
+            x = x + bc(alpha) * p
         if track_diff:
             dxx_new = alpha * alpha * dot(p, p)
             dxx = jnp.where(active, dxx_new, dxx) if batched else dxx_new
-        r = r - bc(alpha) * t
+        with jax.named_scope("acg.blas1"):
+            r = r - bc(alpha) * t
         rr_new = inject_reduction(fault, k, dot(r, r))
         if batched:
             rr_new = jnp.where(active, rr_new, rr)
@@ -264,7 +290,8 @@ def cg_while(matvec, dot, b, x0, stop2, diffstop, maxits: int,
         return (x, r, p, rr_new, beta_next, dxx, k + 1, flag,
                 hist) + tuple(ksys)
 
-    out = jax.lax.while_loop(cond, body, init)
+    out = jax.lax.while_loop(_scoped("acg.check", cond),
+                             _scoped("acg.check", body), init)
     x, r, p, rr, beta, dxx, k, flag, hist = out[:9]
     # tolerance met at exit IS convergence, whatever the flag: rr is a true
     # dot(r,r), and with check_every>1 the loop may pass the unobserved
@@ -376,6 +403,9 @@ def cg_sstep_while(block_fn, b, x0, p0, rr0, shifts0, stop2, s: int,
     written at each system's OWN iteration cursor (systems pause and
     resume, so rows stay contiguous per system)."""
     batched = b.ndim == 2
+    # the builder scopes its own operator applications acg.spmv; its
+    # shifted updates and Gram product default to acg.blas1
+    block_fn = _scoped("acg.blas1", block_fn)
     bc = (lambda v: v[..., None])       # coefficient-axis broadcast:
     # identity-shaped for scalars (() -> (1,)), per-system for (B,)
     vdt = b.dtype
@@ -529,14 +559,16 @@ def cg_sstep_while(block_fn, b, x0, p0, rr0, shifts0, stop2, s: int,
         stalled = active & ~changed & (kiter < maxits)
         flag = jnp.where(active & (bad | stalled), _GRAM_BAD,
                          flag).astype(jnp.int32)
-        if batched:
-            x = jnp.where(changed[:, None],
-                          x + jnp.einsum("bm,mbn->bn", xc, V), x)
-            p = jnp.where(changed[:, None],
-                          jnp.einsum("bm,mbn->bn", pc, V), p)
-        else:
-            x = jnp.where(changed, x + jnp.einsum("m,mn->n", xc, V), x)
-            p = jnp.where(changed, jnp.einsum("m,mn->n", pc, V), p)
+        with jax.named_scope("acg.blas1"):
+            if batched:
+                x = jnp.where(changed[:, None],
+                              x + jnp.einsum("bm,mbn->bn", xc, V), x)
+                p = jnp.where(changed[:, None],
+                              jnp.einsum("bm,mbn->bn", pc, V), p)
+            else:
+                x = jnp.where(changed,
+                              x + jnp.einsum("m,mn->n", xc, V), x)
+                p = jnp.where(changed, jnp.einsum("m,mn->n", pc, V), p)
 
         # on-the-fly Ritz refinement: a COMPLETE block's (α, β) sequence
         # is a Lanczos tridiagonal; its eigenvalues (Ritz estimates of
@@ -571,7 +603,8 @@ def cg_sstep_while(block_fn, b, x0, p0, rr0, shifts0, stop2, s: int,
                            new_shifts, shifts)
         return (x, p, rr_j, shifts, kiter, flag, hist)
 
-    out = jax.lax.while_loop(cond, body, init)
+    out = jax.lax.while_loop(_scoped("acg.check", cond),
+                             _scoped("acg.check", body), init)
     x, p, rr, shifts, kiter, flag, hist = out
     return x, kiter, rr, flag, hist, shifts
 
@@ -691,6 +724,11 @@ def cg_pipelined_while(matvec, dot2, b, x0, stop2, maxits: int,
     """
     batched = b.ndim == 2
     replace_every = replace_period(replace_every, b.dtype)
+    matvec = _scoped("acg.spmv", matvec)
+    dot2 = _scoped("acg.blas1", dot2)
+    # the single-kernel iteration fuses the SpMV with the vector updates
+    # and the dots: one op, counted as the SpMV
+    iter_step = _scoped("acg.spmv", iter_step)
     if carry_in is None:
         r = b - matvec(x0)
         w = matvec(r)
@@ -736,7 +774,8 @@ def cg_pipelined_while(matvec, dot2, b, x0, stop2, maxits: int,
         re-reduce: (r, w, s, z, gamma, delta, r.s, p.s) — the last two
         re-derive the next step's scalars (see docstring)."""
         x, _, _, p, _, _ = args
-        r = b - matvec(x)
+        with jax.named_scope("acg.blas1"):
+            r = b - matvec(x)
         w = matvec(r)
         s = matvec(p)
         z = matvec(s)
@@ -805,12 +844,13 @@ def cg_pipelined_while(matvec, dot2, b, x0, stop2, maxits: int,
             q = inject_vector(fault, SITE_SPMV, k, q)
             # fused 6-vector update (ref acg/cg-kernels-cuda.cu:187-269);
             # XLA fuses these into one pass over the 7 vector streams
-            z = q + bc(beta) * z
-            p = r + bc(beta) * p
-            s = w + bc(beta) * s
-            x = x + bc(alpha) * p
-            r = r - bc(alpha) * s
-            w = w - bc(alpha) * z
+            with jax.named_scope("acg.blas1"):
+                z = q + bc(beta) * z
+                p = r + bc(beta) * p
+                s = w + bc(beta) * s
+                x = x + bc(alpha) * p
+                r = r - bc(alpha) * s
+                w = w - bc(alpha) * z
             gamma_new, delta_new = dot2(r, r, w, r)
             gamma_new = inject_reduction(fault, k, gamma_new)
         g_rec = gamma_new            # recurred, before any replacement
@@ -839,14 +879,15 @@ def cg_pipelined_while(matvec, dot2, b, x0, stop2, maxits: int,
                 def _certify_sel(args):
                     rc, wc, sc, zc, gc, dc, rsc, psc = _replace_step(args)
                     m = bc(need)
-                    return (jnp.where(m, rc, args[1]),
-                            jnp.where(m, wc, args[2]),
-                            jnp.where(m, sc, args[4]),
-                            jnp.where(m, zc, args[5]),
-                            jnp.where(need, gc, gamma_new),
-                            jnp.where(need, dc, delta_new),
-                            jnp.where(need, rsc, rs),
-                            jnp.where(need, psc, ps))
+                    with jax.named_scope("acg.blas1"):
+                        vs = (jnp.where(m, rc, args[1]),
+                              jnp.where(m, wc, args[2]),
+                              jnp.where(m, sc, args[4]),
+                              jnp.where(m, zc, args[5]))
+                    return vs + (jnp.where(need, gc, gamma_new),
+                                 jnp.where(need, dc, delta_new),
+                                 jnp.where(need, rsc, rs),
+                                 jnp.where(need, psc, ps))
 
                 r, w, s, z, gamma_new, delta_new, rs, ps = jax.lax.cond(
                     jnp.any(need), _certify_sel,
@@ -881,8 +922,9 @@ def cg_pipelined_while(matvec, dot2, b, x0, stop2, maxits: int,
             # freeze finished systems: carries, per-system scalars, and
             # the history row all stop advancing
             m = bc(active)
-            x, r, w, p, s, z = (jnp.where(m, v, o)
-                                for v, o in zip((x, r, w, p, s, z), olds))
+            with jax.named_scope("acg.blas1"):
+                x, r, w, p, s, z = (jnp.where(m, v, o) for v, o in
+                                    zip((x, r, w, p, s, z), olds))
             gamma_new = jnp.where(active, gamma_new, gamma)
             delta_new = jnp.where(active, delta_new, delta)
             gamma_prev = jnp.where(active, gamma_prev_next, gamma_prev)
@@ -919,7 +961,8 @@ def cg_pipelined_while(matvec, dot2, b, x0, stop2, maxits: int,
             # — the same k=0 exit the 1-D cond takes
             init = init + (_exit_test(gamma0, 0),
                            jnp.zeros(gamma0.shape, jnp.int32))
-    out = jax.lax.while_loop(cond, body, init)
+    out = jax.lax.while_loop(_scoped("acg.check", cond),
+                             _scoped("acg.check", body), init)
     (x, r, w, p, s, z, gamma, delta, gamma_prev, alpha, k, fresh,
      certified, hist) = out[:14]
     # the maxits door can be reached off the check_every schedule with an
@@ -1082,6 +1125,9 @@ def cg_pipelined_deep_while(matvec, dots, dot, b, x0, stop2, depth: int,
     bc = (lambda v: v[:, None]) if batched else (lambda v: v)
     one = jnp.asarray(1.0, vdt)
     atol2, rtol2 = stop2
+    matvec = _scoped("acg.spmv", matvec)
+    dots = _scoped("acg.blas1", dots)
+    dot = _scoped("acg.blas1", dot)
 
     first = k_start == 0 if k_start is not None else jnp.asarray(True)
     k0 = (jnp.asarray(0, jnp.int32) if k_start is None
@@ -1225,10 +1271,11 @@ def cg_pipelined_deep_while(matvec, dots, dot, b, x0, stop2, depth: int,
         del_t = gcc * mult / gii_s
         # recover v_c (the basis vector the NEXT l bodies' updates ride)
         gcc_s = jnp.where(gcc != 0.0, gcc, one)
-        vsum = jnp.zeros_like(b)
-        for a in range(w - 1):
-            vsum = vsum + bc(col[a]) * V[a + 1]
-        v_c = bc(one / gcc_s) * (Z[l + 1] - vsum)
+        with jax.named_scope("acg.blas1"):
+            vsum = jnp.zeros_like(b)
+            for a in range(w - 1):
+                vsum = vsum + bc(col[a]) * V[a + 1]
+            v_c = bc(one / gcc_s) * (Z[l + 1] - vsum)
 
         # 3. D-Lanczos x-update at index t (residual estimate for free)
         is0 = t == 0
@@ -1236,15 +1283,16 @@ def cg_pipelined_deep_while(matvec, dots, dot, b, x0, stop2, depth: int,
         lam = jnp.where(is0, 0.0, d_tm1 / dp_s)
         dd = gam_t - d_tm1 * lam
         zeta = jnp.where(is0, eta, -lam * zeta_prev)
-        q_new = V[w - 1] - bc(lam) * q
         dd_s = jnp.where(dd != 0.0, dd, one)
-        x_new = x + bc(zeta / dd_s) * q_new
         rr_new = (del_t * zeta / dd_s) ** 2
 
         bad = (dd <= 0.0) | ~good_g
         commit = active & ~bad
-        x = jnp.where(bc(commit), x_new, x)
-        q = jnp.where(bc(commit), q_new, q)
+        with jax.named_scope("acg.blas1"):
+            q_new = V[w - 1] - bc(lam) * q
+            x_new = x + bc(zeta / dd_s) * q_new
+            x = jnp.where(bc(commit), x_new, x)
+            q = jnp.where(bc(commit), q_new, q)
         d_prev = jnp.where(commit, dd, d_prev)
         zeta_prev = jnp.where(commit, zeta, zeta_prev)
         rr_est = jnp.where(commit, rr_new, rr_est)
@@ -1275,10 +1323,11 @@ def cg_pipelined_deep_while(matvec, dots, dot, b, x0, stop2, depth: int,
         z_top, z_prev = Z[w - 1], Z[w - 2]
         wv = matvec(z_top)
         c_s = jnp.where(del_t != 0.0, del_t, one)
-        z_new = bc(one / c_s) * (wv - bc(gam_t) * z_top
-                                 - bc(d_tm1) * z_prev)
-        Z = jnp.concatenate([Z[1:], z_new[None]], axis=0)
-        V = jnp.concatenate([V[1:], v_c[None]], axis=0)
+        with jax.named_scope("acg.blas1"):
+            z_new = bc(one / c_s) * (wv - bc(gam_t) * z_top
+                                     - bc(d_tm1) * z_prev)
+            Z = jnp.concatenate([Z[1:], z_new[None]], axis=0)
+            V = jnp.concatenate([V[1:], v_c[None]], axis=0)
         D_new = dots(Z, z_new)           # the ONE psum of the body
         dbuf = jnp.concatenate([dbuf[1:], D_new[None]], axis=0)
         gbuf = jnp.concatenate([gbuf[1:], gam_t[None]], axis=0)
@@ -1289,7 +1338,8 @@ def cg_pipelined_deep_while(matvec, dots, dot, b, x0, stop2, depth: int,
             ret = ret + (ksys,)
         return ret
 
-    out = jax.lax.while_loop(cond, body, init)
+    out = jax.lax.while_loop(_scoped("acg.check", cond),
+                             _scoped("acg.check", body), init)
     (x, q, Z, V, G, dbuf, gbuf, dlbuf, d_prev, zeta_prev, rr_est,
      k, flag, est, hist) = out[:15]
     touched = flag == _OK                # systems this dispatch drove

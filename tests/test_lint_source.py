@@ -128,6 +128,19 @@ def test_e4_jax_debug_flagged_outside_monitor():
 
 
 # ---------------------------------------------------------------------------
+# E5: kernel names
+
+
+def test_e5_pallas_call_needs_a_name():
+    src = "y = pallas_call(kern, out_shape=s)(x)\n"
+    assert _rules(lint_source(src, HOT)) == ["kernel-name"]
+    assert _rules(lint_source("pl.pallas_call(kern, grid=g)\n",
+                              COLD)) == ["kernel-name"]
+    assert lint_source("pallas_call(kern, name='acg_k', out_shape=s)\n",
+                       HOT) == []
+
+
+# ---------------------------------------------------------------------------
 # pragmas
 
 
@@ -184,4 +197,4 @@ def test_syntax_error_is_reported_not_raised():
 
 def test_rule_catalog_matches_finding_slugs():
     assert set(RULES) == {"gather", "axis-name", "traced-branch",
-                          "debug-callback"}
+                          "debug-callback", "kernel-name"}

@@ -33,7 +33,7 @@ import pytest
 # "what overlaps" and "what is counted" are read from the same parse.
 from acg_tpu.obs.hlo import parse_hlo as _parse_hlo
 
-TAG = "local_spmv"
+TAG = "acg.spmv.local"
 
 
 def _tags(comps, comp, name, seen=None):
