@@ -271,8 +271,9 @@ def roofline_for_sharded(ss, *, solver: str = "cg", nrhs: int = 1,
                          sstep: int = 0,
                          halo_wire: str = "f32") -> RooflineModel:
     """Model a distributed solve over a ShardedSystem: the operator
-    stream is every shard's local block plus the interface ELL (their
-    actual uploaded byte sizes), vectors run over the padded shard rows;
+    stream is every shard's local block plus the interface operator in
+    the form the shard program reads (their actual uploaded byte sizes),
+    vectors run over the padded shard rows;
     the ceiling scales by the mesh size (shards stream in parallel —
     collectives ride ICI, not HBM, and are audited separately by
     obs/hlo.py).  ``halo_wire`` prices the per-iteration ghost payload
@@ -287,7 +288,7 @@ def roofline_for_sharded(ss, *, solver: str = "cg", nrhs: int = 1,
 
     op_bytes = sum(int(a.nbytes) for a in ss.local_op_arrays()
                    if a is not None)
-    op_bytes += int(ss.ivals.nbytes) + int(ss.icols.nbytes)
+    op_bytes += sum(int(a.nbytes) for a in ss.iface_op_arrays())
     if sstep:
         op_bytes *= 2
     n = int(ss.nparts) * int(ss.nown_max)

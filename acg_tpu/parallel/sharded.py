@@ -38,6 +38,89 @@ def _pad8(n: int) -> int:
     return max(-(-n // 8) * 8, 8)
 
 
+# The most contiguous border-row runs a shard's interface may split into
+# for the slice form of the interface correction (ShardedSystem.iruns):
+# a z-slab shard has 2 (its first and last plane), a box of a block
+# partition at most one per face.
+MAX_IFACE_RUNS = 6
+
+
+def _iface_part_runs(A, max_lanes: int):
+    """One part's interface block cut into maximal runs of consecutive
+    rows that have entries, or None when it is not a few such runs.
+
+    In a run starting at row ``r0`` every entry lies on one of a few
+    diagonals ``col - row`` of the (owned row, ghost slot) block, so each
+    diagonal's ghost columns are a unit-stride window: ``row + c``.
+    Returns ``[(r0, n, diags, vals)]`` with ``vals`` (n, len(diags))
+    holding each row's entry on each diagonal (0 where it has none).
+    A run with more diagonals than ``max_lanes`` (the ELL width) is not
+    worth slicing: None."""
+    if not A.nnz:
+        return []
+    rl = A.rowlens
+    rows = np.flatnonzero(rl)
+    cuts = np.flatnonzero(np.diff(rows) != 1) + 1
+    if len(cuts) + 1 > MAX_IFACE_RUNS:
+        return None
+    rowids = np.repeat(np.arange(A.nrows, dtype=np.int64), rl)
+    diag = A.colidx.astype(np.int64) - rowids
+    runs = []
+    for seg in np.split(rows, cuts):
+        r0, n = int(seg[0]), len(seg)
+        lo, hi = int(A.rowptr[r0]), int(A.rowptr[r0 + n])
+        d = diag[lo:hi]
+        diags = np.unique(d)
+        if len(diags) > max_lanes:
+            return None
+        vals = np.zeros((n, len(diags)), dtype=A.vals.dtype)
+        vals[rowids[lo:hi] - r0, np.searchsorted(diags, d)] = A.vals[lo:hi]
+        runs.append((r0, n, diags, vals))
+    return runs
+
+
+def stack_iface_runs(ps: PartitionedSystem, nown: int, nghost: int,
+                     max_lanes: int, dtype):
+    """Every part's interface as contiguous border-row runs, stacked and
+    padded to one static shape, or None when some part's interface is
+    not at most :data:`MAX_IFACE_RUNS` runs fitting a padded shard.
+
+    Returns ``(rows, cols, lens, vals, pad)``: run row starts (P, R),
+    ghost-window starts per diagonal lane (P, R, K), run lengths (P, R),
+    values (P, R, L, K), and the static (front, back) zero margin the
+    ghost vector needs so every window lies inside it.  R, L and K are
+    maxima over parts; a part with fewer runs (an end slab has one
+    neighbour) is padded with empty runs, a run with fewer diagonals
+    with zero-valued lanes that keep an in-range window.  A run's
+    length-L window starts at ``min(row start, nown - L)`` so it stays
+    inside the shard; values and ghost windows are laid out from there."""
+    per = []
+    for p in ps.parts:
+        runs = _iface_part_runs(p.A_iface, max_lanes)
+        if runs is None:
+            return None
+        per.append(runs)
+    P = ps.nparts
+    R = max(max(len(runs) for runs in per), 1)
+    L = max((n for runs in per for _, n, _, _ in runs), default=1)
+    K = max((len(d) for runs in per for _, _, d, _ in runs), default=1)
+    rows = np.zeros((P, R), dtype=np.int32)
+    lens = np.zeros((P, R), dtype=np.int32)
+    cols = np.zeros((P, R, K), dtype=np.int64)
+    vals = np.zeros((P, R, L, K), dtype=dtype)
+    for i, runs in enumerate(per):
+        for j, (r0, n, diags, v) in enumerate(runs):
+            rows[i, j], lens[i, j] = r0, n
+            ws = min(r0, nown - L)
+            cols[i, j, :] = ws + diags[0]
+            cols[i, j, :len(diags)] = ws + diags
+            vals[i, j, r0 - ws:r0 - ws + n, :len(diags)] = v
+    front = max(0, -int(cols.min()))
+    back = max(0, int(cols.max()) + L - nghost)
+    return (rows, (cols + front).astype(np.int32), lens, vals,
+            (front, back))
+
+
 def _dia_padded_nown(maxnown: int) -> int:
     """The DIA shard padding rule — 256-lane alignment above 2048 rows
     (the Pallas row tiles), pad8 below — shared by ShardedSystem.build
@@ -434,6 +517,10 @@ class ShardedSystem:
     st_digits: tuple = ()              # static per-arm axis digits
     st_coeffs: tuple = ()              # static per-arm coefficients
     st_interpret: bool = False         # CPU-test interpret-mode kernel
+    # the interface correction as slices over contiguous border-row runs
+    # (stack_iface_runs) — None keeps the ELL gather over ivals/icols:
+    iruns: tuple | None = None         # (rows, cols, lens, vals) arrays
+    iruns_pad: tuple = (0, 0)          # static ghost-vector zero margins
 
     @property
     def nparts(self) -> int:
@@ -457,7 +544,11 @@ class ShardedSystem:
         acg/cgcuda.c:847-883); "ell" keeps the padded-ELL gather form;
         "auto" picks DIA when the stacked bands are dense enough
         (:func:`local_dia_efficiency` >= 0.25).  The interface (ghost)
-        operator always stays ELL — it is tiny and irregular.  Callers
+        operator is kept as ELL (``ivals``/``icols``); when every part's
+        border rows form a few contiguous runs whose ghost columns are
+        unit-stride windows (:func:`stack_iface_runs` — z-slabs of a
+        natural-order grid), the shard program applies it as slices over
+        those runs instead (``iruns``, :attr:`iface_fmt`).  Callers
         that already swept the parts (build_sharded) pass the resolved
         ``fmt`` plus ``loffsets`` so no O(nnz) sweep repeats here."""
         vdt = np.dtype(dtype if dtype is not None else np.float64)
@@ -530,6 +621,7 @@ class ShardedSystem:
             return vals, cols
 
         iv, ic = stack_ell(lambda p: p.A_iface, Li)
+        runs = stack_iface_runs(ps, NOWN, G, Li, vdt)
         tables = build_halo_tables(ps, nghost_max=G)
 
         from acg_tpu.ops.dia import (DiaMatrix, lossless_cast,
@@ -638,6 +730,10 @@ class ShardedSystem:
         del iv
         icols_dev = put(ic)
         del ic
+        iruns, iruns_pad = None, (0, 0)
+        if runs is not None:
+            rows, cols, lens, vals, iruns_pad = runs
+            iruns = (put(rows), put(cols), put(lens), put(narrow(vals)))
 
         return cls(
             mesh=mesh, ps=ps, nown_max=NOWN, nghost_max=G,
@@ -657,7 +753,8 @@ class ShardedSystem:
             sg_interpret=sgell_interpret,
             st_grid=st_grid, st_offsets=st_offsets,
             st_digits=st_digits, st_coeffs=st_coeffs,
-            st_interpret=stencil_interpret)
+            st_interpret=stencil_interpret,
+            iruns=iruns, iruns_pad=iruns_pad)
 
     # -- vector movement (ref acgvector scatter/gather, acg/vector.c:938+) --
 
@@ -771,6 +868,80 @@ class ShardedSystem:
             def mv(x, ops):
                 return ell_matvec(ops[0], ops[1], x)
         return mv
+
+    @property
+    def iface_fmt(self) -> str:
+        """Form of the interface correction the shard program runs:
+        "runs" (slices over contiguous border-row runs) or "ell"."""
+        return "ell" if self.iruns is None else "runs"
+
+    @property
+    def iface_note(self) -> str:
+        """The solve-result note naming :attr:`iface_fmt`, e.g.
+        "iface runs(2)" with the static run count per shard."""
+        if self.iruns is None:
+            return "iface ell"
+        return f"iface runs({self.iruns[0].shape[-1]})"
+
+    def iface_op_arrays(self) -> tuple:
+        """The traced array operands of the interface correction, as one
+        pytree (the shard program's interface slot)."""
+        return (self.ivals, self.icols) if self.iruns is None else self.iruns
+
+    def iface_fns(self):
+        """Per-shard interface correction closures ``(add, vec)`` over
+        the shard's slices of :meth:`iface_op_arrays` and its ghost
+        vector ``gh``: ``add(y, ops, gh, front=None)`` returns ``y`` plus
+        the correction (written at ``front`` onwards when given — the
+        padded vectors of the fused path); ``vec(ops, gh)`` returns the
+        correction itself.  Vectors may carry a leading (B,) axis.
+
+        The ELL form is the gather ``ell_matvec``.  The runs form reads
+        each run's ghost window with one ``dynamic_slice`` per diagonal
+        lane and adds the masked products into ``y`` with one
+        ``dynamic_update_slice`` per run: no gather and no scatter
+        (contract C5), and the same products and sums per border row."""
+        from acg_tpu.ops.spmv import ell_matvec
+
+        nown = self.nown_max
+        if self.iruns is None:
+            def vec(ops, gh):
+                return ell_matvec(ops[0], ops[1], gh)
+
+            def add(y, ops, gh, front=None):
+                c = vec(ops, gh)
+                if front is None:
+                    return y + c
+                return y.at[front: front + nown].add(c)
+            return add, vec
+
+        pad = self.iruns_pad
+
+        def add(y, ops, gh, front=None):
+            rows, cols, lens, vals = ops
+            R, L, K = vals.shape
+            if pad != (0, 0):
+                gh = jnp.pad(gh, [(0, 0)] * (gh.ndim - 1) + [pad])
+            pos = jax.lax.iota(jnp.int32, L)
+            for r in range(R):
+                acc = None
+                for k in range(K):
+                    g = jax.lax.dynamic_slice_in_dim(gh, cols[r, k], L,
+                                                     axis=-1)
+                    t = vals[r, :, k].astype(gh.dtype) * g
+                    acc = t if acc is None else acc + t
+                ws = jnp.minimum(rows[r], nown - L)
+                off = rows[r] - ws
+                live = (pos >= off) & (pos < off + lens[r])
+                start = ws if front is None else ws + front
+                cur = jax.lax.dynamic_slice_in_dim(y, start, L, axis=-1)
+                y = jax.lax.dynamic_update_slice_in_dim(
+                    y, cur + jnp.where(live, acc, 0), start, axis=-1)
+            return y
+
+        def vec(ops, gh):
+            return add(jnp.zeros(gh.shape[:-1] + (nown,), gh.dtype), ops, gh)
+        return add, vec
 
     def shard_halo_fn(self, wire: str = "f32"):
         """Returns halo(x_own, send_idx, recv_idx, partner, pack_idx, gsp,

@@ -101,6 +101,11 @@ class SolveResult:
     # "format forced: ell".
     # Rendered after the kernel name in the -v stats block.
     kernel_note: str = ""
+    # distributed solves: which form of the interface (ghost) correction
+    # the shard program ran — "iface runs(R)" (slices over R contiguous
+    # border-row runs per shard) or "iface ell" (the ELL gather); ""
+    # on one chip
+    iface: str = ""
     # per-iteration residual-norm² trajectory, length niterations+1
     # (entry 0 = |r0|²; entry k = |r_k|², the recurred gamma for
     # pipelined CG except at certification points, where it is the true
@@ -124,6 +129,13 @@ class SolveResult:
     rnrm2_per_system: np.ndarray | None = None
     r0nrm2_per_system: np.ndarray | None = None
     converged_per_system: np.ndarray | None = None
+
+    @property
+    def path(self) -> tuple:
+        """What ran: (operator_format, kernel) followed by the non-empty
+        notes — the kernel note and, on a mesh, the interface form."""
+        return (self.operator_format, self.kernel) + tuple(
+            n for n in (self.kernel_note, self.iface) if n)
 
     @property
     def relative_residual(self) -> float:

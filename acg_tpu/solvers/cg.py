@@ -1264,6 +1264,7 @@ def _finish(A, x, k, rr, flag, rr0, options, tsolve, pipelined, bnrm2,
                       else "non-finite values in solution or residual"),
             operator_format=path[0], kernel=path[1],
             kernel_note=path[2] if len(path) > 2 else "",
+            iface=path[3] if len(path) > 3 else "",
             residual_history=hist if has_hist else None,
             nrhs=nrhs,
             iterations_per_system=ksys if batched else None,

@@ -193,6 +193,7 @@ def _shard_solver(ss: ShardedSystem, kind: str, maxits: int,
     cert_halo_fn = (ss.shard_halo_fn(wire="f32")
                     if deep_kind and wire != "f32" else None)
     local_mv = ss.local_matvec_fn()
+    iface_add, iface_vec = ss.iface_fns()
     # the padded fused-coupled formulation and the single-kernel pipelined
     # iteration are 1-D tiers; batched solves run the plain formulation,
     # whose per-shard matvec still routes (B, n) blocks through the
@@ -216,7 +217,7 @@ def _shard_solver(ss: ShardedSystem, kind: str, maxits: int,
     spec_v = P(PARTS_AXIS)      # (P, ...) arrays, sharded on leading axis
     spec_r = P()                # replicated scalars
 
-    def solve_shard(lops, iv, ic, sidx, ridx, ptnr, pidx, gsp, gpp,
+    def solve_shard(lops, iops, sidx, ridx, ptnr, pidx, gsp, gpp,
                     b, x0, stop2, diffstop, *rest):
         # optional trailing arguments, in order: the deep-ghost layer's
         # ten sharded tables (s-step and deep-pipelined programs), the
@@ -251,7 +252,7 @@ def _shard_solver(ss: ShardedSystem, kind: str, maxits: int,
         if carry_in is not None:    # per-shard vectors lose the axis too
             carry_in = tuple(a[0] if i < nshard_carry else a
                              for i, a in enumerate(carry_in))
-        iv, ic = iv[0], ic[0]
+        iops = tuple(a[0] for a in iops)
         sidx, ridx, ptnr, pidx, gsp, gpp = (
             sidx[0], ridx[0], ptnr[0], pidx[0], gsp[0], gpp[0])
         b, x0 = b[0], x0[0]
@@ -300,7 +301,7 @@ def _shard_solver(ss: ShardedSystem, kind: str, maxits: int,
                     y_local = jax.lax.optimization_barrier(
                         local_mv(x, lops))
                 with jax.named_scope("acg.spmv.iface"):
-                    return y_local + ell_matvec(iv, ic, gh)
+                    return iface_add(y_local, iops, gh)
         else:
             # the fused padded path, per shard: vectors carry a permanent
             # zero halo (padded once per SOLVE, zero per-iteration pads —
@@ -332,8 +333,7 @@ def _shard_solver(ss: ShardedSystem, kind: str, maxits: int,
                     t = kernel(bands_pad, offsets, xp, rows_tile=rt,
                                scales=scales)
                 with jax.named_scope("acg.spmv.iface"):
-                    return t.at[front: front + nown].add(
-                        ell_matvec(iv, ic, gh))
+                    return iface_add(t, iops, gh, front)
 
             def coupled(r, p, beta):
                 with jax.named_scope("acg.blas1"):
@@ -344,7 +344,7 @@ def _shard_solver(ss: ShardedSystem, kind: str, maxits: int,
                     t, pdot = kernel(bands_pad, offsets, p, rows_tile=rt,
                                      with_dot=True, scales=scales)
                 with jax.named_scope("acg.spmv.iface"):
-                    iface = ell_matvec(iv, ic, gh)
+                    iface = iface_vec(iops, gh)
                     t = t.at[front: front + nown].add(iface)
                 with jax.named_scope("acg.blas1"):
                     pdot = pdot + jnp.vdot(po, iface)
@@ -373,7 +373,7 @@ def _shard_solver(ss: ShardedSystem, kind: str, maxits: int,
                                 alpha, beta, rows_tile=pipe_rt,
                                 scales=scales)
                     with jax.named_scope("acg.spmv.iface"):
-                        iface = ell_matvec(iv, ic, gh)
+                        iface = iface_vec(iops, gh)
                         z2 = zk.at[front: front + nown].add(iface)
                         w2 = wk.at[front: front + nown].add(-alpha * iface)
                     with jax.named_scope("acg.blas1"):
@@ -578,7 +578,7 @@ def _shard_solver(ss: ShardedSystem, kind: str, maxits: int,
                         y = jax.lax.optimization_barrier(
                             local_mv(v, lops))
                     with jax.named_scope("acg.spmv.iface"):
-                        return y + ell_matvec(iv, ic, gh)
+                        return iface_add(y, iops, gh)
 
             k_start, rr0_in, flags_in, hist_in = restart_in[:4]
             ksys_in = restart_in[4] if batched else None
@@ -633,7 +633,7 @@ def _shard_solver(ss: ShardedSystem, kind: str, maxits: int,
     sstep_out = ((spec_r,) if sstep else ())
     mapped = jax.shard_map(
         solve_shard, mesh=mesh,
-        in_specs=(spec_v,) * 11 + (spec_r, spec_r)
+        in_specs=(spec_v,) * 10 + (spec_r, spec_r)
         + ((spec_v,) * 10 if sstep or deep_kind else ())
         + sstep_in
         + deep_in
@@ -757,6 +757,12 @@ def _split7(out):
     return out[:7] + (out[7:],)
 
 
+def _with_deep_note(path: tuple, note: str) -> tuple:
+    """Fold the deep-pipeline dispatch note into the kernel note, ahead
+    of any disengagement reason (as the single-chip solver reports it)."""
+    return path[:2] + (note + ("; " + path[2] if path[2] else ""),)
+
+
 def _solve_dist(kind: str, A, b, x0, options: SolverOptions,
                 stats: SolveStats | None, fault=None,
                 atol2_floor=None, recycle=None, **build_kw) -> SolveResult:
@@ -857,7 +863,7 @@ def _solve_dist(kind: str, A, b, x0, options: SolverOptions,
                       monitor_every=o.monitor_every, nrhs=nrhs,
                       guard=guard, has_fault=fplan is not None,
                       wire=o.halo_wire)
-        args = (ss.local_op_arrays(), ss.ivals, ss.icols, ss.send_idx,
+        args = (ss.local_op_arrays(), ss.iface_op_arrays(), ss.send_idx,
                 ss.recv_idx, ss.partner, ss.pack_idx, ss.ghost_src_part,
                 ss.ghost_src_pos, b_sh, x0_sh, stop2, diffstop)
         ftail = () if fplan is None else (fplan,)
@@ -891,7 +897,7 @@ def _solve_dist(kind: str, A, b, x0, options: SolverOptions,
             while True:
                 ndisp += 1
                 (x_sh, kret, rr, dxx, flag, rr0_op, hist_op, k_op, more,
-                 drift) = fn(*args[:10], x_sh, *args[11:], *dtail,
+                 drift) = fn(*args[:9], x_sh, *args[10:], *dtail,
                              k_op, rr0_op, flags_op, hist_op, *ktail)
                 if batched:
                     ktail = (kret,)
@@ -1013,10 +1019,10 @@ def _solve_dist(kind: str, A, b, x0, options: SolverOptions,
         nrows = ss.nrows
         nnz = ss.nnz
 
-    # which local-operator format + kernel tier ran (the iface operator
-    # is always the tiny ELL gather; see ShardedSystem.build docstring);
-    # naming shared with the single-chip solver via path_names — including
-    # the pipe2d report: when the single-kernel pipelined iteration gate
+    # which local-operator format + kernel tier ran (the interface form
+    # rides after the note: ShardedSystem.iface_note); naming shared
+    # with the single-chip solver via path_names — including the pipe2d
+    # report: when the single-kernel pipelined iteration gate
     # is active the in-loop kernel is pipe2d, not the plan's SpMV tier
     from acg_tpu.solvers.base import path_names
 
@@ -1046,14 +1052,15 @@ def _solve_dist(kind: str, A, b, x0, options: SolverOptions,
         kind == "cg-pipelined", plan, pipe_rt, fplan,
         forced_fmt=build_kw.get("fmt", "auto")),)
     if kind == "cg-pipelined-deep":
-        path = path + (f"deep pipeline depth {depth}, {ndisp} "
-                       f"dispatch(es), wire={o.halo_wire}",)
+        path = _with_deep_note(path, f"deep pipeline depth {depth}, "
+                               f"{ndisp} dispatch(es), wire={o.halo_wire}")
     return _finish(_Meta, x, k, rr, flag, rr0, o, tsolve,
                    pipelined=(kind in ("cg-pipelined",
                                        "cg-pipelined-deep")),
                    bnrm2=bnrm2,
                    dxx=dxx if track_diff else None, stats=stats,
-                   to_host=ss.from_sharded, path=path, hist=hist, sstep=sstep,
+                   to_host=ss.from_sharded, path=path + (ss.iface_note,),
+                   hist=hist, sstep=sstep,
                    solver=("cg-pipelined-deep"
                            if kind == "cg-pipelined-deep" else None))
 
@@ -1187,7 +1194,7 @@ def lowered_step(A, b=None, x0=None,
         if nrhs > 1:
             dtail = dtail + (jnp.zeros(sshape, jnp.int32),)
     return fn.lower(
-        ss.local_op_arrays(), ss.ivals, ss.icols, ss.send_idx,
+        ss.local_op_arrays(), ss.iface_op_arrays(), ss.send_idx,
         ss.recv_idx, ss.partner, ss.pack_idx, ss.ghost_src_part,
         ss.ghost_src_pos, b_sh, x0_sh, stop2, diffstop,
         *(deep.arrays() if deep is not None else ()), *dtail)
@@ -1267,7 +1274,7 @@ def aot_step(A, b=None, x0=None,
     vdt = np.dtype(ss.vec_dtype)
     shape = ((nrhs, ss.nrows) if batched else (ss.nrows,))
     track_diff = (kind == "cg") and (o.diffatol > 0 or o.diffrtol > 0)
-    static_args = (ss.local_op_arrays(), ss.ivals, ss.icols, ss.send_idx,
+    static_args = (ss.local_op_arrays(), ss.iface_op_arrays(), ss.send_idx,
                    ss.recv_idx, ss.partner, ss.pack_idx,
                    ss.ghost_src_part, ss.ghost_src_pos)
     darrs = ()
@@ -1400,15 +1407,16 @@ def aot_step(A, b=None, x0=None,
         tsolve = time.perf_counter() - t0
         path2 = path
         if deep_kind:
-            path2 = path + (f"deep pipeline depth {o.pipeline_depth}, "
-                            f"{ndisp} dispatch(es), "
-                            f"wire={o.halo_wire}",)
+            path2 = _with_deep_note(
+                path, f"deep pipeline depth {o.pipeline_depth}, "
+                f"{ndisp} dispatch(es), wire={o.halo_wire}")
         return _finish(_Meta, x, k, rr, flag, rr0, oo, tsolve,
                        pipelined=(kind in ("cg-pipelined",
                                            "cg-pipelined-deep")),
                        bnrm2=bnrm2,
                        dxx=dxx if track_diff else None, stats=stats,
-                       to_host=ss.from_sharded, path=path2, hist=hist,
+                       to_host=ss.from_sharded,
+                       path=path2 + (ss.iface_note,), hist=hist,
                        solver=("cg-pipelined-deep" if deep_kind
                                else None))
 

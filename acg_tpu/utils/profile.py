@@ -99,7 +99,6 @@ def profile_dist_ops(ss, stats: SolveStats, niterations: int,
     and the per-op event pairs acg/cgcuda.c:583-605)."""
     from jax.sharding import PartitionSpec as P
 
-    from acg_tpu.ops.spmv import ell_matvec
     from acg_tpu.parallel.mesh import PARTS_AXIS
 
     k = max(niterations, 1)
@@ -131,7 +130,7 @@ def profile_dist_ops(ss, stats: SolveStats, niterations: int,
     # compute ops, timed as the sharded programs the solve actually runs
     n_tot = int(ss.nparts * ss.nown_max)
     ib = ss.icols.dtype.itemsize
-    iface_bytes = int(ss.ivals.size) * (ss.ivals.dtype.itemsize + ib)
+    iface_bytes = sum(int(a.nbytes) for a in ss.iface_op_arrays())
     if ss.local_fmt == "dia":      # bands stream + x read + y write
         local_bytes = int(ss.lbands.size) * ss.lbands.dtype.itemsize
     else:                          # vals + colidx streams + x gather
@@ -139,20 +138,21 @@ def profile_dist_ops(ss, stats: SolveStats, niterations: int,
     gemv_bytes = local_bytes + iface_bytes + 3 * n_tot * vb
 
     local_mv = ss.local_matvec_fn()
+    iface_add, _ = ss.iface_fns()
 
-    def gemv_shard(lops, iv, ic, x, g):
+    def gemv_shard(lops, iops, x, g):
         # local + interface SpMV, the full operator application the solve
         # performs (ghost values irrelevant for timing — same work)
         lops = tuple(a[0] for a in lops)
-        return (local_mv(x[0], lops)
-                + ell_matvec(iv[0], ic[0], g[0]))[None]
+        iops = tuple(a[0] for a in iops)
+        return iface_add(local_mv(x[0], lops), iops, g[0])[None]
 
     gemv_jit = jax.jit(jax.shard_map(
-        gemv_shard, mesh=mesh, in_specs=(spec_v,) * 5, out_specs=spec_v,
+        gemv_shard, mesh=mesh, in_specs=(spec_v,) * 4, out_specs=spec_v,
         check_vma=False))
     g_sh = jnp.zeros((ss.nparts, ss.nghost_max),
                      dtype=np.dtype(ss.vec_dtype))
-    t_gemv = time_op(gemv_jit, ss.local_op_arrays(), ss.ivals, ss.icols,
+    t_gemv = time_op(gemv_jit, ss.local_op_arrays(), ss.iface_op_arrays(),
                      x_sh, g_sh)
 
     def dot_shard(u, v):

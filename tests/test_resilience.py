@@ -559,7 +559,7 @@ def test_fault_plan_adds_no_collectives(problem):
     import jax.numpy as jnp
     fplan = FaultSpec.parse("spmv@2").device_plan(np.float64)
     lowered = fn.lower(
-        ss.local_op_arrays(), ss.ivals, ss.icols, ss.send_idx,
+        ss.local_op_arrays(), ss.iface_op_arrays(), ss.send_idx,
         ss.recv_idx, ss.partner, ss.pack_idx, ss.ghost_src_part,
         ss.ghost_src_pos, ss.zeros_sharded(), ss.zeros_sharded(),
         (jnp.asarray(0.0), jnp.asarray(1e-20)), jnp.asarray(0.0),

@@ -320,9 +320,12 @@ def test_roofline_sharded_interface_only():
     ss = build_sharded(A, part=part, nparts=4, fmt="stencil")
     m = roofline_for_sharded(ss, device_kind="TPU v5e")
     assert m.operator_format == "stencil"
-    # the local operator streams nothing; only the tiny interface ELL
-    # (a stored operator by design) remains
-    assert m.operator_bytes == int(ss.ivals.nbytes) + int(ss.icols.nbytes)
+    # the local operator streams nothing; only the tiny interface
+    # operator (a stored operator by design) remains — here row slabs,
+    # so the border-row runs the shard program reads
+    assert ss.iface_fmt == "runs"
+    assert m.operator_bytes == sum(int(a.nbytes)
+                                   for a in ss.iface_op_arrays())
 
 
 # -- distributed ------------------------------------------------------------
