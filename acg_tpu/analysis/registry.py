@@ -48,12 +48,19 @@ _CLASSIC_OPTS = SolverOptions(maxits=5, residual_rtol=1e-9)
 _SSTEP_OPTS = SolverOptions(maxits=8, residual_rtol=1e-9, sstep=SSTEP)
 _DEEP_OPTS = SolverOptions(maxits=8, residual_rtol=1e-9,
                            pipeline_depth=DEPTH)
+# the multigrid cases: 2 grids on the 8³ 27-point problem (mg_problem)
+_MG_OPTS = SolverOptions(maxits=5, residual_rtol=1e-9, precond="mg",
+                         mg_levels=2)
 
 
-def solver_options(solver: str, wire: str = "f32") -> SolverOptions:
+def solver_options(solver: str, wire: str = "f32",
+                   precond: str = "none") -> SolverOptions:
     """The options each registry case compiles under (tolerances are
     runtime operands — only the static shape of the program matters).
-    ``wire`` selects the compressed halo wire format sub-matrix."""
+    ``wire`` selects the compressed halo wire format sub-matrix,
+    ``precond`` the preconditioned classic loop."""
+    if precond == "mg":
+        return _MG_OPTS
     o = (_SSTEP_OPTS if solver == "cg-sstep"
          else _DEEP_OPTS if solver == "cg-pipelined-deep"
          else _CLASSIC_OPTS)
@@ -168,12 +175,14 @@ class ContractCase:
     nrhs: int
     fmt: str = "auto"       # "stencil" = the matrix-free tier, forced
     wire: str = "f32"       # compressed halo wire format sub-matrix
+    precond: str = "none"   # "mg" = HPCG's V-cycle on mg_problem()
 
     @property
     def name(self) -> str:
         tier = "-st" if self.fmt == "stencil" else ""
         w = "" if self.wire == "f32" else f"-w{self.wire}"
-        return (f"{self.solver}{tier}-p{self.nparts}-{self.dtype}"
+        pc = "" if self.precond == "none" else f"-{self.precond}"
+        return (f"{self.solver}{pc}{tier}-p{self.nparts}-{self.dtype}"
                 f"-b{self.nrhs}{w}")
 
 
@@ -200,6 +209,12 @@ def registry_cases(fast: bool = False) -> list[ContractCase]:
                 for nrhs in (1, 4):
                     cases.append(ContractCase(solver, nparts, dtype,
                                               nrhs, fmt="dia"))
+    # the multigrid-preconditioned classic loop, one chip, one
+    # right-hand side, on the stored-band and matrix-free tiers alike:
+    # no collectives, and gather- and scatter-free
+    for fmt in ("dia", "stencil"):
+        cases.append(ContractCase("cg", 1, "float32", 1, fmt=fmt,
+                                  precond="mg"))
     if fast:
         cases.append(ContractCase("cg", 1, "float32", 1, fmt="stencil"))
     else:
@@ -231,6 +246,15 @@ def default_problem():
     return poisson2d_5pt(12)
 
 
+def mg_problem():
+    """The multigrid cases' system: HPCG's 27-point operator on 8³ (the
+    V-cycle's program has the same ops at every level, so two levels
+    pin what four do, at half the compile time)."""
+    from acg_tpu.sparse import poisson3d_27pt
+
+    return poisson3d_27pt(8)
+
+
 def _slab_part(A, nparts: int) -> np.ndarray:
     """Axis-aligned slab partition of the (assumed square-2D-grid)
     sweep problem — the partition under which every local block IS the
@@ -258,7 +282,7 @@ def _build_operator(case: ContractCase, A, ss_cache: dict, fmt: str,
     if case.nparts == 1:
         from acg_tpu.solvers.cg import build_device_operator
 
-        key = (1, case.dtype, fmt)
+        key = (1, case.dtype, fmt, case.precond)
         dev = ss_cache.get(key)
         if dev is None:
             dev = ss_cache[key] = build_device_operator(
@@ -283,7 +307,10 @@ def _compile_case(case: ContractCase, A, ss_cache: dict,
     unsupported configurations to SKIP entries).  ``fmt`` overrides the
     case's tier (the matrix-free pair check compiles a stored-tier twin
     of a stencil case)."""
-    opts = solver_options(case.solver, wire=case.wire)
+    opts = solver_options(case.solver, wire=case.wire,
+                          precond=case.precond)
+    if case.precond != "none":
+        A = mg_problem()
     slab = case.fmt == "stencil"
     fmt = case.fmt if fmt is None else fmt
     b = (np.ones(A.nrows) if case.nrhs == 1
@@ -310,7 +337,7 @@ def _stored_operator_facts(case: ContractCase, ss_cache: dict):
     carries local shapes) and the exact shapes that must not appear as
     while-body parameters of the matrix-free program."""
     if case.nparts == 1:
-        dev = ss_cache[(1, case.dtype, "dia")]
+        dev = ss_cache[(1, case.dtype, "dia", case.precond)]
         dims = {tuple(dev.bands.shape)}
         if dev.scales is not None:
             dims.add(tuple(dev.scales.shape))
@@ -411,7 +438,8 @@ def run_registry(fast: bool = False, problem=None,
     # no extra gathers (acg_tpu/analysis/contracts.py
     # verify_matrix_free) — "we deleted the band stream", statically
     for case in registry_cases(fast=fast):
-        if case.fmt != "stencil" or case.name not in texts:
+        if (case.fmt != "stencil" or case.precond != "none"
+                or case.name not in texts):
             continue
         entry = {"name": f"{case.name}-vs-stored", "verdict": "PASS",
                  "violations": []}

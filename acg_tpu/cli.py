@@ -119,6 +119,14 @@ def make_parser() -> argparse.ArgumentParser:
                         "every exit against the true residual; 2 <= L "
                         "<= 8 (L=1 dispatches the ordinary pipelined "
                         "solver, bit-identically) [2]")
+    p.add_argument("--precond", default="none", choices=["none", "mg"],
+                   help="preconditioner of single-chip classic CG: mg = "
+                        "HPCG's multigrid V-cycle (8-colour symmetric "
+                        "Gauss-Seidel, injection; needs a 3-D "
+                        "constant-coefficient stencil whose grid halves "
+                        "--mg-levels - 1 times) [none]")
+    p.add_argument("--mg-levels", type=int, default=4, metavar="L",
+                   help="grids in the --precond mg V-cycle [4]")
     p.add_argument("--max-iterations", type=int, default=100, metavar="N",
                    help="maximum number of iterations [100]")
     p.add_argument("--diff-atol", type=float, default=0.0, metavar="TOL")
@@ -965,6 +973,9 @@ def _main(argv=None) -> int:
                        "pipeline depth must be in [1, 8] (basis "
                        "conditioning caps the useful range; depth 1 "
                        "IS the ordinary pipelined solver)")
+    if args.mg_levels < 1:
+        raise AcgError(Status.ERR_INVALID_VALUE,
+                       f"--mg-levels {args.mg_levels}: at least 1 grid")
     if args.halo_wire != "f32" and args.halo == "rdma":
         raise AcgError(Status.ERR_NOT_SUPPORTED,
                        "--halo-wire compresses the collective message "
@@ -981,6 +992,7 @@ def _main(argv=None) -> int:
         sstep=args.sstep if sstep_mode else 0,
         pipeline_depth=args.pipeline_depth if deep_mode else 1,
         halo_wire=args.halo_wire,
+        precond=args.precond, mg_levels=args.mg_levels,
         # detection rides along whenever injection or supervision is on
         guard_nonfinite=bool(args.resilient or fault_specs))
 

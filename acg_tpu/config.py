@@ -158,6 +158,16 @@ class SolverOptions:
     # traces the exact unguarded program — zero hot-loop cost when off
     # (PERF.md "Resilience overhead").  solve_resilient() forces it on.
     guard_nonfinite: bool = False
+    # Preconditioner of single-chip classic CG: "none", or "mg" — HPCG's
+    # multigrid V-cycle (acg_tpu/ops/mg.py) over `mg_levels` grids, each
+    # halved in every dimension, with one 8-colour symmetric Gauss-Seidel
+    # sweep before and after the coarse correction and one on the
+    # coarsest grid.  "mg" needs a recognized 3-D constant-coefficient
+    # stencil whose grid halves mg_levels - 1 times; every other solver,
+    # batch and operator raises ERR_NOT_SUPPORTED, never an
+    # unpreconditioned solve.  "none" traces the unpreconditioned loop.
+    precond: str = "none"
+    mg_levels: int = 4
 
     def __post_init__(self):
         if self.maxits < 0:
@@ -179,6 +189,11 @@ class SolverOptions:
                              "ordinary pipelined solver; basis "
                              "conditioning is the practical ceiling, "
                              "see PERF.md)")
+        if self.precond not in ("none", "mg"):
+            raise ValueError("precond must be 'none' or 'mg' (HPCG's "
+                             "multigrid V-cycle)")
+        if self.mg_levels < 1:
+            raise ValueError("mg_levels must be >= 1")
         if self.halo_wire not in ("f32", "bf16", "int16-delta"):
             raise ValueError("halo_wire must be one of 'f32' (full-width "
                              "wire, the default), 'bf16', 'int16-delta'")

@@ -21,12 +21,15 @@ Names follow one scheme, ``acg.<layer>[.<stage>]``:
   ``acg.serve.batch`` (``n``, ``bucket``) with ``acg.serve.stack``,
   ``.dispatch`` and ``.demux``, and ``acg.serve.respond`` (``trace_id``)
   per response; ``acg.prep.assemble``, ``.partition`` and ``.upload``
-  while an operator is prepared;
+  while an operator is prepared, and ``acg.prep.mg`` while a multigrid
+  hierarchy is planned;
 - device scopes (``jax.named_scope``) on every op of the solve loops:
   ``acg.spmv`` (``.local``, ``.iface`` and ``.skin`` on the distributed
   path), ``acg.blas1``, ``acg.halo.pack`` / ``.exchange`` / ``.unpack``,
-  ``acg.allreduce`` and ``acg.check``; the innermost ``acg.*`` scope of
-  an op's name path is its layer.  Pallas kernels carry ``acg_<kernel>``
+  ``acg.allreduce``, ``acg.check``, and around a preconditioner
+  ``acg.precond`` with the V-cycle's ``acg.mg.smooth`` / ``.residual``
+  / ``.restrict`` / ``.prolong``; the innermost ``acg.*`` scope of an
+  op's name path is its layer.  Pallas kernels carry ``acg_<kernel>``
   names (``pallas_call(name=...)``).
 
 The reference driver interleaves ``acgtime_gettime`` pairs around each

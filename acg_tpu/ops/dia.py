@@ -166,6 +166,11 @@ class DeviceDia:
     nnz: int = dataclasses.field(metadata=dict(static=True), default=0)
     vec_dtype: str = dataclasses.field(metadata=dict(static=True),
                                        default="float32")
+    # the constant-coefficient stencil these bands store, when recognized
+    # (acg_tpu/ops/stencil.py StencilSpec), else None: a multigrid
+    # preconditioner is derived from it while the SpMV reads the bands
+    stencil: object = dataclasses.field(metadata=dict(static=True),
+                                        default=None)
 
     @classmethod
     def from_dia(cls, D: DiaMatrix, dtype=None, mat_dtype="auto") -> "DeviceDia":

@@ -761,9 +761,13 @@ class DeviceStencil:
     def mat_itemsize(self) -> int:
         return 0
 
-    def spec_hash(self) -> str:
+    @property
+    def spec(self) -> StencilSpec:
         return StencilSpec(self.grid, self.offsets, self.digits,
-                           self.coeffs, self.nnz).spec_hash()
+                           self.coeffs, self.nnz)
+
+    def spec_hash(self) -> str:
+        return self.spec.spec_hash()
 
     def operator_stream_bytes(self) -> int:
         """ZERO: the whole point.  No band arrays exist; the roofline

@@ -191,7 +191,7 @@ def demux_result(res: SolveResult, i: int, bnrm2: float) -> SolveResult:
                   else "non-finite values in solution or residual"),
         operator_format=res.operator_format, kernel=res.kernel,
         kernel_note=res.kernel_note, iface=res.iface,
-        residual_history=hist, nrhs=1)
+        precond=res.precond, residual_history=hist, nrhs=1)
     # status: a converged system is a SUCCESS even when a batch-mate
     # failed; a non-converged one inherits the batch classification
     # (fault/breakdown/non-convergence) — honest per-request outcomes

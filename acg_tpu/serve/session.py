@@ -492,7 +492,7 @@ class Session:
                 self._tier(),
                 o.maxits, o.check_every, o.replace_every,
                 o.monitor_every, o.guard_nonfinite, o.sstep,
-                o.pipeline_depth, o.halo_wire,
+                o.pipeline_depth, o.halo_wire, o.precond, o.mg_levels,
                 o.residual_atol > 0, o.residual_rtol > 0,
                 o.diffatol > 0, o.diffrtol > 0, bool(has_x0))
 

@@ -106,6 +106,9 @@ class SolveResult:
     # border-row runs per shard) or "iface ell" (the ELL gather); ""
     # on one chip
     iface: str = ""
+    # the preconditioner the loop applied: "mg<L> symgs8c" (HPCG's
+    # L-level V-cycle with 8-colour symmetric Gauss-Seidel), or ""
+    precond: str = ""
     # per-iteration residual-norm² trajectory, length niterations+1
     # (entry 0 = |r0|²; entry k = |r_k|², the recurred gamma for
     # pipelined CG except at certification points, where it is the true
@@ -133,9 +136,10 @@ class SolveResult:
     @property
     def path(self) -> tuple:
         """What ran: (operator_format, kernel) followed by the non-empty
-        notes — the kernel note and, on a mesh, the interface form."""
+        notes — the kernel note, on a mesh the interface form, and the
+        preconditioner."""
         return (self.operator_format, self.kernel) + tuple(
-            n for n in (self.kernel_note, self.iface) if n)
+            n for n in (self.kernel_note, self.iface, self.precond) if n)
 
     @property
     def relative_residual(self) -> float:
@@ -242,6 +246,18 @@ def kernel_disengagement_note(pipelined: bool, plan, pipe_rt,
                    else "kernel probe unavailable")
         notes.append(f"stpipe2d disengaged: {why}")
     return "; ".join(notes)
+
+
+def require_unpreconditioned(options, solver: str) -> None:
+    """ERR_NOT_SUPPORTED for a preconditioner on a solver that has none
+    (only single-chip classic CG applies one): never a silent
+    unpreconditioned solve."""
+    if options.precond != "none":
+        from acg_tpu.errors import AcgError, Status
+
+        raise AcgError(Status.ERR_NOT_SUPPORTED,
+                       f"precond={options.precond!r} runs on single-chip "
+                       f"classic CG only, not {solver}")
 
 
 def cg_flops_per_iter(nnz: int, nrows: int, pipelined: bool = False,

@@ -43,7 +43,8 @@ from acg_tpu.obs.trace import span
 from acg_tpu.ops.blas1 import batched_dot, gram
 from acg_tpu.ops.spmv import DeviceEll, pad_vector
 from acg_tpu.solvers.base import (SolveResult, SolveStats,
-                                  cg_flops_per_iter)
+                                  cg_flops_per_iter,
+                                  require_unpreconditioned)
 from acg_tpu.solvers.loops import (cg_pipelined_deep_while,
                                    cg_pipelined_while, cg_sstep_while,
                                    cg_while)
@@ -97,22 +98,24 @@ def _device_wait(x, k):
 
 @functools.partial(jax.jit,
                    static_argnames=("maxits", "track_diff", "check_every",
-                                    "monitor", "monitor_every", "guard"))
+                                    "monitor", "monitor_every", "guard",
+                                    "mg"))
 def _cg_device(op, b, x0, stop2, diffstop, maxits: int, track_diff: bool,
                check_every: int = 1, monitor=None, monitor_every: int = 0,
-               fault=None, guard: bool = False):
+               fault=None, guard: bool = False, mg=None):
     """Classic CG; returns (x, k, rnrm2sqr, dxnrm2sqr, flag, r0nrm2sqr,
     hist).
 
     ``op`` is a device operator pytree (DeviceEll or DeviceDia) whose
     static fields select the SpMV formulation at trace time.  ``fault``
     (a DeviceFaultPlan pytree — data, not trace structure) and ``guard``
-    (static) are the resilience hooks of acg_tpu/robust/."""
+    (static) are the resilience hooks of acg_tpu/robust/.  ``mg`` (a
+    static :class:`~acg_tpu.ops.mg.MgPlan`) preconditions the loop."""
     return cg_while(op.matvec, batched_dot,
                     b, x0, stop2, diffstop, maxits, track_diff,
                     check_every=check_every,
                     monitor=monitor, monitor_every=monitor_every,
-                    fault=fault, guard=guard)
+                    fault=fault, guard=guard, precond=_precond_fn(mg))
 
 
 @functools.partial(jax.jit,
@@ -227,12 +230,12 @@ def _pad_fused(op, b, x0, rows_tile: int):
 @functools.partial(jax.jit,
                    static_argnames=("maxits", "track_diff", "check_every",
                                     "rows_tile", "kind", "monitor",
-                                    "monitor_every", "guard"))
+                                    "monitor_every", "guard", "mg"))
 def _cg_device_fused(op, b, x0, stop2, diffstop, maxits: int,
                      track_diff: bool, check_every: int, rows_tile: int,
                      kind: str = "resident", monitor=None,
                      monitor_every: int = 0, fault=None,
-                     guard: bool = False):
+                     guard: bool = False, mg=None):
     """Classic CG through the padded 2-D Pallas fast path: vectors carry a
     permanent zero halo (no per-iteration pad copy — the naive kernel
     wrapper re-pads x every call, ~17 MB/iter of pure copy at 128³), and
@@ -251,7 +254,7 @@ def _cg_device_fused(op, b, x0, stop2, diffstop, maxits: int,
         mv, batched_dot, bp, xp, stop2, diffstop, maxits, track_diff,
         check_every=check_every, coupled_step=coupled,
         monitor=monitor, monitor_every=monitor_every,
-        fault=fault, guard=guard)
+        fault=fault, guard=guard, precond=_precond_fn(mg, hpad))
     return (jax.lax.slice_in_dim(x, hpad, hpad + n, axis=-1),
             k, rr, dxx, flag, rr0, hist)
 
@@ -891,6 +894,7 @@ def cg_sstep(A, b, x0=None, options: SolverOptions = SolverOptions(),
     ISSUE 20 — the certification above makes a stale schedule a
     performance question, never a correctness one)."""
     o = options
+    require_unpreconditioned(o, "cg-sstep")
     s = _sstep_validate(o, fault)
     if shifts0 is None and recycle is not None:
         shifts0 = recycle.get_shifts(s)
@@ -982,6 +986,47 @@ def _prefer_stencil(st, mat_dtype, nrhs: int) -> bool:
         nrhs)
 
 
+def _dia_keeping_stencil(D, st, dtype, mat_dtype):
+    """The stored-band operator of ``D``, carrying the stencil that
+    ``st`` (a DeviceStencil, or None) recognized in it."""
+    from acg_tpu.ops.dia import DeviceDia
+
+    dev = DeviceDia.from_dia(D, dtype=dtype, mat_dtype=mat_dtype)
+    return dev if st is None else dataclasses.replace(dev, stencil=st.spec)
+
+
+def _mg_of(dev, o: SolverOptions, batched: bool = False):
+    """The V-cycle plan (acg_tpu/ops/mg.py) of a classic CG solve on
+    ``dev`` under ``o``, None without a preconditioner, or
+    ERR_NOT_SUPPORTED."""
+    if o.precond == "none":
+        return None
+    from acg_tpu.ops.mg import mg_plan
+    from acg_tpu.ops.stencil import DeviceStencil
+
+    if batched:
+        raise AcgError(Status.ERR_NOT_SUPPORTED,
+                       "precond='mg' solves one right-hand side at a "
+                       "time, not a batch")
+    if o.segment_iters > 0:
+        raise AcgError(Status.ERR_NOT_SUPPORTED,
+                       "precond='mg' runs as one program "
+                       "(segment_iters=0)")
+    spec = (dev.spec if isinstance(dev, DeviceStencil)
+            else getattr(dev, "stencil", None))
+    with span("acg.prep.mg"):
+        return mg_plan(spec, o.mg_levels)
+
+
+def _precond_fn(mg, front: int = 0):
+    """z = M r over the loop's flat vectors, or None."""
+    if mg is None:
+        return None
+    from acg_tpu.ops.mg import vcycle_flat
+
+    return vcycle_flat(mg, front)
+
+
 def build_device_operator(A, dtype=None, fmt: str = "auto",
                           mat_dtype="auto", nrhs: int = 1):
     """Build the device operator (the upload half of solver init, reference
@@ -1028,18 +1073,16 @@ def build_device_operator(A, dtype=None, fmt: str = "auto",
                    else np.dtype(host_vals.dtype))
             return DeviceStencil.from_matrix(A, dtype=vdt)
         if isinstance(A, DiaMatrix):
+            st, _rep = try_device_stencil(A, dtype=dtype)
             if fmt == "auto" and stencil_available():
                 # the matrix-free tier outranks every stored tier when the
                 # system IS a verified constant-coefficient stencil and the
                 # kernel probe is green (ROADMAP item 2): zero operator
                 # stream, no band storage.  Probe-gated like every tier —
                 # off-TPU the stored ladder below is unchanged.
-                vdt = (np.dtype(dtype) if dtype is not None
-                       else np.dtype(A.bands.dtype))
-                st, _rep = try_device_stencil(A, dtype=vdt)
                 if _prefer_stencil(st, mat_dtype, nrhs):
                     return st
-            return DeviceDia.from_dia(A, dtype=dtype, mat_dtype=mat_dtype)
+            return _dia_keeping_stencil(A, st, dtype, mat_dtype)
         if isinstance(A, CsrMatrix):
             if fmt not in ("auto", "dia", "ell", "sgell", "stencil"):
                 raise AcgError(Status.ERR_INVALID_VALUE,
@@ -1110,8 +1153,10 @@ def build_device_operator(A, dtype=None, fmt: str = "auto",
                     # DIA recovery nor segment packing applies)
                     fmt = "ell"
             if fmt == "dia":
-                return DeviceDia.from_dia(DiaMatrix.from_csr(A), dtype=dtype,
-                                          mat_dtype=mat_dtype)
+                D = DiaMatrix.from_csr(A)
+                return _dia_keeping_stencil(
+                    D, try_device_stencil(D, dtype=dtype)[0], dtype,
+                    mat_dtype)
             # an explicitly forced fmt="ell" keeps its documented contract and
             # pins the XLA gather form (the A/B baseline)
             return DeviceEll.from_ell(EllMatrix.from_csr(A), dtype=dtype,
@@ -1180,7 +1225,8 @@ def _unpermute(x, nrows: int, perm):
 
 def _finish(A, x, k, rr, flag, rr0, options, tsolve, pipelined, bnrm2,
             dxx=None, stats=None, path=("", ""), hist=None,
-            sstep: int = 0, solver: str | None = None, *, to_host):
+            sstep: int = 0, solver: str | None = None, *, to_host,
+            precond: str = ""):
     """Assemble the SolveResult.  ``tsolve`` is the measured device-solve
     time (timer around the compiled loop only, matching the reference's
     tsolve which excludes the solution copyback, acg/cgcuda.c:1022-1107).
@@ -1265,6 +1311,7 @@ def _finish(A, x, k, rr, flag, rr0, options, tsolve, pipelined, bnrm2,
             operator_format=path[0], kernel=path[1],
             kernel_note=path[2] if len(path) > 2 else "",
             iface=path[3] if len(path) > 3 else "",
+            precond=precond,
             residual_history=hist if has_hist else None,
             nrhs=nrhs,
             iterations_per_system=ksys if batched else None,
@@ -1377,6 +1424,7 @@ def cg(A, b, x0=None, options: SolverOptions = SolverOptions(),
         plan = (_fused_plan_batched(dev, b_pad.shape[0]) if batched
                 else _fused_plan(dev))
         monitor = _resolve_monitor(o)
+        mg = _mg_of(dev, o, batched)
     with span("acg.call.dispatch"):
         t0 = time.perf_counter()
         if plan is not None and o.segment_iters > 0:
@@ -1408,7 +1456,7 @@ def cg(A, b, x0=None, options: SolverOptions = SolverOptions(),
                 maxits=o.maxits, track_diff=track_diff,
                 check_every=o.check_every, rows_tile=rt, kind=kind,
                 monitor=monitor, monitor_every=o.monitor_every,
-                fault=fplan, guard=guard)
+                fault=fplan, guard=guard, mg=mg)
         elif o.segment_iters > 0:
             x, k, rr, dxx, flag, rr0, hist = _run_segmented(
                 lambda: _cg_device_seg(
@@ -1428,7 +1476,7 @@ def cg(A, b, x0=None, options: SolverOptions = SolverOptions(),
                 maxits=o.maxits, track_diff=track_diff,
                 check_every=o.check_every,
                 monitor=monitor, monitor_every=o.monitor_every,
-                fault=fplan, guard=guard)
+                fault=fplan, guard=guard, mg=mg)
     k = _device_wait(x, k)        # scalar, or per-system (B,) when batched
     tsolve = time.perf_counter() - t0
     from acg_tpu.solvers.base import kernel_disengagement_note
@@ -1440,7 +1488,7 @@ def cg(A, b, x0=None, options: SolverOptions = SolverOptions(),
                    path=_describe_path(
                        dev, perm, plan,
                        nrhs=b_pad.shape[0] if batched else 1) + (note,),
-                   hist=hist)
+                   hist=hist, precond=mg.note if mg else "")
 
 
 def _deflate_x0(matvec, b, x0, W, WtAW):
@@ -1549,6 +1597,8 @@ def lowered_step(A, b, x0=None, options: SolverOptions = SolverOptions(),
     # the SAME monitor resolution as the solve: an --explain audit of a
     # monitored solve must see the callback ops the hot loop carries
     monitor = _resolve_monitor(o)
+    if solver in ("cg-sstep", "cg-pipelined-deep") or pipelined:
+        require_unpreconditioned(o, solver or "cg-pipelined")
     if solver == "cg-sstep":
         # the same rejections cg_sstep applies
         s = _sstep_validate(o, fault)
@@ -1625,18 +1675,20 @@ def lowered_step(A, b, x0=None, options: SolverOptions = SolverOptions(),
                                                vdt))
     plan = (_fused_plan_batched(dev, b_pad.shape[0]) if batched
             else _fused_plan(dev))
+    mg = _mg_of(dev, o, batched)
     if plan is not None:
         kind, rt = plan
         return _cg_device_fused.lower(
             dev, b_pad, x0_pad, stop2, diffstop, maxits=o.maxits,
             track_diff=track_diff, check_every=o.check_every,
             rows_tile=rt, kind=kind, monitor=monitor,
-            monitor_every=o.monitor_every, fault=fplan, guard=guard)
+            monitor_every=o.monitor_every, fault=fplan, guard=guard,
+            mg=mg)
     return _cg_device.lower(
         dev, b_pad, x0_pad, stop2, diffstop, maxits=o.maxits,
         track_diff=track_diff, check_every=o.check_every,
         monitor=monitor, monitor_every=o.monitor_every,
-        fault=fplan, guard=guard)
+        fault=fplan, guard=guard, mg=mg)
 
 
 def compile_step(A, b, x0=None, options: SolverOptions = SolverOptions(),
@@ -1722,7 +1774,7 @@ def check_aot_options(compiled_o: SolverOptions,
     match)."""
     static = ("maxits", "check_every", "replace_every", "monitor_every",
               "guard_nonfinite", "segment_iters", "sstep",
-              "pipeline_depth", "halo_wire")
+              "pipeline_depth", "halo_wire", "precond", "mg_levels")
     for f in static:
         if getattr(o, f) != getattr(compiled_o, f):
             raise AcgError(Status.ERR_INVALID_VALUE,
@@ -1816,6 +1868,7 @@ def aot_step(A, b, x0=None, options: SolverOptions = SolverOptions(),
         note = kernel_disengagement_note(False, plan, None, None,
                                          forced_fmt=fmt)
     path = path + (note,)
+    mg = None if deep_kind or pipelined else _mg_of(dev, o, batched)
 
     @_traced_call("cg-pipelined-deep" if deep_kind
                   else "cg-pipelined" if pipelined else "cg")
@@ -1912,7 +1965,8 @@ def aot_step(A, b, x0=None, options: SolverOptions = SolverOptions(),
                        to_host=_host_solution(dev.nrows, perm),
                        path=path2, hist=hist,
                        solver=("cg-pipelined-deep" if deep_kind
-                               else None))
+                               else None),
+                       precond=mg.note if mg else "")
 
     return AotSolve(compiled, solve,
                     kind=("cg-pipelined-deep" if deep_kind
@@ -1929,6 +1983,7 @@ def cg_pipelined(A, b, x0=None, options: SolverOptions = SolverOptions(),
     :func:`cg`; an injection solve gates off the single-kernel pipelined
     iteration (the mega-kernel exposes no injection sites)."""
     o = options
+    require_unpreconditioned(o, "cg-pipelined")
     if o.diffatol > 0 or o.diffrtol > 0:
         raise AcgError(Status.ERR_NOT_SUPPORTED,
                        "pipelined CG supports residual-based stopping only")
@@ -2147,6 +2202,7 @@ def cg_pipelined_deep(A, b, x0=None,
     overrides the power-iteration/Chebyshev shift seeds — a testing
     hook."""
     o = options
+    require_unpreconditioned(o, "cg-pipelined-deep")
     if o.pipeline_depth == 1:
         return cg_pipelined(A, b, x0, options=o, dtype=dtype, fmt=fmt,
                             mat_dtype=mat_dtype, stats=stats,

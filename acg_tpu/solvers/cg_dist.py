@@ -40,7 +40,8 @@ from acg_tpu.parallel.mesh import PARTS_AXIS
 from acg_tpu.parallel.sharded import ShardedSystem, resolve_local_fmt
 from acg_tpu.partition.graph import PartitionedSystem, partition_system
 from acg_tpu.partition.partitioner import partition_graph
-from acg_tpu.solvers.base import SolveResult, SolveStats
+from acg_tpu.solvers.base import (SolveResult, SolveStats,
+                                  require_unpreconditioned)
 from acg_tpu.solvers.cg import (_CONVERGED, _GRAM_BAD, _cheb_leja_nodes,
                                 _deflate_x0, _device_wait, _finish,
                                 _pipelined_continue, _power_lmax,
@@ -767,6 +768,7 @@ def _solve_dist(kind: str, A, b, x0, options: SolverOptions,
                 stats: SolveStats | None, fault=None,
                 atol2_floor=None, recycle=None, **build_kw) -> SolveResult:
     o = options
+    require_unpreconditioned(o, f"{kind} over a mesh")
     with span("acg.call.prepare"):
         b = np.asarray(b)
         nrhs = b.shape[0] if b.ndim == 2 else 1
@@ -1081,6 +1083,7 @@ def lowered_step(A, b=None, x0=None,
     (optional — zeros by default, shapes are all that matter for
     lowering) select the multi-RHS program when either is ``(B, n)``."""
     o = options
+    require_unpreconditioned(o, "a distributed solver")
     if solver == "cg-recycled":
         # deflation is SETUP-only host work (x0 preconditioning): the
         # shard program cg_recycled_dist dispatches IS cg_dist's — the
@@ -1250,6 +1253,7 @@ def aot_step(A, b=None, x0=None,
     from acg_tpu.solvers.cg import AotSolve
 
     o = options
+    require_unpreconditioned(o, "a distributed solver")
     if solver is not None:
         pipelined = solver == "cg-pipelined"
     if solver == "cg-pipelined-deep" and o.pipeline_depth <= 1:

@@ -108,7 +108,7 @@ def cg_while(matvec, dot, b, x0, stop2, diffstop, maxits: int,
              track_diff: bool, check_every: int = 1, coupled_step=None,
              segment: int = 0, carry_in=None, want_carry: bool = False,
              monitor=None, monitor_every: int = 0,
-             fault=None, guard: bool = False):
+             fault=None, guard: bool = False, precond=None):
     """Classic CG loop (ref acg/cg.c:534-637 / acg/cgcuda.c:845-1020).
 
     Returns (x, k, rnrm2sqr, dxnrm2sqr, flag, rnrm2sqr0, hist) where
@@ -160,8 +160,20 @@ def cg_while(matvec, dot, b, x0, stop2, diffstop, maxits: int,
     breakdown witness relies on, so without the guard a non-finite
     solve spins silently to maxits).  Both default off and then trace
     the exact pre-existing program.
+
+    PRECONDITIONED (``precond``, a function z = M r for a symmetric
+    positive-definite M; one right-hand side): the carry gains z = M r
+    and r'z, the direction update becomes p = z + βp, α = r'z / p'Ap and
+    β = r'z' / r'z.  Convergence, the history and the returned norms
+    stay on |r|², so the residual keeps its meaning.  r'z <= 0 with
+    |r|² > 0 proves M is not SPD and raises the breakdown flag, as the
+    p'Ap witness does for A.  ``precond=None`` traces the unpreconditioned
+    program exactly.
     """
     batched = b.ndim == 2
+    if precond is not None and batched:
+        raise ValueError("a preconditioned loop takes one right-hand side")
+    precond = _scoped("acg.precond", precond)
     # broadcast a (B,) per-system scalar against (B, n) system vectors;
     # identity in the 1-D path, so that trace is unchanged
     bc = (lambda s: s[:, None]) if batched else (lambda s: s)
@@ -198,6 +210,13 @@ def cg_while(matvec, dot, b, x0, stop2, diffstop, maxits: int,
                 jnp.full_like(rr0, jnp.inf),
                 jnp.asarray(0, jnp.int32), init_flag,
                 _history_init(rr0, maxits))
+        if precond is not None:
+            z0 = precond(r)
+            rz0 = dot(r, z0)
+            init_flag = jnp.where(
+                (init_flag == _OK) & (rz0 <= 0.0), _BREAKDOWN,
+                init_flag).astype(jnp.int32)
+            init = init[:7] + (init_flag,) + init[8:] + (z0, rz0)
         if batched:
             # per-system iteration counts (the global k cannot serve: a
             # system frozen at iteration 3 of a 40-iteration batch solve
@@ -214,7 +233,10 @@ def cg_while(matvec, dot, b, x0, stop2, diffstop, maxits: int,
         return (k < limit) & alive
 
     def body(c):
-        x, r, p, rr, beta, dxx, k, flag, hist, *ksys = c
+        x, r, p, rr, beta, dxx, k, flag, hist, *rest = c
+        ksys = rest if batched else []
+        if precond is not None:
+            z, rz = rest
         active = (flag == _OK) if batched else None
         # deterministic fault injection (no-ops tracing nothing when
         # fault is None): the residual carry and the halo-feeding
@@ -223,7 +245,7 @@ def cg_while(matvec, dot, b, x0, stop2, diffstop, maxits: int,
         # after the dot — see acg_tpu/robust/faults.py for the site map
         r = inject_vector(fault, SITE_CARRY, k, r)
         p = inject_vector(fault, SITE_HALO, k, p)
-        p_new, t, ptap = coupled_step(r, p, beta)
+        p_new, t, ptap = coupled_step(r if precond is None else z, p, beta)
         t = inject_vector(fault, SITE_SPMV, k, t)
         if batched:
             # frozen systems keep their direction (beta keeps recurring
@@ -241,7 +263,8 @@ def cg_while(matvec, dot, b, x0, stop2, diffstop, maxits: int,
         # to maxits instead of dying with a spurious "indefinite matrix".
         indefinite = (ptap < 0.0) | ((ptap == 0.0) & (rr > 0.0))
         safe = ptap > 0.0
-        alpha = jnp.where(safe, rr / jnp.where(safe, ptap, 1.0), 0.0)
+        alpha = jnp.where(safe, (rr if precond is None else rz)
+                          / jnp.where(safe, ptap, 1.0), 0.0)
         if batched:
             alpha = jnp.where(active, alpha, 0.0)   # freeze x and r
         with jax.named_scope("acg.blas1"):
@@ -252,6 +275,10 @@ def cg_while(matvec, dot, b, x0, stop2, diffstop, maxits: int,
         with jax.named_scope("acg.blas1"):
             r = r - bc(alpha) * t
         rr_new = inject_reduction(fault, k, dot(r, r))
+        if precond is not None:
+            z = precond(r)
+            rz_new = dot(r, z)
+            indefinite = indefinite | ((rz_new <= 0.0) & (rr_new > 0.0))
         if batched:
             rr_new = jnp.where(active, rr_new, rr)
             # frozen systems' history stops advancing: their slots past
@@ -286,6 +313,10 @@ def cg_while(matvec, dot, b, x0, stop2, diffstop, maxits: int,
             ksys = [jnp.where(active, k + 1, ksys[0])]
         else:
             flag = flag_new
+        if precond is not None:
+            beta_next = rz_new / jnp.where(rz == 0.0, 1.0, rz)
+            return (x, r, p, rr_new, beta_next, dxx, k + 1, flag, hist,
+                    z, rz_new)
         beta_next = rr_new / jnp.where(rr == 0.0, 1.0, rr)
         return (x, r, p, rr_new, beta_next, dxx, k + 1, flag,
                 hist) + tuple(ksys)

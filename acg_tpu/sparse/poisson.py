@@ -161,6 +161,41 @@ def poisson3d_7pt_dia(nx: int, ny: int | None = None, nz: int | None = None,
     return DiaMatrix(n, n, offs, bands, nnz)
 
 
+def poisson3d_27pt_dia(nx: int, ny: int | None = None,
+                       nz: int | None = None, dtype=np.float64,
+                       row_align: int = 8):
+    """27-point 3D stencil (diag 26, all neighbours -1) built DIRECTLY in
+    DIA band form, equal to ``DiaMatrix.from_csr(poisson3d_27pt(...))``
+    (tested): only the 27 band vectors are materialized, where the CSR
+    route would hold 453M entries at 256³."""
+    import itertools
+
+    from acg_tpu.ops.dia import DiaMatrix
+
+    ny = ny if ny is not None else nx
+    nz = nz if nz is not None else nx
+    n = nx * ny * nz
+    nrp = -(-n // row_align) * row_align
+    i = np.arange(n)
+    coords = (i // (ny * nz), (i // nz) % ny, i % nz)
+    # in range of each axis: [below, at, above] the point's coordinate
+    inside = [(c > 0, None, c < m - 1)
+              for c, m in zip(coords, (nx, ny, nz))]
+    arms = sorted(itertools.product((-1, 0, 1), repeat=3),
+                  key=lambda d: d[0] * ny * nz + d[1] * nz + d[2])
+    bands = np.zeros((27, nrp), dtype=dtype)
+    nnz = 0
+    for k, d in enumerate(arms):
+        ok = np.ones(n, bool)
+        for ax, g in enumerate(d):
+            if g:
+                ok &= inside[ax][g + 1]
+        bands[k, :n] = np.where(ok, 26.0 if d == (0, 0, 0) else -1.0, 0.0)
+        nnz += int(ok.sum())
+    offs = tuple(d[0] * ny * nz + d[1] * nz + d[2] for d in arms)
+    return DiaMatrix(n, n, offs, bands, nnz)
+
+
 def grid_partition_vector(shape, grid) -> np.ndarray:
     """Partition a structured grid into a block grid: the structured analog of
     METIS partitioning (exact, zero-cost).  ``grid`` is a tuple with the same

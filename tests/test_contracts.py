@@ -341,7 +341,10 @@ def test_registry_matrix_covers_the_acceptance_axes():
     # ISSUE 20) + the 16-case matrix-free stencil sub-matrix
     # ({cg, cg-pipelined} x {1, 4} x {f32, bf16} x {B=1, 4} —
     # ISSUE 12)
-    assert len([c for c in cases if c.fmt != "stencil"]) == 48
+    # and, apart from those, the multigrid-preconditioned classic loop
+    # on the stored and matrix-free tiers (single chip, f32, B=1)
+    plain = [c for c in cases if c.precond == "none"]
+    assert len([c for c in plain if c.fmt != "stencil"]) == 48
     rec = [c for c in cases if c.solver == "cg-recycled"]
     assert len(rec) == 8
     assert {c.nparts for c in rec} == {1, 4}
@@ -352,13 +355,18 @@ def test_registry_matrix_covers_the_acceptance_axes():
                                         "cg-pipelined-deep"}
     assert {c.wire for c in wire} == {"bf16", "int16-delta"}
     assert {c.nparts for c in wire} == {4}
-    st = [c for c in cases if c.fmt == "stencil"]
+    mg = [c for c in cases if c.precond == "mg"]
+    assert [(c.solver, c.nparts, c.dtype, c.nrhs, c.fmt) for c in mg] == [
+        ("cg", 1, "float32", 1, "dia"), ("cg", 1, "float32", 1, "stencil")]
+    st = [c for c in plain if c.fmt == "stencil"]
     assert len(st) == 16
     assert {c.solver for c in st} == {"cg", "cg-pipelined"}
     assert {c.nparts for c in st} == {1, 4}
     fast = registry_cases(fast=True)
-    assert {c.nparts for c in fast} == {1} and len(fast) == 21
-    assert len([c for c in fast if c.fmt == "stencil"]) == 1
+    assert {c.nparts for c in fast} == {1} and len(fast) == 23
+    assert [c for c in fast if c.precond == "mg"] == mg
+    assert len([c for c in fast if c.fmt == "stencil"
+                and c.precond == "none"]) == 1
 
 
 # ---------------------------------------------------------------------------

@@ -472,10 +472,11 @@ def test_registry_fast_includes_stencil_case():
     from acg_tpu.analysis.registry import registry_cases
 
     fast = registry_cases(fast=True)
-    st = [c for c in fast if c.fmt == "stencil"]
+    st = [c for c in fast if c.fmt == "stencil" and c.precond == "none"]
     assert len(st) == 1 and st[0].nparts == 1
     full = registry_cases(fast=False)
-    st_full = [c for c in full if c.fmt == "stencil"]
+    st_full = [c for c in full
+               if c.fmt == "stencil" and c.precond == "none"]
     assert len(st_full) == 16
     assert {c.nparts for c in st_full} == {1, 4}
     assert {c.solver for c in st_full} == {"cg", "cg-pipelined"}

@@ -88,6 +88,24 @@ def test_hbm_ring_dia_256(one_chip):
     _compile(step, one_chip, ((7, npad), BF16), ((npad,), F32))
 
 
+def test_hbm_ring_dia_27pt_256(one_chip):
+    """HPCG's 27 bands at 256³: the plan picks a rows_tile whose VMEM
+    stack fits (512 did not: 16.3 MB on the chip)."""
+    n = 256 ** 3
+    offsets = tuple(sorted(i * 65536 + j * 256 + k for i in (-1, 0, 1)
+                           for j in (-1, 0, 1) for k in (-1, 0, 1)))
+    rt = pk.pallas_hbm2d_ring_plan(n, offsets, np.float32,
+                                   np.dtype("bfloat16"))
+    assert rt == 256
+    npad = _padded_len(n, offsets, rt)
+
+    def step(bands_pad, x_pad):
+        return pk.dia_matvec_pallas_hbm2d_ring(bands_pad, offsets, x_pad,
+                                               rows_tile=rt, with_dot=True)
+
+    _compile(step, one_chip, ((27, npad), BF16), ((npad,), F32))
+
+
 def test_pipe2d_iteration_128(one_chip):
     offsets, n = _p3d(128)
     rt = pk.pallas_2d_plan(n, offsets, np.float32, np.dtype("bfloat16"))
