@@ -265,10 +265,10 @@ class DeviceDia:
     def matvec(self, x: jax.Array) -> jax.Array:
         """SpMV through :func:`dia_matvec_best`.  In the HBM-resident
         regime (past the resident-x VMEM bound) that path pads the band
-        stack per call — loop-invariant under a jitted solver loop (LICM
-        hoists it; the fused solver path avoids it entirely,
-        acg_tpu/solvers/cg.py _cg_device_fused), but a ~GB-scale copy per
-        call for EAGER callers at e.g. 464³.  Repeated eager matvecs
+        stack per call: a ~GB-scale copy per call at e.g. 464³, and XLA
+        does not hoist it out of a jitted loop either (the fused solver
+        path pads once per solve instead, acg_tpu/solvers/cg.py
+        _cg_device_fused).  Repeated eager matvecs
         therefore reuse a single-slot padded-band cache held on the
         instance (skipped when ``bands`` is a tracer, i.e. when the
         operator itself is a jit argument)."""
@@ -394,11 +394,12 @@ def dia_matvec_best(bands: jax.Array, offsets: tuple, x: jax.Array,
         # past the resident-x VMEM bound (the 100M-DOF regime): the
         # HBM-resident-x kernel, for EVERY storage width — at this scale
         # the XLA path's materialized shifted copies of x dominate.  The
-        # per-call pads below are loop-invariant for the bands (XLA's
-        # while-loop LICM hoists them out of solver loops) and ~5% of
-        # the kernel's time for x; the solver's fused path
-        # (acg_tpu/solvers/cg.py _cg_device_fused) avoids both by
-        # carrying permanently padded vectors
+        # pads below run on every call, bands included: XLA does not
+        # hoist the band pad out of a while loop (a v5e compile of a loop
+        # over this route keeps a relayout and a pad of the bands in the
+        # body).  The solver's fused path (acg_tpu/solvers/cg.py
+        # _cg_device_fused) pads the bands once per solve and carries
+        # permanently padded vectors
         if rt_res is None:
             kernel, rt = _hbm_kernel_for(n, offsets, x.dtype, bands.dtype)
             if kernel is not None:

@@ -151,6 +151,22 @@ def dia_matvec_pallas_2d(bands, offsets: tuple, x, rows_tile: int = 512,
     return y.reshape(n)
 
 
+def _padded_band_rows(bands_pad, rows_tile: int) -> tuple[int, int]:
+    """(D, Rp) of padded bands, refusing any layout but (D, Rp, 128).
+
+    The padded kernels read the bands as (D, Rp, 128) blocks.  A 2-D
+    (D, Rp*128) operand would need a reshape here, and XLA's tiling of
+    the two forms differs (the band axis of the 2-D form is tiled by 8),
+    so that reshape is a full copy of the bands on every call — inside a
+    solver loop, on every iteration.  Hence one layout, checked."""
+    if bands_pad.ndim != 3 or bands_pad.shape[-1] != LANES:
+        raise ValueError(f"padded bands must be (D, Rp, {LANES}) "
+                         f"(pad_dia_operands), got {bands_pad.shape}")
+    D, Rp, _ = bands_pad.shape
+    assert Rp % rows_tile == 0
+    return D, Rp
+
+
 def _banded_tile_acc(offsets, rows_tile, scaled, src_ref, bands_ref,
                      scales_ref, base, dt):
     """One (rows_tile, 128) tile of DIA(bands) @ src on the padded layout:
@@ -213,16 +229,16 @@ def dia_matvec_pallas_2d_padded(bands_pad, offsets: tuple, x_pad,
                                 interpret: bool = False, scales=None):
     """y = DIA(bands) @ x on the padded layout (see kernel docstring).
 
-    ``bands_pad``: (D, Rp*128) with ``H = padded_halo_rows(offsets,
+    ``bands_pad``: (D, Rp, 128) with ``H = padded_halo_rows(offsets,
     rows_tile)`` zero halo rows in front and H + tail-rounding behind
-    (build with :func:`pad_dia_operands`); ``x_pad``: (Rp*128,) with the
-    same halo, zeros there.  Returns y in the SAME padded layout (zero
+    (build with :func:`pad_dia_operands`; any other layout is refused,
+    see :func:`_padded_band_rows`); ``x_pad``: (Rp*128,) with the same
+    halo, zeros there.  Returns y in the SAME padded layout (zero
     halo preserved), plus the scalar <x, y> when ``with_dot`` — which for
     CG's t = Ap is exactly p'Ap.
     """
-    D, npad = bands_pad.shape
-    assert npad % (rows_tile * LANES) == 0
-    Rp = npad // LANES
+    D, Rp = _padded_band_rows(bands_pad, rows_tile)
+    npad = Rp * LANES
     ntiles = Rp // rows_tile
     scaled = scales is not None
     sc = (scales.astype(x_pad.dtype) if scaled
@@ -248,7 +264,7 @@ def dia_matvec_pallas_2d_padded(bands_pad, offsets: tuple, x_pad,
         ],
         out_specs=tuple(out_specs),
         interpret=interpret,
-    )(x_pad.reshape(Rp, LANES), bands_pad.reshape(D, Rp, LANES), sc)
+    )(x_pad.reshape(Rp, LANES), bands_pad, sc)
     y = outs[0].reshape(npad)
     if with_dot:
         return y, outs[1][0, 0]
@@ -320,9 +336,8 @@ def cg_pipelined_iter_pallas(bands_pad, offsets: tuple, w_pad, z_pad,
     :func:`dia_matvec_pallas_2d_padded`; ``alpha``/``beta`` are device
     scalars (this iteration's coefficients, derived from the PREVIOUS
     iteration's (gamma, delta) by the solver loop)."""
-    D, npad = bands_pad.shape
-    assert npad % (rows_tile * LANES) == 0
-    Rp = npad // LANES
+    D, Rp = _padded_band_rows(bands_pad, rows_tile)
+    npad = Rp * LANES
     ntiles = Rp // rows_tile
     dt = w_pad.dtype
     scaled = scales is not None
@@ -348,7 +363,7 @@ def cg_pipelined_iter_pallas(bands_pad, offsets: tuple, w_pad, z_pad,
             pl.BlockSpec((1, 2), lambda i: (0, 0),
                          memory_space=pltpu.SMEM),),
         interpret=interpret,
-    )(w_pad.reshape(Rp, LANES), bands_pad.reshape(D, Rp, LANES), sc, ab,
+    )(w_pad.reshape(Rp, LANES), bands_pad, sc, ab,
       z_pad.reshape(Rp, LANES), r_pad.reshape(Rp, LANES),
       p_pad.reshape(Rp, LANES), s_pad.reshape(Rp, LANES),
       x_pad.reshape(Rp, LANES))
@@ -412,10 +427,10 @@ def dia_matvec_pallas_2d_padded_batched(bands_pad, offsets: tuple, x_pad,
     :func:`dia_matvec_pallas_2d_padded`); returns (B, npad) — plus the
     per-system <x_s, y_s> vector of shape (B,) when ``with_dot`` (for
     CG's t = Ap this is the per-system p'Ap the batched loop carries)."""
-    D, npad = bands_pad.shape
+    D, Rp = _padded_band_rows(bands_pad, rows_tile)
+    npad = Rp * LANES
     B = x_pad.shape[0]
-    assert x_pad.shape[-1] == npad and npad % (rows_tile * LANES) == 0
-    Rp = npad // LANES
+    assert x_pad.shape[-1] == npad
     ntiles = Rp // rows_tile
     scaled = scales is not None
     sc = (scales.astype(x_pad.dtype) if scaled
@@ -443,7 +458,7 @@ def dia_matvec_pallas_2d_padded_batched(bands_pad, offsets: tuple, x_pad,
         ],
         out_specs=tuple(out_specs),
         interpret=interpret,
-    )(x_pad.reshape(B, Rp, LANES), bands_pad.reshape(D, Rp, LANES), sc)
+    )(x_pad.reshape(B, Rp, LANES), bands_pad, sc)
     y = outs[0].reshape(B, npad)
     if with_dot:
         return y, outs[1][0]
@@ -456,8 +471,10 @@ def dia_matvec_pallas_2d_batched(bands, offsets: tuple, x,
                                  rows_tile: int = 512,
                                  interpret: bool = False, scales=None):
     """Eager-contract wrapper for (B, n) multi-RHS SpMV: pads operands
-    into the padded layout (loop-invariant for the bands under a jitted
-    solver loop — LICM hoists it) and runs the batched resident kernel."""
+    into the padded layout and runs the batched resident kernel.  The
+    bands are padded on every call; a solver loop pads them once, outside
+    the loop (acg_tpu/solvers/cg.py ``_pad_fused``), and calls
+    :func:`dia_matvec_pallas_2d_padded_batched` itself."""
     n = x.shape[-1]
     bp, (xp,) = pad_dia_operands(bands, (x,), rows_tile, offsets)
     hp = padded_halo_rows(offsets, rows_tile) * LANES
@@ -522,17 +539,18 @@ def pad_dia_operands(bands, x_vecs, rows_tile: int, offsets: tuple):
     ``H = padded_halo_rows(offsets, rows_tile)`` zero halo rows in front,
     and ``H`` plus whatever tail rounds the total row count to a
     rows_tile multiple behind (so ANY lane-aligned n admits any tile —
-    464³'s row count is 2⁵·29³ and divides nothing useful).  Traced (jnp)
-    ops — call inside jit; XLA folds the pads into the surrounding
-    program."""
+    464³'s row count is 2⁵·29³ and divides nothing useful).  The bands
+    come back as (D, Rp, 128), the kernels' block layout, and are passed
+    to them as they are; the vectors stay flat (Rp*128,), whose
+    (Rp, 128) view is a bitcast.  Traced (jnp) ops — call inside jit,
+    once per solve and outside the solver loop."""
     D, n = bands.shape
     R = n // LANES
     H = padded_halo_rows(offsets, rows_tile)
     back = H + (-R) % rows_tile
     bp = jnp.pad(bands.reshape(D, R, LANES),
                  ((0, 0), (H, back), (0, 0)))
-    return (bp.reshape(D, -1),
-            pad_dia_vectors(x_vecs, n, rows_tile, offsets)[0])
+    return bp, pad_dia_vectors(x_vecs, n, rows_tile, offsets)[0]
 
 
 def _cluster_windows(offsets: tuple, slack: int = 8):
@@ -628,9 +646,8 @@ def dia_matvec_pallas_hbm2d(bands_pad, offsets: tuple, x_pad,
     layout in and out, optional fused <x, y>), with x HBM-resident —
     for operators past the resident kernel's VMEM bound.  ``with_dot``
     requires a main diagonal (offset 0) — always present for SPD."""
-    D, npad = bands_pad.shape
-    assert npad % (rows_tile * LANES) == 0
-    Rp = npad // LANES
+    D, Rp = _padded_band_rows(bands_pad, rows_tile)
+    npad = Rp * LANES
     ntiles = Rp // rows_tile
     assert not with_dot or 0 in offsets
     windows = _cluster_windows(offsets)
@@ -663,7 +680,7 @@ def dia_matvec_pallas_hbm2d(bands_pad, offsets: tuple, x_pad,
         out_specs=tuple(out_specs),
         scratch_shapes=scratch,
         interpret=interpret,
-    )(x_pad.reshape(Rp, LANES), bands_pad.reshape(D, Rp, LANES), sc)
+    )(x_pad.reshape(Rp, LANES), bands_pad, sc)
     y = outs[0].reshape(npad)
     if with_dot:
         return y, outs[1][0, 0]
@@ -796,9 +813,8 @@ def dia_matvec_pallas_hbm2d_ring(bands_pad, offsets: tuple, x_pad,
     """Same contract as :func:`dia_matvec_pallas_hbm2d` (padded layout in
     and out, optional fused <x, y>), with the ring-buffer x stream (1.0x
     fetch instead of one fetch per offset cluster)."""
-    D, npad = bands_pad.shape
-    assert npad % (rows_tile * LANES) == 0
-    Rp = npad // LANES
+    D, Rp = _padded_band_rows(bands_pad, rows_tile)
+    npad = Rp * LANES
     ntiles = Rp // rows_tile
     assert not with_dot or 0 in offsets
     qmin_t, qmax_t = _ring_span(offsets, rows_tile)
@@ -831,7 +847,7 @@ def dia_matvec_pallas_hbm2d_ring(bands_pad, offsets: tuple, x_pad,
         out_specs=tuple(out_specs),
         scratch_shapes=scratch,
         interpret=interpret,
-    )(x_pad.reshape(Rp, LANES), bands_pad.reshape(D, Rp, LANES), sc)
+    )(x_pad.reshape(Rp, LANES), bands_pad, sc)
     y = outs[0].reshape(npad)
     if with_dot:
         return y, outs[1][0, 0]

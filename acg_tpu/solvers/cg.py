@@ -221,7 +221,9 @@ def _fused_ops(op, bands_pad, rows_tile: int, kind: str):
 def _pad_fused(op, b, x0, rows_tile: int):
     """One-time padding into the fused layout (zero halo rows; see
     pad_dia_operands) — kept OUT of the per-segment functions so
-    segmented solves do not re-pad the bands every segment."""
+    segmented solves do not re-pad the bands every segment.  The bands
+    come back as (D, Rp, 128), the block layout the kernels read, so the
+    solver loop carries them with no per-iteration relayout."""
     from acg_tpu.ops.pallas_kernels import pad_dia_operands
 
     return pad_dia_operands(op.bands, (b, x0), rows_tile, op.offsets)

@@ -394,6 +394,7 @@ def test_release_matvec_cache_drops_the_eager_pad(monkeypatch):
 
     def fake_kernel(bands_pad, offsets, xp, rows_tile=None, scales=None,
                     **kw):
+        assert bands_pad.ndim == 3      # the padded kernels' contract
         return jnp.zeros_like(xp)
 
     # force the eager HBM route: no resident 2-D plan, HBM kernel "found"

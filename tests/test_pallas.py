@@ -105,6 +105,7 @@ def test_dia_matvec_pallas_2d_padded_fused_dot(scales_on):
         bref = bands
     want = dia_matvec(bref, D.offsets, jnp.asarray(x))
     bp, (xp,) = pad_dia_operands(bands, (jnp.asarray(x),), rt, D.offsets)
+    assert bp.shape == (len(D.offsets), xp.size // LANES, LANES)
     y, pd = dia_matvec_pallas_2d_padded(bp, D.offsets, xp, rows_tile=rt,
                                         with_dot=True, interpret=True,
                                         scales=scales)
@@ -119,6 +120,37 @@ def test_dia_matvec_pallas_2d_padded_fused_dot(scales_on):
     y2 = dia_matvec_pallas_2d_padded(bp, D.offsets, xp, rows_tile=rt,
                                      interpret=True, scales=scales)
     np.testing.assert_allclose(np.asarray(y2), np.asarray(y), rtol=1e-6)
+
+
+@pytest.mark.parametrize("name", ["dia_matvec_pallas_2d_padded",
+                                  "dia_matvec_pallas_2d_padded_batched",
+                                  "dia_matvec_pallas_hbm2d",
+                                  "dia_matvec_pallas_hbm2d_ring",
+                                  "cg_pipelined_iter_pallas"])
+def test_padded_kernels_refuse_flat_bands(name):
+    """Every padded-contract kernel takes the bands as (D, Rp, 128), the
+    layout pad_dia_operands builds, and refuses the flat (D, Rp*128)
+    form: reshaping it inside a solver loop relayouts every band on
+    every iteration."""
+    from acg_tpu.ops import pallas_kernels as pk
+
+    offsets = (-128, -1, 0, 1, 128)
+    n, rt = 16 * pk.LANES, 8
+    rng = np.random.default_rng(5)
+    bands = jnp.asarray(rng.standard_normal((5, n)).astype(np.float32))
+    x = jnp.asarray(rng.standard_normal(n).astype(np.float32))
+    bp, (xp,) = pk.pad_dia_operands(bands, (x,), rt, offsets)
+    assert bp.shape == (5, xp.size // pk.LANES, pk.LANES)
+    if name == "cg_pipelined_iter_pallas":
+        args = (xp,) * 6 + (jnp.float32(0.5), jnp.float32(0.25))
+    elif name.endswith("_batched"):
+        args = (xp[None],)
+    else:
+        args = (xp,)
+    kernel = getattr(pk, name)
+    with pytest.raises(ValueError, match=r"\(D, Rp, 128\)"):
+        kernel(bp.reshape(5, -1), offsets, *args, rows_tile=rt,
+               interpret=True)
 
 
 def test_pallas_2d_plan_bounds():

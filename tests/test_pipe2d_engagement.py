@@ -28,12 +28,14 @@ from acg_tpu.ops.dia import dia_matvec  # noqa: E402
 
 def _jnp_padded_spmv(counter):
     """jnp twin of dia_matvec_pallas_2d_padded on the padded layout
-    (zero halo bands make the plain shifted-multiply exact there)."""
+    (zero halo bands make the plain shifted-multiply exact there); the
+    bands arrive as the kernels take them, (D, Rp, 128)."""
 
     def spmv(bands_pad, offsets, x_pad, rows_tile=512, with_dot=False,
              interpret=False, scales=None):
         counter["spmv"] = counter.get("spmv", 0) + 1
-        bref = bands_pad.astype(x_pad.dtype)
+        assert bands_pad.ndim == 3 and bands_pad.shape[-1] == pk.LANES
+        bref = bands_pad.reshape(bands_pad.shape[0], -1).astype(x_pad.dtype)
         if scales is not None:
             bref = bref * scales.astype(x_pad.dtype)[:, None]
         y = dia_matvec(bref, offsets, x_pad)
@@ -52,7 +54,8 @@ def _jnp_pipe2d_iter(counter):
     def iter_step(bands_pad, offsets, w, z, r, p, s, x, alpha, beta,
                   rows_tile=512, interpret=False, scales=None):
         counter["pipe2d"] = counter.get("pipe2d", 0) + 1
-        bref = bands_pad.astype(w.dtype)
+        assert bands_pad.ndim == 3 and bands_pad.shape[-1] == pk.LANES
+        bref = bands_pad.reshape(bands_pad.shape[0], -1).astype(w.dtype)
         if scales is not None:
             bref = bref * scales.astype(w.dtype)[:, None]
         q = dia_matvec(bref, offsets, w)
